@@ -30,6 +30,10 @@ Fields:
     ``sync_compress_bytes`` — the replica-axis sync payload at
     none/bf16/int8 (subprocesses, so the forced host device counts
     never leak into this process).
+  * ``device`` — where the in-process timings above ran.  Every other
+    field comes from a child probe pinned to the host CPU
+    (``child_probes``: platform cpu), which never contends for a chip
+    with this process.
 
   PYTHONPATH=src python benchmarks/bench_parle.py          # write JSON
   PYTHONPATH=src python -m benchmarks.run parle            # suite line
@@ -58,6 +62,19 @@ OUT_PATH = os.path.join(os.path.dirname(__file__), "BENCH_parle.json")
 PIN = {"d_model": 64, "num_layers": 2, "d_ff": 128, "vocab": 512,
        "seq": 16, "batch": 1, "n_replicas": 2, "L": 5,
        "mesh": "replica:2,data:2,model:2", "param_size": 1 << 20}
+
+
+def _cpu_env(host_devices: int = 0) -> dict:
+    """Environment of a child probe: pinned to the host CPU, so it never
+    contends for a chip with this process, and never silently moves."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    if host_devices:
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                            + f" --xla_force_host_platform_device_count="
+                              f"{host_devices}")
+    env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..", "src")
+                         + os.pathsep + env.get("PYTHONPATH", ""))
+    return env
 
 
 def _time_us(fn, *args, warmup=2, iters=10):
@@ -220,7 +237,7 @@ def measure_comm() -> dict:
                                       "comm_volume.py"),
          "--mesh", PIN["mesh"], "--host-devices", "8",
          "--algo", "parle", "--param-size", str(PIN["param_size"])],
-        capture_output=True, text=True, timeout=900)
+        capture_output=True, text=True, timeout=900, env=_cpu_env())
     if res.returncode != 0:
         raise RuntimeError(res.stdout + res.stderr)
     row = next(l for l in res.stdout.splitlines()
@@ -271,12 +288,7 @@ def measure_compress() -> dict:
     """Replica-axis sync payload bytes per device at each
     --sync-compress setting, from compiled HLO (child process: 2 forced
     host devices, 1 MiB f32 model)."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=2")
-    env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..", "src")
-                         + os.pathsep + env.get("PYTHONPATH", ""))
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env = _cpu_env(host_devices=2)
     res = subprocess.run(
         [sys.executable, "-c",
          _COMPRESS_CHILD % (PIN["param_size"], PIN["L"])],
@@ -295,8 +307,8 @@ def measure_compress() -> dict:
 _OVERLAP_CHILD = r"""
 import dataclasses, json, time
 import jax, jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
+from repro.utils.compat import shard_map
 from repro.configs.base import ParleConfig
 from repro.core import parle, compress
 from repro.launch.mesh import make_mesh_from_spec
@@ -399,12 +411,7 @@ def measure_overlap() -> dict:
     fields combine that structure with the separately measured
     collective time, since this CPU backend has no async collectives to
     realize the overlap in wall clock."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8")
-    env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..", "src")
-                         + os.pathsep + env.get("PYTHONPATH", ""))
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env = _cpu_env(host_devices=8)
     res = subprocess.run(
         [sys.executable, "-c",
          _OVERLAP_CHILD % (PIN["param_size"], PIN["L"])],
@@ -421,10 +428,7 @@ def measure_overlap() -> dict:
 def _dist_pod(extra, metrics_out, timeout=1200):
     """One launch/dist_run pod in a subprocess; returns the merged
     registry snapshot from the pod_merged event."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..", "src")
-                         + os.pathsep + env.get("PYTHONPATH", ""))
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env = _cpu_env()
     res = subprocess.run(
         [sys.executable, "-m", "repro.launch.dist_run", "--nproc", "3",
          "--algo", "parle", "--smoke", "--steps", "9", "--L", "3",
@@ -530,10 +534,7 @@ def measure_recovery() -> dict:
     plan = json.dumps({"seed": 5, "faults": [
         {"kind": "coordinator_kill", "round": kill_round,
          "down_ms": 300}]})
-    env = dict(os.environ)
-    env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..", "src")
-                         + os.pathsep + env.get("PYTHONPATH", ""))
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env = _cpu_env()
     with tempfile.TemporaryDirectory() as td:
         def pod(tag, port, fault_plan=""):
             ck = os.path.join(td, f"{tag}.npz")
@@ -576,13 +577,19 @@ def measure_recovery() -> dict:
 
 
 def main(out_path: str = OUT_PATH):
-    rec = {"pinned_config": PIN}
+    import jax
+    dev = jax.devices()
+    # measure_steps runs here; every other probe is a CPU-pinned child
+    rec = {"pinned_config": PIN,
+           "device": {"platform": dev[0].platform,
+                      "kind": dev[0].device_kind, "count": len(dev)}}
     rec.update(measure_steps())
-    rec.update(measure_comm())
-    rec.update(measure_compress())
-    rec.update(measure_overlap())
-    rec.update(measure_straggler())
-    rec.update(measure_recovery())
+    probes = {}
+    for probe in (measure_comm, measure_compress, measure_overlap,
+                  measure_straggler, measure_recovery):
+        probes.update(probe())
+    rec.update(probes)
+    rec["child_probes"] = {"platform": "cpu", "fields": sorted(probes)}
     with open(out_path, "w") as f:
         json.dump(rec, f, indent=1, sort_keys=True)
         f.write("\n")

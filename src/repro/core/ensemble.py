@@ -20,30 +20,34 @@ import numpy as np
 from repro.utils.pytree import tree_mean_axis0
 
 
-def _flatten_replicas(tree):
-    leaves = [l.reshape(l.shape[0], -1) for l in jax.tree.leaves(tree)]
-    return jnp.concatenate(leaves, axis=1)          # (n, total)
-
-
+@jax.jit
 def replica_overlap(replica_tree) -> jnp.ndarray:
-    """Mean pairwise cosine similarity across the replica axis."""
-    flat = _flatten_replicas(replica_tree)
-    norm = flat / (jnp.linalg.norm(flat, axis=1, keepdims=True) + 1e-12)
-    sim = norm @ norm.T                             # (n, n)
-    n = sim.shape[0]
+    """Mean pairwise cosine similarity across the replica axis.
+
+    The (n, n) Gram matrix is summed leaf by leaf, so the replicas are
+    never concatenated into one (n, total) copy."""
+    leaves = jax.tree.leaves(replica_tree)
+    n = leaves[0].shape[0]
+    gram = jnp.stack([jnp.stack([
+        sum(jnp.sum(l[a] * l[b]) for l in leaves) for b in range(n)])
+        for a in range(n)])
+    norm = jnp.sqrt(jnp.diagonal(gram)) + 1e-12
+    sim = gram / (norm[:, None] * norm[None, :])
     if n == 1:
         return jnp.asarray(1.0)
-    off = (jnp.sum(sim) - jnp.trace(sim)) / (n * (n - 1))
-    return off
+    return (jnp.sum(sim) - jnp.trace(sim)) / (n * (n - 1))
 
 
+@jax.jit
 def replica_spread(replica_tree) -> jnp.ndarray:
     """RMS distance of replicas from their mean, normalized by the mean
     norm — goes to 0 as scoping collapses the ensemble."""
-    flat = _flatten_replicas(replica_tree)
-    mean = jnp.mean(flat, axis=0, keepdims=True)
-    spread = jnp.sqrt(jnp.mean(jnp.sum((flat - mean) ** 2, axis=1)))
-    return spread / (jnp.linalg.norm(mean) + 1e-12)
+    leaves = jax.tree.leaves(replica_tree)
+    means = [jnp.mean(l, axis=0, keepdims=True) for l in leaves]
+    sq_dist = sum(jnp.sum((l - m) ** 2, axis=tuple(range(1, l.ndim)))
+                  for l, m in zip(leaves, means))             # (n,)
+    mean_norm = jnp.sqrt(sum(jnp.sum(m ** 2) for m in means))
+    return jnp.sqrt(jnp.mean(sq_dist)) / (mean_norm + 1e-12)
 
 
 def one_shot_average(replica_tree):

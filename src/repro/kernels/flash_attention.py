@@ -5,6 +5,9 @@ to the TPU memory hierarchy:
  * grid = (B, H, num_q_blocks, num_k_blocks); the k dimension is the
    innermost, sequential ("arbitrary") axis; (m, l, acc) running
    statistics live in VMEM scratch across k iterations.
+ * The wrapper moves heads ahead of time, (B, T, H, hd) -> (B, H, T, hd),
+   so each block's tiled dims are (block, hd): the TPU tiles the last
+   two block dims in (8, 128) units or takes them whole.
  * Block shapes default to (128, head_dim): 128 is the MXU systolic
    dimension, so q @ k^T and p @ v are full-width MXU ops.
  * Causal + window masking is computed from absolute block offsets;
@@ -22,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils.compat import tpu_compiler_params
-
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
@@ -40,9 +41,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :]                       # (bq, hd)
-    k = k_ref[0, :, 0, :]                       # (bk, hd)
-    v = v_ref[0, :, 0, :]
+    q = q_ref[0, 0]                             # (bq, hd)
+    k = k_ref[0, 0]                             # (bk, hd)
+    v = v_ref[0, 0]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -70,16 +71,16 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(ik == num_k_blocks - 1)
     def _flush():
-        o_ref[0, :, 0, :] = (acc_scr[...] /
-                             jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] /
+                       jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("window", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, window: int = 0,
                     block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = True):
+                    block_k: int = DEFAULT_BLOCK_K, *,
+                    interpret: bool):
     """q, k, v: (B, T, H, hd) — GQA already expanded.  Causal."""
     B, T, H, hd = q.shape
     block_q = min(block_q, T)
@@ -89,26 +90,27 @@ def flash_attention(q, k, v, window: int = 0,
     scale = hd ** -0.5
 
     grid = (B, H, nq, nk)
-    q_spec = pl.BlockSpec((1, block_q, 1, hd), lambda b, h, i, j: (b, i, h, 0))
-    k_spec = pl.BlockSpec((1, block_k, 1, hd), lambda b, h, i, j: (b, j, h, 0))
-    o_spec = pl.BlockSpec((1, block_q, 1, hd), lambda b, h, i, j: (b, i, h, 0))
+    q_spec = pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0))
+    k_spec = pl.BlockSpec((1, 1, block_k, hd), lambda b, h, i, j: (b, h, j, 0))
 
     kernel = functools.partial(
         _kernel, scale=scale, block_q=block_q, block_k=block_k,
         num_k_blocks=nk, window=window, seq_len=T)
 
-    return pl.pallas_call(
+    heads_first = lambda a: jnp.swapaxes(a, 1, 2)      # (B, H, T, hd)
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[q_spec, k_spec, k_spec],
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, T, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )(heads_first(q), heads_first(k), heads_first(v))
+    return heads_first(out)

@@ -1,14 +1,12 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode; on TPU
-set REPRO_KERNEL_COMPILE=1 (or pass interpret=False) to compile for
-real.  Models call these through ``use_flash=True`` / ``use_kernel=True``
-flags; the default model path is the pure-XLA reference implementation,
-which is also the correctness oracle.
+On a TPU backend the kernels compile for the chip; on any other backend
+they run in interpret mode.  Models call these through
+``use_flash=True`` / ``use_kernel=True`` flags; the default model path is
+the pure-XLA reference implementation, which is also the correctness
+oracle.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
@@ -19,8 +17,6 @@ from repro.kernels import ssd_scan as _ssd
 
 
 def _interpret() -> bool:
-    if os.environ.get("REPRO_KERNEL_COMPILE"):
-        return False
     return jax.default_backend() != "tpu"
 
 

@@ -8,14 +8,22 @@ is ~7 HBM round-trips of N each; fused it is exactly 5 reads + 3 writes.
 The sync step (fired once every L steps, right after the one all-reduce
 produces xbar) reads four streams (x, z, v_x, xbar) and writes two
 (x', v_x') instead of the ~6 round-trips XLA emits for Eq. 8c-8d.
-TPU mapping: flat 1-D streams, tiled into (8, 1024)-shaped VMEM blocks
-(8x128-lane aligned); scalars ride in SMEM via scalar prefetch.
+
+TPU mapping: each leaf is viewed as (rows, cols) — its leading dims
+collapsed onto its last one, a free reshape of the TPU's tiled layout —
+and tiled into (8, 1024)-shaped VMEM blocks (a dim shorter than that is
+taken whole; a dim's last block may run past its end, where Pallas masks
+the writes).  Flattening a leaf to 1024-wide rows instead would relayout
+(copy) every stream whose last dim is not a multiple of 1024, e.g. a
+50280-wide head.  The updated streams alias their inputs, and scalars
+ride in SMEM via scalar prefetch.
 
 Oracles: kernels/ref.py::parle_inner_update / parle_sync_update.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +34,20 @@ from jax.experimental.pallas import tpu as pltpu
 # 8 streams resident => ~256 KiB of VMEM per program instance.
 BLOCK = (8, 1024)
 BLOCK_ELEMS = BLOCK[0] * BLOCK[1]
+
+
+def _view(a, lead: int = 0):
+    """``a`` as (*a.shape[:lead], rows, cols): the dims after ``lead``
+    collapsed onto their last one."""
+    s = a.shape[lead:]
+    cols = s[-1] if s else 1
+    return a.reshape(*a.shape[:lead], math.prod(s[:-1]), cols)
+
+
+def _tiling(rows: int, cols: int):
+    """Block and grid for a (rows, cols) view."""
+    block = (min(BLOCK[0], rows), min(BLOCK[1], cols))
+    return block, (pl.cdiv(rows, block[0]), pl.cdiv(cols, block[1]))
 
 
 def _kernel(scal_ref, y_ref, z_ref, v_ref, g_ref, x_ref,
@@ -49,17 +71,15 @@ def _kernel(scal_ref, y_ref, z_ref, v_ref, g_ref, x_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def parle_update_flat(y, z, v, g, x, scalars, interpret: bool = True):
-    """All operands: flat (M,) with M % BLOCK_ELEMS == 0; z, v, x are
-    f32 masters, y and g carry the compute dtype (f32 or bf16).
+def parle_update_leaf(y, z, v, g, x, scalars, interpret: bool):
+    """One leaf's inner update; all operands share one shape.  z, v, x
+    are f32 masters, y and g carry the compute dtype (f32 or bf16).
     scalars: (4,) f32 = [inv_gamma, lr, mu, alpha]."""
-    m = y.shape[0]
-    rows = m // BLOCK[1]
-    grid = (rows // BLOCK[0],)
-    shaped = lambda a: a.reshape(rows, BLOCK[1])
+    rows, cols = _view(y).shape
+    block, grid = _tiling(rows, cols)
     # index maps under PrefetchScalarGridSpec also receive the scalar ref
-    spec = pl.BlockSpec(BLOCK, lambda i, _s: (i, 0))
-    out_shape = [jax.ShapeDtypeStruct((rows, BLOCK[1]), a.dtype)
+    spec = pl.BlockSpec(block, lambda i, j, _s: (i, j))
+    out_shape = [jax.ShapeDtypeStruct((rows, cols), a.dtype)
                  for a in (y, z, v)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -67,13 +87,14 @@ def parle_update_flat(y, z, v, g, x, scalars, interpret: bool = True):
         in_specs=[spec] * 5,
         out_specs=[spec] * 3,
     )
-    y2, z2, v2 = pl.pallas_call(
+    outs = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
+        input_output_aliases={1: 0, 2: 1, 3: 2},     # y, z, v in place
         interpret=interpret,
-    )(scalars, shaped(y), shaped(z), shaped(v), shaped(g), shaped(x))
-    return y2.reshape(m), z2.reshape(m), v2.reshape(m)
+    )(scalars, *[_view(a) for a in (y, z, v, g, x)])
+    return tuple(o.reshape(y.shape) for o in outs)
 
 
 def _pack_scalars(*vals):
@@ -89,7 +110,10 @@ def _local_shard_wrap(call, shard_ctx, path, rep_shapes, shared_shape,
     Inside the algorithm's outer shard_map the replica axis is already
     manual and the "data"/"model" axes are auto: this nested shard_map
     makes them manual too for exactly the (elementwise) update, handing
-    the kernel local blocks.  ``rep_shapes`` leaves carry a leading
+    the kernel local blocks.  ``call(scalars, *leaves)`` takes the
+    scalars as its first (replicated) operand, never as a closure: a
+    value captured from the outer body lives on the outer body's mesh
+    and the nested body rejects it.  ``rep_shapes`` leaves carry a leading
     (local-)replica dim that stays unsharded; the optional
     ``shared_shape`` operand (xbar / elastic ref) has no replica dim.
     """
@@ -100,43 +124,30 @@ def _local_shard_wrap(call, shard_ctx, path, rep_shapes, shared_shape,
 
     spec = shard_ctx.leaf_spec(path_names(path), rep_shapes[0][1:])
     rep_spec = P(None, *spec)
-    in_specs = (rep_spec,) * len(rep_shapes)
+    in_specs = (P(),) + (rep_spec,) * len(rep_shapes)
     if shared_shape is not None:
         in_specs = in_specs + (spec,)
     return shard_map(call, shard_ctx.mesh, in_specs=in_specs,
                      out_specs=(rep_spec,) * num_out)
 
 
-def _leaf_call(flat_fn, leaf_group, scalars, interpret):
-    """Pad/flatten ONE group of same-shaped leaves, run the flat fused
-    kernel, cut the padding (padding lanes are discarded).  Leaf dtypes
-    pass through untouched — the kernels handle mixed precision (bf16
-    compute streams next to f32 masters) internally."""
-    ref = leaf_group[0]
-    shape, size = ref.shape, ref.size
-    pad = (-size) % BLOCK_ELEMS
-    fl = lambda a: jnp.pad(a.reshape(-1), (0, pad))
-    res = flat_fn(*[fl(l) for l in leaf_group], scalars,
-                  interpret=interpret)
-    cut = lambda a: a[:size].reshape(shape)
-    return tuple(cut(r) for r in res)
-
-
-def _leafwise(flat_fn, trees, scalars, num_out, interpret, shard_ctx=None):
-    """Apply a flat fused kernel leafwise over pytrees.  With a planner
-    ``shard_ctx`` each leaf's call runs under a nested shard_map over the
-    in-replica axes (block grid over the local shard)."""
+def _leafwise(leaf_fn, trees, scalars, num_out, interpret, shard_ctx=None):
+    """Apply a per-leaf fused kernel leafwise over pytrees.  With a
+    planner ``shard_ctx`` each leaf's call runs under a nested shard_map
+    over the in-replica axes (block grid over the local shard).  Leaf
+    dtypes pass through untouched — the kernels handle mixed precision
+    (bf16 compute streams next to f32 masters) internally."""
     flat0, treedef = jax.tree_util.tree_flatten_with_path(trees[0])
     leaves = [[l for _, l in flat0]] \
         + [treedef.flatten_up_to(t) for t in trees[1:]]
     outs = [[] for _ in range(num_out)]
     for (path, _), *leaf_group in zip(flat0, *leaves):
-        call = lambda *g: _leaf_call(flat_fn, g, scalars, interpret)
+        call = lambda sc, *g: leaf_fn(*g, sc, interpret=interpret)
         if shard_ctx is not None:
             call = _local_shard_wrap(
                 call, shard_ctx, path,
                 [l.shape for l in leaf_group], None, num_out)
-        res = call(*leaf_group)
+        res = call(scalars, *leaf_group)
         for acc, r in zip(outs, res):
             acc.append(r)
     un = jax.tree_util.tree_unflatten
@@ -144,10 +155,10 @@ def _leafwise(flat_fn, trees, scalars, num_out, interpret, shard_ctx=None):
 
 
 def parle_update_tree(y, z, v, g, x, *, inv_gamma, lr, mu, alpha,
-                      interpret: bool = True, shard_ctx=None):
+                      interpret: bool, shard_ctx=None):
     """Fused inner update (8a-8b) leafwise over pytrees."""
     scalars = _pack_scalars(inv_gamma, lr, mu, alpha)
-    return _leafwise(parle_update_flat, (y, z, v, g, x), scalars,
+    return _leafwise(parle_update_leaf, (y, z, v, g, x), scalars,
                      num_out=3, interpret=interpret, shard_ctx=shard_ctx)
 
 
@@ -171,69 +182,56 @@ def _sync_kernel(scal_ref, x_ref, z_ref, v_ref, xbar_ref, x_out, v_out,
         maybe_y_out[0][0] = x_new.astype(maybe_y_out[0].dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "y_dtype"))
-def parle_sync_flat(x, z, v, xbar, scalars, interpret: bool = True,
-                    y_dtype=None):
-    """x, z, v: (R, M) f32; xbar: (M,) f32 with M % BLOCK_ELEMS == 0;
-    scalars: (4,) f32 = [gamma_scale, inv_rho, lr, mu].
+def _replicated_call(kernel, reps, shared, scalars, out_dtypes, aliases,
+                     interpret):
+    """Run ``kernel`` over (R, *s) replica streams plus one shared s-shaped
+    stream, which stays at size M and is re-read per replica grid step —
+    never materialized at R*M.  Outputs are (R, *s) in ``out_dtypes``."""
+    r = reps[0].shape[0]
+    _, rows, cols = _view(reps[0], 1).shape
+    block, grid = _tiling(rows, cols)
+    spec = pl.BlockSpec((1,) + block, lambda a, i, j, _s: (a, i, j))
+    shared_spec = pl.BlockSpec(block, lambda a, i, j, _s: (i, j))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(r,) + grid,
+        in_specs=[spec] * len(reps) + [shared_spec],
+        out_specs=[spec] * len(out_dtypes),
+    )
+    outs = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((r, rows, cols), d)
+                   for d in out_dtypes],
+        input_output_aliases=aliases,
+        interpret=interpret,
+    )(scalars, *[_view(a, 1) for a in reps], _view(shared))
+    return tuple(o.reshape(reps[0].shape) for o in outs)
 
-    xbar is the (already all-reduced) replica mean: it stays at size M
-    and is re-read per replica grid step — never materialized at R*M,
-    so the sync's HBM budget is 3 R*M + M reads and 2 R*M writes.
+
+@functools.partial(jax.jit, static_argnames=("interpret", "y_dtype"))
+def parle_sync_leaf(x, z, v, xbar, scalars, interpret: bool,
+                    y_dtype=None):
+    """x, z, v: (R, *s) f32; xbar: s f32, the (already all-reduced)
+    replica mean, so the sync's HBM budget is 3 R*M + M reads and
+    2 R*M writes.  scalars: (4,) f32 = [gamma_scale, inv_rho, lr, mu].
 
     ``y_dtype``: when given and different from x's dtype, the kernel
     also emits the inner-loop reset ``y' = cast(x')`` as a third output
     — the mixed-precision compute copy, cast fused into the same pass.
     Returns (x', v') or (x', v', y').
     """
-    r, m = x.shape
-    rows = m // BLOCK[1]
-    grid = (r, rows // BLOCK[0])
-    shaped = lambda a: a.reshape(r, rows, BLOCK[1])
-    spec = pl.BlockSpec((1,) + BLOCK, lambda a, i, _s: (a, i, 0))
-    bar_spec = pl.BlockSpec(BLOCK, lambda a, i, _s: (i, 0))
     emit_y = y_dtype is not None and jnp.dtype(y_dtype) != x.dtype
     out_dtypes = [x.dtype, v.dtype] + ([jnp.dtype(y_dtype)] if emit_y else [])
-    out_shape = [jax.ShapeDtypeStruct((r, rows, BLOCK[1]), d)
-                 for d in out_dtypes]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[spec] * 3 + [bar_spec],
-        out_specs=[spec] * len(out_shape),
-    )
-    outs = pl.pallas_call(
-        _sync_kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(scalars, shaped(x), shaped(z), shaped(v),
-      xbar.reshape(rows, BLOCK[1]))
-    return tuple(o.reshape(r, m) for o in outs)
+    return _replicated_call(_sync_kernel, (x, z, v), xbar, scalars,
+                            out_dtypes, {1: 0, 3: 1}, interpret)
 
 
-def _shared_leaf_call(flat_fn, reps, shared, scalars, interpret, **kw):
-    """Pad/flatten ONE leaf group of (R, ...) streams + a shared (...)
-    stream, run the flat kernel, cut the padding.  Dtypes pass through
-    (mixed precision is the kernels' business); each output keeps the
-    dtype the kernel declared for it."""
-    lead = reps[0]
-    r = lead.shape[0]
-    size = shared.size
-    assert lead.size == r * size, (lead.shape, shared.shape)
-    pad = (-size) % BLOCK_ELEMS
-    fl = lambda a, n: jnp.pad(a.reshape(n, -1), ((0, 0), (0, pad)))
-    outs = flat_fn(*[fl(l, r) for l in reps], fl(shared, 1)[0],
-                   scalars, interpret=interpret, **kw)
-    cut = lambda a: a[:, :size].reshape(lead.shape)
-    return tuple(cut(o) for o in outs)
-
-
-def _replicated_shared_tree(flat_fn, rep_trees, shared_tree, scalars,
+def _replicated_shared_tree(leaf_fn, rep_trees, shared_tree, scalars,
                             interpret, num_out: int = 2, shard_ctx=None,
                             **kw):
-    """Shared leafwise driver for the (R, M)-streams + one shared
-    M-stream kernels (sync: xbar; elastic: ref).  With a planner
+    """Shared leafwise driver for the (R, ...)-streams + one shared
+    (...)-stream kernels (sync: xbar; elastic: ref).  With a planner
     ``shard_ctx`` each leaf runs under a nested shard_map over the
     in-replica axes: the kernel grids over the LOCAL shard and the
     shared stream stays at local-shard size too (sharded exactly like
@@ -245,13 +243,12 @@ def _replicated_shared_tree(flat_fn, rep_trees, shared_tree, scalars,
     outs = [[] for _ in range(num_out)]
     for (path, _), *group in zip(flat0, *rep_leaves, shared_leaves):
         *reps, shared = group
-        call = lambda *rs: _shared_leaf_call(flat_fn, rs[:-1], rs[-1],
-                                             scalars, interpret, **kw)
+        call = lambda sc, *rs: leaf_fn(*rs, sc, interpret=interpret, **kw)
         if shard_ctx is not None:
             call = _local_shard_wrap(
                 call, shard_ctx, path, [l.shape for l in reps],
                 shared.shape, num_out=num_out)
-        res = call(*reps, shared)
+        res = call(scalars, *reps, shared)
         for acc, o in zip(outs, res):
             acc.append(o)
     un = jax.tree_util.tree_unflatten
@@ -259,7 +256,7 @@ def _replicated_shared_tree(flat_fn, rep_trees, shared_tree, scalars,
 
 
 def parle_sync_tree(x, z, v, xbar, *, gamma_scale, inv_rho, lr, mu,
-                    interpret: bool = True, shard_ctx=None, y_dtype=None):
+                    interpret: bool, shard_ctx=None, y_dtype=None):
     """Fused sync update (8c-8d) leafwise over pytrees.
 
     x, z, v leaves carry the leading replica axis (R, ...); xbar leaves
@@ -270,7 +267,7 @@ def parle_sync_tree(x, z, v, xbar, *, gamma_scale, inv_rho, lr, mu,
     """
     scalars = _pack_scalars(gamma_scale, inv_rho, lr, mu)
     emit_y = y_dtype is not None and jnp.dtype(y_dtype) != jnp.float32
-    return _replicated_shared_tree(parle_sync_flat, (x, z, v), xbar,
+    return _replicated_shared_tree(parle_sync_leaf, (x, z, v), xbar,
                                    scalars, interpret,
                                    num_out=3 if emit_y else 2,
                                    shard_ctx=shard_ctx,
@@ -296,46 +293,23 @@ def _elastic_kernel(scal_ref, x_ref, v_ref, g_ref, ref_ref, x_out, v_out):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def elastic_update_flat(x, v, g, ref, scalars, interpret: bool = True):
-    """x, v, g: (R, M) f32; ref: (M,) f32 with M % BLOCK_ELEMS == 0;
-    scalars: (3,) f32 = [inv_rho, lr, mu].
-
-    ref is the shared reference variable: it stays at size M and is
-    re-read per replica grid step — never materialized at R*M, so the
-    worker step's HBM budget is 3 R*M + M reads and 2 R*M writes.
-    """
-    r, m = x.shape
-    rows = m // BLOCK[1]
-    grid = (r, rows // BLOCK[0])
-    shaped = lambda a: a.reshape(r, rows, BLOCK[1])
-    spec = pl.BlockSpec((1,) + BLOCK, lambda a, i, _s: (a, i, 0))
-    ref_spec = pl.BlockSpec(BLOCK, lambda a, i, _s: (i, 0))
-    out_shape = [jax.ShapeDtypeStruct((r, rows, BLOCK[1]), x.dtype)] * 2
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[spec] * 3 + [ref_spec],
-        out_specs=[spec] * 2,
-    )
-    x2, v2 = pl.pallas_call(
-        _elastic_kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(scalars, shaped(x), shaped(v), shaped(g),
-      ref.reshape(rows, BLOCK[1]))
-    return x2.reshape(r, m), v2.reshape(r, m)
+def elastic_update_leaf(x, v, g, ref, scalars, interpret: bool):
+    """x, v, g: (R, *s) f32; ref: s f32, the shared reference variable,
+    so the worker step's HBM budget is 3 R*M + M reads and 2 R*M writes.
+    scalars: (3,) f32 = [inv_rho, lr, mu]."""
+    return _replicated_call(_elastic_kernel, (x, v, g), ref, scalars,
+                            [x.dtype, v.dtype], {1: 0, 2: 1}, interpret)
 
 
 def elastic_update_tree(x, v, g, ref, *, inv_rho, lr, mu,
-                        interpret: bool = True, shard_ctx=None):
+                        interpret: bool, shard_ctx=None):
     """Fused Elastic-SGD worker update (7a) leafwise over pytrees.
 
     x, v, g leaves carry the leading replica axis (R, ...); ref leaves
     are the UN-broadcast reference variable of shape (...).
     """
     scalars = _pack_scalars(inv_rho, lr, mu)
-    return _replicated_shared_tree(elastic_update_flat, (x, v, g), ref,
+    return _replicated_shared_tree(elastic_update_leaf, (x, v, g), ref,
                                    scalars, interpret, shard_ctx=shard_ctx)
 
 
@@ -352,27 +326,31 @@ def _quant_ef_kernel(c_ref, q_out, s_out, e_out):
     1024-chunk + the error-feedback residual, in a single pass (1 read,
     ~1.25 writes of the stream)."""
     c = c_ref[0]                             # (8, 1024) f32
-    amax = jnp.max(jnp.abs(c), axis=-1)      # (8,)
+    amax = jnp.max(jnp.abs(c), axis=-1, keepdims=True)      # (8, 1)
     scale = jnp.where(amax == 0, 1.0, amax * (1.0 / 127.0))
-    q = jnp.clip(jnp.round(c / scale[:, None]), -127, 127)
-    deq = q * scale[:, None]
+    q = jnp.clip(jnp.round(c / scale), -127, 127)
+    deq = q * scale
     q_out[0] = q.astype(jnp.int8)
     s_out[0] = scale
     e_out[0] = c - deq
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def quantize_ef_flat(c, interpret: bool = True):
+def quantize_ef_flat(c, interpret: bool):
     """c: (R, M) f32 with M % BLOCK_ELEMS == 0.  Returns (q, scales, e):
-    q (R, M) int8, scales (R, M // 1024) f32, e = c - dequant(q) f32."""
+    q (R, M) int8, scales (R, M // 1024) f32, e = c - dequant(q) f32.
+
+    Inside the kernel the scales are an (R, rows, 1) column, one (8, 1)
+    block per grid step: the TPU tiles the last two block dims in
+    (8, 128) units or takes them whole."""
     r, m = c.shape
     rows = m // BLOCK[1]
     grid = (r, rows // BLOCK[0])
     spec = pl.BlockSpec((1,) + BLOCK, lambda a, i: (a, i, 0))
-    s_spec = pl.BlockSpec((1, BLOCK[0]), lambda a, i: (a, i))
+    s_spec = pl.BlockSpec((1, BLOCK[0], 1), lambda a, i: (a, i, 0))
     out_shape = [
         jax.ShapeDtypeStruct((r, rows, BLOCK[1]), jnp.int8),
-        jax.ShapeDtypeStruct((r, rows), jnp.float32),
+        jax.ShapeDtypeStruct((r, rows, 1), jnp.float32),
         jax.ShapeDtypeStruct((r, rows, BLOCK[1]), jnp.float32),
     ]
     q, s, e = pl.pallas_call(
@@ -383,7 +361,7 @@ def quantize_ef_flat(c, interpret: bool = True):
         out_shape=out_shape,
         interpret=interpret,
     )(c.reshape(r, rows, BLOCK[1]))
-    return q.reshape(r, m), s, e.reshape(r, m)
+    return q.reshape(r, m), s.reshape(r, rows), e.reshape(r, m)
 
 
 def _dequant_sync_kernel(scal_ref, x_ref, z_ref, v_ref, q_ref, s_ref,
@@ -396,7 +374,7 @@ def _dequant_sync_kernel(scal_ref, x_ref, z_ref, v_ref, q_ref, s_ref,
     inv_rho = scal_ref[1]
     lr = scal_ref[2]
     mu = scal_ref[3]
-    deq = q_ref[...].astype(jnp.float32) * s_ref[...][..., None]
+    deq = q_ref[...].astype(jnp.float32) * s_ref[...]   # (n, 8, 1) scales
     xbar = jnp.mean(deq, axis=0)             # (8, 1024)
     x = x_ref[0]
     g_x = gamma_scale * (x - z_ref[0]) + inv_rho * (x - xbar)
@@ -409,14 +387,14 @@ def _dequant_sync_kernel(scal_ref, x_ref, z_ref, v_ref, q_ref, s_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "y_dtype"))
-def parle_sync_dequant_flat(x, z, v, q, s, scalars, interpret: bool = True,
+def parle_sync_dequant_flat(x, z, v, q, s, scalars, interpret: bool,
                             y_dtype=None):
     """Fused dequantize + replica-mean + sync update.
 
     x, z, v: (R, M) f32 (R = local replicas); q: (n, M) int8 — the
     all-gathered per-replica payloads of ALL n global replicas; s:
-    (n, M // 1024) f32 per-chunk scales; scalars as parle_sync_flat.
-    Returns (x', v') or (x', v', y') like :func:`parle_sync_flat`.
+    (n, M // 1024) f32 per-chunk scales; scalars as parle_sync_leaf.
+    Returns (x', v') or (x', v', y') like :func:`parle_sync_leaf`.
     """
     r, m = x.shape
     n = q.shape[0]
@@ -425,7 +403,7 @@ def parle_sync_dequant_flat(x, z, v, q, s, scalars, interpret: bool = True,
     shaped = lambda a: a.reshape(r, rows, BLOCK[1])
     spec = pl.BlockSpec((1,) + BLOCK, lambda a, i, _s: (a, i, 0))
     q_spec = pl.BlockSpec((n,) + BLOCK, lambda a, i, _s: (0, i, 0))
-    s_spec = pl.BlockSpec((n, BLOCK[0]), lambda a, i, _s: (0, i))
+    s_spec = pl.BlockSpec((n, BLOCK[0], 1), lambda a, i, _s: (0, i, 0))
     emit_y = y_dtype is not None and jnp.dtype(y_dtype) != x.dtype
     out_dtypes = [x.dtype, v.dtype] + ([jnp.dtype(y_dtype)] if emit_y else [])
     out_shape = [jax.ShapeDtypeStruct((r, rows, BLOCK[1]), d)
@@ -442,7 +420,7 @@ def parle_sync_dequant_flat(x, z, v, q, s, scalars, interpret: bool = True,
         out_shape=out_shape,
         interpret=interpret,
     )(scalars, shaped(x), shaped(z), shaped(v),
-      q.reshape(n, rows, BLOCK[1]), s.reshape(n, rows))
+      q.reshape(n, rows, BLOCK[1]), s.reshape(n, rows, 1))
     return tuple(o.reshape(r, m) for o in outs)
 
 
@@ -463,24 +441,24 @@ def _apply_quant_kernel(scal_ref, x_ref, z_ref, v_ref, c_ref, e_ref,
     v_new = mu * v_ref[0] + g_x
     x_new = x - lr * (g_x + mu * v_new)
     ctot = x_new + e_ref[0]            # next payload, error fed back
-    amax = jnp.max(jnp.abs(ctot), axis=-1)
+    amax = jnp.max(jnp.abs(ctot), axis=-1, keepdims=True)   # (8, 1)
     scale = jnp.where(amax == 0, 1.0, amax * (1.0 / 127.0))
-    q = jnp.clip(jnp.round(ctot / scale[:, None]), -127, 127)
+    q = jnp.clip(jnp.round(ctot / scale), -127, 127)
     x_out[0] = x_new
     v_out[0] = v_new
     q_out[0] = q.astype(jnp.int8)
     s_out[0] = scale
-    e_out[0] = ctot - q * scale[:, None]
+    e_out[0] = ctot - q * scale
     if maybe_y_out:                    # fused y' = cast(x') (bf16 path)
         maybe_y_out[0][0] = x_new.astype(maybe_y_out[0].dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "y_dtype"))
-def parle_apply_quantize_flat(x, z, v, c, e, scalars, interpret: bool = True,
+def parle_apply_quantize_flat(x, z, v, c, e, scalars, interpret: bool,
                               y_dtype=None):
     """x, z, v, e: (R, M) f32; c: (M,) f32 with M % BLOCK_ELEMS == 0 —
     the carried staleness-1 consensus, re-read per replica grid step
-    like xbar in parle_sync_flat; scalars: (4,) f32 =
+    like xbar in parle_sync_leaf; scalars: (4,) f32 =
     [gamma_scale, inv_rho, lr, mu].
 
     Returns (x', v', q, s, e') or (x', v', q, s, e', y'): the applied
@@ -494,13 +472,13 @@ def parle_apply_quantize_flat(x, z, v, c, e, scalars, interpret: bool = True,
     shaped = lambda a: a.reshape(r, rows, BLOCK[1])
     spec = pl.BlockSpec((1,) + BLOCK, lambda a, i, _s: (a, i, 0))
     bar_spec = pl.BlockSpec(BLOCK, lambda a, i, _s: (i, 0))
-    s_spec = pl.BlockSpec((1, BLOCK[0]), lambda a, i, _s: (a, i))
+    s_spec = pl.BlockSpec((1, BLOCK[0], 1), lambda a, i, _s: (a, i, 0))
     emit_y = y_dtype is not None and jnp.dtype(y_dtype) != x.dtype
     out_shape = [
         jax.ShapeDtypeStruct((r, rows, BLOCK[1]), x.dtype),
         jax.ShapeDtypeStruct((r, rows, BLOCK[1]), v.dtype),
         jax.ShapeDtypeStruct((r, rows, BLOCK[1]), jnp.int8),
-        jax.ShapeDtypeStruct((r, rows), jnp.float32),
+        jax.ShapeDtypeStruct((r, rows, 1), jnp.float32),
         jax.ShapeDtypeStruct((r, rows, BLOCK[1]), jnp.float32),
     ] + ([jax.ShapeDtypeStruct((r, rows, BLOCK[1]), jnp.dtype(y_dtype))]
          if emit_y else [])
@@ -524,7 +502,7 @@ def parle_apply_quantize_flat(x, z, v, c, e, scalars, interpret: bool = True,
 
 
 def parle_apply_quantize_tree(x, z, v, c, e, *, gamma_scale, inv_rho, lr,
-                              mu, interpret: bool = True, y_dtype=None):
+                              mu, interpret: bool, y_dtype=None):
     """Fused overlap head leafwise over pytrees: x, z, v, e leaves carry
     the leading replica axis (R, ...); c leaves are the UN-broadcast
     carried consensus of shape (...).  Iterate outputs are cut back to
@@ -560,7 +538,7 @@ def parle_apply_quantize_tree(x, z, v, c, e, *, gamma_scale, inv_rho, lr,
 
 
 def parle_sync_dequant_tree(x, z, v, q_tree, s_tree, *, gamma_scale,
-                            inv_rho, lr, mu, interpret: bool = True,
+                            inv_rho, lr, mu, interpret: bool,
                             y_dtype=None):
     """Fused dequantize+mean+sync-update leafwise over pytrees.
 

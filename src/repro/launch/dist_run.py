@@ -38,6 +38,12 @@ consensus, not any per-worker layout):
 
 All jax imports are deferred: XLA_FLAGS (per-process device count) and
 the distributed runtime must be configured before jax initializes.
+
+The pod is a host-CPU stand-in for a multi-host pod: every worker runs
+with ``JAX_PLATFORMS=cpu``.  Its barrier collectives are gloo's, which
+are CPU-only, and N workers on one TPU host would each claim every chip.
+Across the chips of one host, one process drives them all:
+``python -m repro.launch.train --mesh replica:4``.
 """
 from __future__ import annotations
 
@@ -464,7 +470,7 @@ def _run_async_worker(args) -> list:
 
 
 def _spawn(args, worker_args, env_extra):
-    env = dict(os.environ, **env_extra)
+    env = dict(os.environ, **env_extra, JAX_PLATFORMS="cpu")
     # each worker leads its own process group/session so a wedged pod
     # can be killed as a unit (workers + any children they forked)
     return subprocess.Popen(
@@ -668,6 +674,7 @@ def _run_async_pod(args) -> int:
         decay=args.decay, consensus=consensus, start_round=start_round,
         liveness_s=args.liveness_s, ck_dir=ck_dir)
     print(json.dumps({"launch": "dist_run", "mode": "async",
+                      "platform": "cpu",
                       "nproc": args.nproc, "coord_port": coord_port,
                       "replicas": args.replicas or args.nproc,
                       "rounds": args.steps // args.L,
@@ -727,6 +734,17 @@ def _run_async_pod(args) -> int:
             sink.close()
 
 
+def _check_platform():
+    """Refuse a pod asked to run on anything but the host CPU."""
+    plat = os.environ.get("JAX_PLATFORMS") or "cpu"
+    if plat != "cpu":
+        raise SystemExit(
+            f"dist_run runs its pod on the host CPU, but JAX_PLATFORMS="
+            f"{plat!r}: its workers would each claim the same chips.  To "
+            f"train across the chips of one host run one process: python "
+            f"-m repro.launch.train --mesh replica:4")
+
+
 def main(argv=None):
     args = build_argparser().parse_args(argv)
     if args._worker >= 0:
@@ -735,13 +753,14 @@ def main(argv=None):
         else:
             run_worker(args)
         return 0
+    _check_platform()
     if args.sync_policy == "async":
         return _run_async_pod(args)
 
     spec = _mesh_spec(args)
     base = _base_args(args)
-    print(json.dumps({"launch": "dist_run", "nproc": args.nproc,
-                      "mesh": spec}), flush=True)
+    print(json.dumps({"launch": "dist_run", "platform": "cpu",
+                      "nproc": args.nproc, "mesh": spec}), flush=True)
 
     procs = [_spawn(args, base + ["--nproc", str(args.nproc),
                                   "--_worker", str(i)]

@@ -16,13 +16,19 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: sharding constraints keep their
+    GSPMD meaning (Explicit axes, the default, turn them into asserts)."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_parle_mesh(n_replicas: int, model_parallel: int = 16,
@@ -31,14 +37,14 @@ def make_parle_mesh(n_replicas: int, model_parallel: int = 16,
     nd = num_devices or len(jax.devices())
     assert nd % (n_replicas * model_parallel) == 0, (nd, n_replicas, model_parallel)
     data = nd // (n_replicas * model_parallel)
-    return jax.make_mesh((n_replicas, data, model_parallel),
-                         ("replica", "data", "model"))
+    return _auto_mesh((n_replicas, data, model_parallel),
+                      ("replica", "data", "model"))
 
 
 def make_host_mesh():
     """Degenerate mesh over whatever devices exist (CPU tests)."""
     nd = len(jax.devices())
-    return jax.make_mesh((nd, 1), ("data", "model"))
+    return _auto_mesh((nd, 1), ("data", "model"))
 
 
 def replica_axis_of(mesh: Mesh) -> str | None:
@@ -85,4 +91,4 @@ def make_mesh_from_spec(spec: str) -> Mesh:
                          f"{len(devices)} (hint: XLA_FLAGS="
                          f"--xla_force_host_platform_device_count={need})")
     return Mesh(np.asarray(devices[:need]).reshape(tuple(axes.values())),
-                tuple(axes))
+                tuple(axes), axis_types=(AxisType.Auto,) * len(axes))

@@ -46,6 +46,7 @@ from repro.models.model import build_model, cache_positions
 from repro.obs import Obs
 from repro.serving import (Engine, SamplingParams, make_naive_fns,
                            naive_generate)
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _prompt_lengths(args):
@@ -199,6 +200,7 @@ def main(argv=None):
                     help="write a Chrome-trace JSON (compile / prefill / "
                          "decode spans) here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

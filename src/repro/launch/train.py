@@ -30,6 +30,7 @@ from repro.models.model import build_model
 from repro.obs import Obs
 from repro.runtime import (CheckpointSpec, RoundRunner, emit_progress,
                            resolve_train_policy)
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def build_argparser():
@@ -121,16 +122,26 @@ def build_argparser():
 
 
 def main(argv=None):
-    args = build_argparser().parse_args(argv)
+    """Train from a command line; returns the progress history."""
+    enable_compile_cache()
+    return run(build_argparser().parse_args(argv))[1]
+
+
+def run(args, cfg=None):
+    """Train as ``args`` say; returns ``(deployable params, history)``.
+
+    ``cfg``: a ModelConfig to train in place of ``--arch``/``--smoke``
+    (a registered config cut to size, as chip_smoke.py does)."""
     if args.host_devices:
         import os
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.host_devices}")
     policy = resolve_train_policy(args)
-    cfg = get_config(args.arch)
-    if args.smoke:
-        cfg = smoke_variant(cfg)
+    if cfg is None:
+        cfg = get_config(args.arch)
+        if args.smoke:
+            cfg = smoke_variant(cfg)
     model = build_model(cfg)
     key = jax.random.PRNGKey(args.seed)
     params = model.init(key)
@@ -157,7 +168,20 @@ def main(argv=None):
                          batch_size=args.batch, seed=args.seed)
 
     obs = Obs(args.metrics_out, args.trace_out, process_name="train")
-    state = algo.init(params, pcfg)
+    # jitted: one fresh buffer per state leaf, so nothing is aliased (a
+    # donated round needs that) and no leaf is held twice.  On a mesh
+    # the state is built on its planner shardings: each device holds
+    # only its replicas and its 1/(data*model) of every leaf, so state
+    # too big for one device's HBM is loadable from step 0
+    state_sh = None
+    if mesh is not None:
+        from repro.sharding import partition
+        specs = algo.state_pspecs(raxis, params=jax.eval_shape(
+            lambda: params), mesh=mesh, cfg=pcfg)
+        state_sh = partition.shardings(mesh, specs)
+    state = jax.jit(lambda p: algo.init(p, pcfg),
+                    out_shardings=state_sh)(params)
+    params = jax.eval_shape(lambda: params)      # the planner needs shapes
     start = 0
     if args.resume:
         # resolve ONCE (directory -> newest valid checkpoint; corrupt
@@ -165,6 +189,8 @@ def main(argv=None):
         # and the counter stamp all read the SAME verified file
         args.resume = ckpt.resolve(args.resume)
         state = ckpt.restore(args.resume, state, algo=args.algo)
+        if state_sh is not None:
+            state = jax.device_put(state, state_sh)
         try:                    # continue the stream + checkpoint numbering
             start = ckpt.latest_step(args.resume)
         except FileNotFoundError:       # sidecar-less foreign checkpoint
@@ -172,18 +198,11 @@ def main(argv=None):
         # counters continue monotonically from the checkpoint's stamp
         obs.registry.restore_counters(ckpt.saved_metrics(args.resume))
     if mesh is not None:
-        from repro.sharding import partition, planner
+        from repro.sharding import planner
         step_fn = policy.make_step_fn(algo, model.loss, pcfg, mesh=mesh,
                                       replica_axis=raxis,
                                       use_kernel=args.use_kernel)
         inner_axes = planner.in_replica_axes(mesh, raxis)
-        if inner_axes:
-            # place the state on its planner shardings up front: each
-            # device holds 1/(data*model) of every leaf, so configs too
-            # big for one device's HBM are loadable from step 0
-            specs = algo.state_pspecs(raxis, params=params, mesh=mesh,
-                                      cfg=pcfg)
-            state = jax.device_put(state, partition.shardings(mesh, specs))
         print(json.dumps(obs.emit(
             "mesh", mesh=dict(mesh.shape), replica_axis=raxis,
             in_replica_axes=list(inner_axes),
@@ -223,7 +242,7 @@ def main(argv=None):
         algo=args.algo, arch=cfg.name,
         total_wall_s=round(time.time() - t0, 1))))
     obs.finalize()
-    return history
+    return final, history
 
 
 def _validate_replicas(args, pcfg, mesh, raxis):
@@ -259,7 +278,6 @@ def _run_rounds(args, algo, policy, pcfg, model, mesh, raxis, stream,
     (``RoundRunner.run_rounds`` owns staging/spans/counters/checkpoints
     — see repro/runtime/runner.py; this function no longer contains a
     step loop)."""
-    from repro.core.parle import dealias_state
     from repro.data.synthetic import make_round_batch_fn
 
     obs = runner.obs
@@ -277,7 +295,6 @@ def _run_rounds(args, algo, policy, pcfg, model, mesh, raxis, stream,
                                     use_kernel=args.use_kernel)
     stage = make_round_batch_fn(stream, L, args.batch, n,
                                 split=args.split_data)
-    state = dealias_state(state)     # donated rounds need distinct buffers
     return runner.run_rounds(
         state, round_fn, stage, start=start, rounds=rounds, L=L,
         tokens_per_round=L * args.batch * args.seq * n,

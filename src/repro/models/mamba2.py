@@ -105,7 +105,9 @@ def ssd_chunked(x, dt, A, B_mat, C_mat, chunk: int, h0=None):
     cb = jnp.einsum("bcin,bcjn->bcij", Cc, Bc)       # (B, nc, Q, Q)
     delta = cum[:, :, :, None, :] - cum[:, :, None, :, :]   # (B,nc,Q,Q,nh)
     mask = jnp.tril(jnp.ones((Q, Q), bool))
-    decay = jnp.where(mask[None, None, :, :, None], jnp.exp(delta), 0.0)
+    # mask BEFORE the exp: above the diagonal delta > 0 can overflow, and
+    # where(mask, inf, 0) has a NaN gradient (0 * inf)
+    decay = jnp.exp(jnp.where(mask[None, None, :, :, None], delta, -jnp.inf))
     scores = cb[..., None] * decay * dtc[:, :, None, :, :]  # (B,nc,Q,Q,nh)
     y_intra = jnp.einsum("bcijh,bcjhp->bcihp", scores, xc)
 

@@ -48,15 +48,10 @@ class CheckpointSpec(NamedTuple):
 
 def aot_with_span(obs, jitted, name, lower_args):
     """AOT-compile a jitted program under a ``compile`` span so compile
-    time is separated from the steady-state spans; falls back to the
-    jit-dispatch path (with a note event) if lowering is unsupported."""
-    try:
-        with obs.tracer.span(f"compile:{name}", cat="compile"):
-            return jitted.lower(*lower_args).compile()
-    except Exception as e:          # pragma: no cover - defensive
-        obs.emit("note", msg=f"AOT compile of {name} failed ({e}); "
-                 "falling back to jit dispatch")
-        return jitted
+    time is separated from the steady-state spans.  A compile error
+    propagates: there is no fallback path."""
+    with obs.tracer.span(f"compile:{name}", cat="compile"):
+        return jitted.lower(*lower_args).compile()
 
 
 def record_hlo_bytes(obs, compiled, mesh, pcfg, scope, ns="train"):
@@ -230,13 +225,17 @@ class RoundRunner:
 def emit_progress(obs, algo, state, metrics, step, rnd, t0):
     """ONE schema for every progress emit site (per-step and fused-round
     drivers): kind=train_progress with the same key set — ``round`` is
-    the number of completed Eq. 8 rounds in both.  Per-replica losses
-    (when the step emits them) land as labeled gauges."""
+    the number of completed Eq. 8 rounds in both, and a fused round adds
+    its per-step ``step_losses`` unrounded.  Per-replica losses (when the
+    step emits them) land as labeled gauges."""
     import numpy as np
     diag = {k: round(v, 4) for k, v in algo.diagnostics(state).items()}
+    extra = {}
+    if "losses" in metrics:             # a fused round: its L step losses
+        extra["step_losses"] = np.asarray(metrics["losses"]).tolist()
     rec = obs.emit("train_progress", step=step, round=rnd,
                    loss=round(float(metrics["loss"]), 4),
-                   wall_s=round(time.time() - t0, 1), diag=diag)
+                   wall_s=round(time.time() - t0, 1), diag=diag, **extra)
     if obs.enabled:
         obs.registry.gauge("train.loss").set(rec["loss"])
         for k, v in diag.items():

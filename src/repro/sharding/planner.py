@@ -224,9 +224,13 @@ def constrain_tree(tree, mesh: Mesh, lead: int = 0, policy: str = "fsdp_tp"):
         spec = _apply_policy(raw, policy)
         spec, _ = _sanitize(spec, shape, dict(mesh.shape), names, warn=True)
         full = P(*([None] * lead), *spec)
+        # inside a shard_map body a bare spec resolves against the body's
+        # context mesh (replica axis Manual); a NamedSharding over
+        # ``mesh`` would not match it
         return jax.lax.with_sharding_constraint(
-            leaf, NamedSharding(mesh, full))
+            leaf, full if in_body else NamedSharding(mesh, full))
 
+    in_body = not jax.sharding.get_abstract_mesh().empty
     return jax.tree_util.tree_map_with_path(fix, tree)
 
 

@@ -1,78 +1,32 @@
-"""Version-portability shims for the jax APIs that renamed underneath us.
-
-The container pins jax 0.4.37; newer releases renamed three things this
-repo touches.  Every call site goes through here so the skew lives in
-exactly one file:
-
-  * ``shard_map``          — moved ``jax.experimental.shard_map`` ->
-                             ``jax.shard_map``; kwarg ``check_rep`` ->
-                             ``check_vma``.
-  * ``tpu_compiler_params``— ``pltpu.TPUCompilerParams`` ->
-                             ``pltpu.CompilerParams``.
-  * ``use_mesh``           — ``with mesh:`` context ->
-                             ``jax.set_mesh`` / ``jax.sharding.use_mesh``.
-"""
+"""The one ``shard_map`` spelling every call site in this repo uses."""
 from __future__ import annotations
 
-import contextlib
-import inspect
-
 import jax
-
-try:                                     # jax >= 0.6
-    from jax import shard_map as _shard_map
-except ImportError:                      # jax <= 0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SM_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
+from jax.sharding import AxisType
 
 
 def shard_map(f, mesh, in_specs, out_specs, check: bool = False,
               auto: frozenset = frozenset()):
-    """``shard_map`` with replication checking toggled portably.
+    """``jax.shard_map`` with the replication check off by default.
 
-    ``check`` maps to ``check_vma`` (new) or ``check_rep`` (old) —
-    both default to True upstream, but every use in this repo wants the
-    check off (pmean inside a cond is not rep-invariant to the checker).
+    ``check`` maps to ``check_vma``: every use in this repo wants it off
+    (pmean inside a cond is not rep-invariant to the checker).
 
     ``auto``: mesh axes left to GSPMD *inside* the body (partial-manual
     shard_map) — the in-replica FSDP/TP axes of the planner-sharded
-    path.  Raises on jax builds whose shard_map lacks the parameter,
-    but only when a non-empty ``auto`` is actually requested.
+    path.  The body is manual over every other axis of ``mesh``.
+
+    Called while tracing another shard_map's body over the same axes
+    (the kernels' per-leaf wrap), the nested map runs on that body's
+    context mesh and makes manual only the axes still Auto there.
     """
-    kw = {}
-    if "check_vma" in _SM_PARAMS:
-        kw["check_vma"] = check
-    elif "check_rep" in _SM_PARAMS:
-        kw["check_rep"] = check
-    if auto:
-        if "auto" not in _SM_PARAMS:
-            raise NotImplementedError(
-                "this jax's shard_map has no `auto` parameter; the "
-                "composed replica+data/model mesh path needs it — use a "
-                "replica-only --mesh, or a jax with partial-manual "
-                "shard_map support")
-        kw["auto"] = auto
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (new) / ``pltpu.TPUCompilerParams`` (old)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
-@contextlib.contextmanager
-def use_mesh(mesh):
-    """Ambient-mesh context: ``jax.set_mesh`` where it exists, otherwise
-    the classic ``with mesh:`` context manager (jax <= 0.5)."""
-    setter = getattr(jax, "set_mesh", None) or \
-        getattr(jax.sharding, "use_mesh", None)
-    if setter is not None:
-        with setter(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    ctx = jax.sharding.get_abstract_mesh()
+    if (not ctx.empty and ctx.axis_names == mesh.axis_names
+            and AxisType.Manual in ctx.axis_types):
+        mesh = ctx
+    manual = {a for a, t in zip(mesh.axis_names, mesh.axis_types)
+              if t == AxisType.Manual}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check,
+                         axis_names=frozenset(mesh.axis_names)
+                         - set(auto) - manual)

@@ -35,6 +35,20 @@ def test_losses_parser_filters_tagged_lines():
     assert float.fromhex(recs[0]["loss_hex"]) == 6.0
 
 
+@pytest.mark.parametrize("platform,refused", [
+    ("", False), ("cpu", False), ("tpu", True)])
+def test_pod_refuses_a_non_cpu_platform(monkeypatch, platform, refused):
+    """The pod's workers run on the host CPU; asked for another platform
+    it refuses and points at the one-process mesh path."""
+    from repro.launch.dist_run import _check_platform
+    monkeypatch.setenv("JAX_PLATFORMS", platform)
+    if refused:
+        with pytest.raises(SystemExit, match="--mesh replica:4"):
+            _check_platform()
+    else:
+        _check_platform()
+
+
 @pytest.mark.slow
 def test_two_process_run_matches_single_process_bitwise():
     env = dict(os.environ)

@@ -89,10 +89,33 @@ def test_moe_grouped_dispatch_matches_flat(key):
     x = jax.random.normal(key, (2, 16, cfg.d_model))
     flat, _ = moe_mod.moe_forward(params, cfg, x)
     gcfg = dataclasses.replace(cfg, moe_groups=4)
-    from repro.utils.compat import use_mesh
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with use_mesh(mesh):
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         (AxisType.Auto, AxisType.Auto))
+    with jax.set_mesh(mesh):
         grouped, _ = jax.jit(
             lambda p, x: moe_mod.moe_forward(p, gcfg, x))(params, x)
     np.testing.assert_allclose(np.asarray(grouped), np.asarray(flat),
                                rtol=1e-5, atol=1e-6)
+
+
+def test_ssd_chunked_grads_finite_under_fast_decay(key):
+    """Steep per-step decay makes exp(cum_i - cum_j) overflow above the
+    chunk's diagonal; the masked entries must not turn the backward pass
+    into NaN (0 * inf).  mamba2 and zamba2 at published widths reach
+    such decays at initialization."""
+    from repro.models.mamba2 import ssd_chunked
+    B, T, nh, P, N = 1, 64, 2, 8, 4
+    ks = jax.random.split(key, 4)
+    x = jax.random.normal(ks[0], (B, T, nh, P))
+    dt = jnp.full((B, T, nh), 8.0)
+    A = jnp.full((nh,), -16.0)              # log decay -128 per step
+    Bm = jax.random.normal(ks[1], (B, T, N))
+    Cm = jax.random.normal(ks[2], (B, T, N))
+
+    def f(x, dt):
+        y, _ = ssd_chunked(x, dt, A, Bm, Cm, 64)
+        return jnp.sum(y)
+
+    for g in jax.grad(f, argnums=(0, 1))(x, dt):
+        assert np.isfinite(np.asarray(g)).all()

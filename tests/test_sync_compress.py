@@ -87,7 +87,8 @@ def test_dequant_update_kernel_matches_composed_oracle():
     got = parle_sync_dequant_flat(x, z, v, q,
                                   s.reshape(n, -1),
                                   jnp.asarray([1.0, 2.0, 0.1, 0.9],
-                                              jnp.float32))
+                                              jnp.float32),
+                                  interpret=True)
     for w, g in zip(want, got):
         np.testing.assert_allclose(np.asarray(w), np.asarray(g),
                                    rtol=1e-5, atol=1e-6)
