@@ -1,0 +1,94 @@
+"""Plain float32 reference of Parle's first rounds (Chaudhari et al.,
+2017, Eq. 8a-8d and the scoping of Eq. 9), replica by replica.
+
+Inner step k of replica a, on that replica's rows of step k:
+    g   = grad f(y) + (y - x) / gamma                          (8a)
+    v   = mu v + g ;  y <- y - lr (g + mu v)                   (Nesterov)
+    z   = alpha z + (1 - alpha) y                              (8b)
+After L inner steps, the sync:
+    xbar = mean_a x^a                                          (8d)
+    g_x  = (x - z) + (x - xbar) / rho                          (8c)
+    v_x  = mu v_x + g_x ;  x <- x - lr (g_x + mu v_x)
+    y, z <- x ;  v <- 0 ;  gamma, rho <- max(f * ., floor), f = 1 - 1/(2B)
+
+Returns what the benchmark compares: the replica-mean loss of every inner
+step, the norm of each replica's leaf of v_x after the first sync (the
+gradient the outer update gets), and the norm of each replica's leaf of
+the change of x over all the rounds followed.
+"""
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from bench.reference import models
+from bench.traffic import tokens as token_rows
+
+
+def leaf_norms(tree) -> dict:
+    """{path: norm} over a tree without a replica axis."""
+    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
+    return {jax.tree_util.keystr(p): float(jnp.sqrt(jnp.sum(jnp.square(l))))
+            for p, l in flat}
+
+
+def run(m: dict, job: dict, seed: int, rounds: int) -> dict:
+    h = job["parle"]
+    n, L, B, T = job["replicas"], job["L"], job["batch"], job["seq"]
+    lr, mu, alpha = h["lr"], h["momentum"], h["alpha"]
+    f = 1.0 - 1.0 / (2.0 * h["batches_per_epoch"])
+    V = m["vocab_size"]
+    with jax.default_matmul_precision("highest"):
+        x0 = jax.jit(lambda k: models.init(k, m))(jax.random.PRNGKey(seed))
+
+        @partial(jax.jit, donate_argnums=(0, 1, 2))
+        def inner(y, z, v, x, toks, labs, inv_gamma):
+            loss, g = jax.value_and_grad(models.loss)(y, m, toks, labs)
+            tm = jax.tree.map
+            gy = tm(lambda g, y, x: g + inv_gamma * (y - x), g, y, x)
+            v = tm(lambda v, gy: mu * v + gy, v, gy)
+            y = tm(lambda y, gy, v: y - lr * (gy + mu * v), y, gy, v)
+            z = tm(lambda z, y: alpha * z + (1 - alpha) * y, z, y)
+            return loss, y, z, v
+
+        @jax.jit
+        def sync(x, z, vx, xbar, inv_rho):
+            g = jax.tree.map(lambda x, z, xb: (x - z) + inv_rho * (x - xb),
+                             x, z, xbar)
+            vx = jax.tree.map(lambda v, g: mu * v + g, vx, g)
+            x = jax.tree.map(lambda x, g, v: x - lr * (g + mu * v), x, g, vx)
+            return x, vx
+
+        xs = [x0] * n
+        vxs = [jax.tree.map(jnp.zeros_like, x0)] * n
+        gamma, rho = h["gamma0"], h["rho0"]
+        losses = np.zeros((rounds * L, n))
+        grad = None
+        for r in range(rounds):
+            zs = []
+            for a in range(n):
+                y, z = (jax.tree.map(jnp.copy, xs[a]) for _ in range(2))
+                v = jax.tree.map(jnp.zeros_like, x0)
+                for k in range(L):
+                    step = r * L + k
+                    toks, labs = token_rows.rows(seed, step, a, n, B, T, V)
+                    loss, y, z, v = inner(y, z, v, xs[a], toks, labs,
+                                          1.0 / gamma)
+                    losses[step, a] = float(loss)
+                zs.append(z)
+                del y, v
+            xbar = jax.tree.map(lambda *t: sum(t) / n, *xs)
+            out = [sync(xs[a], zs[a], vxs[a], xbar, 1.0 / rho)
+                   for a in range(n)]
+            xs, vxs = [o[0] for o in out], [o[1] for o in out]
+            del zs, xbar, out
+            if r == 0:
+                grad = [leaf_norms(v) for v in vxs]
+            gamma = max(gamma * f, h["gamma_min"])
+            rho = max(rho * f, h["rho_min"])
+        change = [leaf_norms(jax.tree.map(jnp.subtract, x, x0)) for x in xs]
+    return {"losses": losses.mean(axis=1).tolist(), "grad": grad,
+            "change": change}

@@ -1,0 +1,69 @@
+"""The comparison that decides ``correct``, at a size a CPU test run holds:
+a sound run passes, and the lower-precision control and every fault a
+training cell can have fail it.  Each run skips the look for a chip and
+drives the rest of a run of the cell as ``BENCHMARK.json`` lists it
+(set-up, the first rounds, the reference), shrunk to a CPU size, with the
+timed path intact, switched to lower precision, or broken underneath
+(bench/tests/faults.py).
+
+The limits here are for this size, read on the CPU: above every sound
+reading, below every control and fault reading.  A cell's own limits, in
+``bench/limits/``, are read on the chip at the cell's size (PERF.md).
+"""
+import copy
+
+import jax
+import pytest
+
+from bench import harness
+from bench.run import run_cell
+from bench.tests import faults
+
+TRAIN_LIMITS = {"loss_gap": 1e-4, "grad_gap": 1e-3, "change_gap": 1e-3}
+
+
+def tiny_train(cell):
+    spec = copy.deepcopy(harness.cell_spec(cell))
+    m = spec["config"]["model"]
+    m.update(num_layers=2, d_model=64, vocab_size=128, ssm_state=16,
+             ssm_head_dim=16, ssm_chunk=16)
+    spec["config"]["reduced"] = ["num_layers", "d_model", "vocab_size",
+                                 "ssm_state", "ssm_head_dim", "ssm_chunk"]
+    t = spec["traffic"]
+    t.update(L=3, batch=2, seq=32)
+    fl = t["flags"]
+    for k, v in (("--L", "3"), ("--batch", "2"), ("--seq", "32")):
+        fl[fl.index(k) + 1] = v
+    spec["limits"] = {k: {"limit": v} for k, v in TRAIN_LIMITS.items()}
+    return spec
+
+
+def run(spec, seed=2**31 + 17, **kw):
+    chips = spec["workload"]["chips"]
+    out, checks, info = run_cell(spec["workload"]["name"], seed, 0.0,
+                                 False, devices=jax.devices()[:chips],
+                                 spec=spec, **kw)
+    return out["correct"], info["readings"]
+
+
+@pytest.fixture(scope="module", params=[w["name"] for w in harness.load_json(
+    harness.ROOT / "BENCHMARK.json")["workloads"]])
+def train_spec(request):
+    return tiny_train(request.param)
+
+
+def test_sound_run_is_correct(train_spec):
+    ok, readings = run(train_spec)
+    assert ok, readings
+
+
+def test_control_bf16_is_not_correct(train_spec):
+    ok, readings = run(train_spec, extra_flags=("--precision", "bf16"))
+    assert not ok, readings
+
+
+@pytest.mark.parametrize("fault", ["unchanged", "half_batch"])
+def test_fault_is_not_correct(train_spec, fault):
+    with faults.planted(fault):
+        ok, readings = run(train_spec)
+    assert not ok, readings

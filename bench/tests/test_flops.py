@@ -1,0 +1,46 @@
+"""bench/flops.py against counts made by hand."""
+import pytest
+
+from bench import flops
+
+MAMBA2 = {"family": "ssm", "num_layers": 1, "d_model": 2048,
+          "vocab_size": 4190, "ssm_state": 128, "ssm_head_dim": 64,
+          "ssm_expand": 2, "ssm_conv": 4, "ssm_chunk": 128}
+
+
+def test_one_mamba2_layer():
+    in_proj = 2 * 2048 * (2 * 4096 + 2 * 128 + 64)      # 34,865,152
+    conv = 2 * 4 * (4096 + 2 * 128)                      # 34,816
+    intra = 129 * (128 + 64 * 64)                        # 544,896
+    inter = 4 * 128 * 64 * 64                            # 2,097,152
+    out_proj = 2 * 4096 * 2048                           # 16,777,216
+    assert flops.ssm_layer_fwd(MAMBA2) == in_proj + conv + intra + inter \
+        + out_proj == 54_319_232
+
+
+def test_model_and_training_counts():
+    m = dict(MAMBA2, num_layers=4)
+    fwd = 4 * 54_319_232 + 2 * 2048 * 4190
+    assert flops.model_fwd(m) == fwd
+    assert flops.train_per_token(m) == 3 * fwd
+
+
+def test_one_parle_kernel_call():
+    # a (4, 2048) leaf, 2 replicas, f32: inner reads y, z, v, g, x and
+    # writes y, z, v; sync reads x, z, v and the mean, writes x, v
+    assert flops.parle_inner_bytes(4 * 2048, 2) == 8 * 2 * 8192 * 4
+    assert flops.parle_sync_bytes(4 * 2048, 2) == 11 * 8192 * 4
+
+
+def test_param_sizes_count_the_mamba2_share():
+    m = dict(MAMBA2, num_layers=4, norm_eps=1e-5)
+    per_layer = (2048 * 8512 + 4 * 4352 + 4352 + 64 * 3 + 2048 + 4096
+                 + 4096 * 2048)
+    assert sum(flops.param_sizes(m)) == 4 * per_layer + 2 * 4190 * 2048 \
+        + 2048
+
+
+def test_unknown_device_kind_is_an_error():
+    assert flops.peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
+    with pytest.raises(KeyError):
+        flops.peaks("TPU v9")
