@@ -1,0 +1,8 @@
+"""Device self time of the chunked SSD, forward and backward (the
+``ssd`` scope of ``models/mamba2.py::ssm_block_forward``), per inner
+step over the traced window (bench/scopes.py)."""
+from bench import scopes
+
+
+def read(art):
+    return scopes.per_step_ms(art, ("ssd",))

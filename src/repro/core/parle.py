@@ -26,6 +26,12 @@ Updates (Nesterov momentum mu=0.9 per Remark 2, none on the reference):
 Baselines: ``mode="entropy_sgd"`` is exactly Parle with n=1 (the elastic
 term vanishes identically — §2.1/§3); Elastic-SGD lives in
 core/elastic_sgd.py (per-step coupling, Eq. 7).
+
+Profiles: ``inner_step`` runs under ``jax.named_scope("parle_inner")``
+and the sync (``sync_step``, ``consensus_step``, ``overlap_head``, the
+flush and the async apply) under ``"parle_sync"``; XLA keeps the scope
+in the metadata of every op they lower to, so every round body below
+inherits the split without naming it.
 """
 from __future__ import annotations
 
@@ -111,6 +117,7 @@ def init_from_replicas(replica_params, cfg) -> ParleState:
 # Inner step (8a)-(8b)
 # ------------------------------------------------------------------
 
+@jax.named_scope("parle_inner")
 def inner_step(state: ParleState, grads, cfg, use_kernel: bool = False,
                lr_scale=1.0, shard_ctx=None) -> ParleState:
     """grads: pytree with leading replica axis = grad f(y^a) per replica.
@@ -252,6 +259,7 @@ def _quantized_sync_stats(x, e, method: str, axis_name, use_kernel: bool,
     return un(treedef, xbars), un(treedef, e_news)
 
 
+@jax.named_scope("parle_sync")
 def consensus_step(state: ParleState, xbar, cfg, *,
                    use_kernel: bool = False, lr_scale=1.0,
                    shard_ctx=None, payload=None) -> ParleState:
@@ -337,6 +345,7 @@ def _sync_stats(state: ParleState, cfg, axis_name, use_kernel, shard_ctx):
     return xbar, payload, e_new
 
 
+@jax.named_scope("parle_sync")
 def sync_step(state: ParleState, cfg, axis_name: str | None = None,
               use_kernel: bool = False, lr_scale=1.0,
               shard_ctx=None) -> ParleState:
@@ -383,6 +392,7 @@ def fused_step(state: ParleState, grads, cfg, use_kernel: bool = False,
 # the f32 local/replica-sharded paths.
 # ------------------------------------------------------------------
 
+@jax.named_scope("parle_sync")
 def overlap_head(state: ParleState, cfg, axis_name: str | None = None,
                  use_kernel: bool = False, lr_scale=1.0,
                  shard_ctx=None) -> ParleState:
@@ -471,6 +481,7 @@ def make_flush_fn(cfg, lr_schedule=None):
     resuming continues the overlapped trajectory exactly (flushing a
     checkpointed state and then resuming from it would double-apply)."""
 
+    @jax.named_scope("parle_sync")
     def flush(state):
         lr_scale = (lr_schedule(state.step - 1) if lr_schedule is not None
                     else 1.0)
@@ -1123,6 +1134,7 @@ def make_async_apply_fn(cfg, lr_schedule=None):
     (schedule(step - 1)).  ``e`` passes through — the caller installs
     the refreshed residual from :func:`async_contribution` first."""
 
+    @jax.named_scope("parle_sync")
     def apply(state, xbar):
         lr_scale = (lr_schedule(state.step - 1) if lr_schedule is not None
                     else 1.0)

@@ -180,6 +180,7 @@ def make_round_batch_fn(stream: TokenStream, L: int, batch_size: int,
                             stream.num_codebooks, split=split)
 
     @jax.jit
+    @jax.named_scope("stage")
     def stage(start_step):
         steps = start_step + jnp.arange(L)
         return jax.vmap(lambda s: jax.vmap(lambda a: one(s, a))(

@@ -111,6 +111,7 @@ def flash_attention(q, k, v, window: int = 0,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+        name="flash_attention",
         interpret=interpret,
     )(heads_first(q), heads_first(k), heads_first(v))
     return heads_first(out)

@@ -116,5 +116,6 @@ def paged_attention(q, k_pool, v_pool, table, lengths, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="paged_attention",
         interpret=interpret,
     )(table.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pool, v_pool)

@@ -359,6 +359,7 @@ def quantize_ef_flat(c, interpret: bool):
         in_specs=[spec],
         out_specs=[spec, s_spec, spec],
         out_shape=out_shape,
+        name="quantize_ef",
         interpret=interpret,
     )(c.reshape(r, rows, BLOCK[1]))
     return q.reshape(r, m), s.reshape(r, rows), e.reshape(r, m)
@@ -418,6 +419,7 @@ def parle_sync_dequant_flat(x, z, v, q, s, scalars, interpret: bool,
         _dequant_sync_kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
+        name="dequant_sync",
         interpret=interpret,
     )(scalars, shaped(x), shaped(z), shaped(v),
       q.reshape(n, rows, BLOCK[1]), s.reshape(n, rows, 1))
@@ -492,6 +494,7 @@ def parle_apply_quantize_flat(x, z, v, c, e, scalars, interpret: bool,
         _apply_quant_kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
+        name="apply_quantize",
         interpret=interpret,
     )(scalars, shaped(x), shaped(z), shaped(v),
       c.reshape(rows, BLOCK[1]), shaped(e))

@@ -115,6 +115,7 @@ def ssd_scan(x, dt, A, B_mat, C_mat, chunk: int = 128, *, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((Bsz, nh, T, P), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="ssd_scan",
         interpret=interpret,
     )(A.astype(jnp.float32), jnp.transpose(x, (0, 2, 1, 3)),
       jnp.transpose(dt, (0, 2, 1))[:, :, None, :], B_mat, C_mat)

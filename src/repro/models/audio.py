@@ -32,6 +32,7 @@ def init_params(key, cfg, dtype=jnp.float32):
     return p
 
 
+@jax.named_scope("embed")
 def _embed(params, cfg, tokens):
     """tokens: (B, K, T) -> (B, T, d) summed codebook embeddings."""
     B, K, T = tokens.shape

@@ -75,7 +75,8 @@ def _slice_layers(layers, start, size):
 def forward_hidden(params, cfg, tokens, remat=False, use_flash=False,
                    use_kernel=False):
     B, T = tokens.shape
-    x = params["embed"][tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
 
     def ssm_body(h, lp):

@@ -184,24 +184,29 @@ def ssm_block_forward(lp, cfg, x, h0=None, use_kernel=False):
     """x: (B, T, d) -> (B, T, d), final_state."""
     Bsz, T, d = x.shape
     di, N, nh, P = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_num_heads, cfg.ssm_head_dim
-    u = rms_norm(x, lp["ln"], cfg.norm_eps)
-    proj = jnp.einsum("btd,de->bte", u, lp["in_proj"])
-    z, xBC, dt = _split_proj(cfg, proj)
-    xBC = _causal_conv(xBC, lp["conv_w"], lp["conv_b"])
-    xs = xBC[..., :di].reshape(Bsz, T, nh, P)
-    B_mat = xBC[..., di:di + N]
-    C_mat = xBC[..., di + N:]
-    dt = softplus(dt + lp["dt_bias"])
-    A = -jnp.exp(lp["A_log"])
-    if use_kernel:
-        from repro.kernels import ops as kops
-        y, hf = kops.ssd_scan(xs, dt, A, B_mat, C_mat, cfg.ssm_chunk, h0=h0)
-    else:
-        y, hf = ssd_chunked(xs, dt, A, B_mat, C_mat, cfg.ssm_chunk, h0=h0)
-    y = y + lp["D"][None, None, :, None] * xs
-    y = y.reshape(Bsz, T, di)
-    y = rms_norm(y * silu(z), lp["norm"], cfg.norm_eps)
-    return x + jnp.einsum("bte,ed->btd", y, lp["out_proj"]), hf
+    with jax.named_scope("in_proj"):
+        u = rms_norm(x, lp["ln"], cfg.norm_eps)
+        proj = jnp.einsum("btd,de->bte", u, lp["in_proj"])
+    with jax.named_scope("conv"):
+        z, xBC, dt = _split_proj(cfg, proj)
+        xBC = _causal_conv(xBC, lp["conv_w"], lp["conv_b"])
+        xs = xBC[..., :di].reshape(Bsz, T, nh, P)
+        B_mat = xBC[..., di:di + N]
+        C_mat = xBC[..., di + N:]
+    with jax.named_scope("ssd"):
+        dt = softplus(dt + lp["dt_bias"])
+        A = -jnp.exp(lp["A_log"])
+        if use_kernel:
+            from repro.kernels import ops as kops
+            y, hf = kops.ssd_scan(xs, dt, A, B_mat, C_mat, cfg.ssm_chunk,
+                                  h0=h0)
+        else:
+            y, hf = ssd_chunked(xs, dt, A, B_mat, C_mat, cfg.ssm_chunk, h0=h0)
+        y = y + lp["D"][None, None, :, None] * xs
+    with jax.named_scope("out_proj"):
+        y = y.reshape(Bsz, T, di)
+        y = rms_norm(y * silu(z), lp["norm"], cfg.norm_eps)
+        return x + jnp.einsum("bte,ed->btd", y, lp["out_proj"]), hf
 
 
 def ssm_block_prefill(lp, cfg, x, h0, conv0, valid):
@@ -285,7 +290,8 @@ def init_params(key, cfg, dtype=jnp.float32):
 
 
 def forward_hidden(params, cfg, tokens, remat=False, use_kernel=False):
-    x = params["embed"][tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
 
     def body(h, lp):
         out, _ = ssm_block_forward(lp, cfg, h, use_kernel=use_kernel)

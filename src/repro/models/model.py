@@ -112,20 +112,24 @@ def with_cache_positions(cache, pos):
 def _lm_loss(hidden_fn, cfg):
     """Hidden-states + T-chunked CE: the (B, T, V) logits tensor is
     never materialized (V reaches 202k for llama4-scout)."""
+    @jax.named_scope("model")
     def loss(params, batch):
         h, aux = hidden_fn(params, batch)
         head = params["embed"].T if cfg.tie_embeddings else params["head"]
-        ce = chunked_cross_entropy(h, head, batch["labels"])
+        with jax.named_scope("head_loss"):
+            ce = chunked_cross_entropy(h, head, batch["labels"])
         return ce + aux, {"ce": ce, "aux": aux}
     return loss
 
 
 def _audio_loss(hidden_fn, cfg):
+    @jax.named_scope("model")
     def loss(params, batch):
         h, aux = hidden_fn(params, batch)            # (B, T, d)
         labels = batch["labels"].transpose(0, 2, 1)  # (B, T, K)
-        ce = chunked_cross_entropy(h, params["head"], labels,
-                                   num_streams=cfg.num_codebooks)
+        with jax.named_scope("head_loss"):
+            ce = chunked_cross_entropy(h, params["head"], labels,
+                                       num_streams=cfg.num_codebooks)
         return ce + aux, {"ce": ce, "aux": aux}
     return loss
 

@@ -110,7 +110,8 @@ def forward_hidden(params, cfg, tokens, use_flash=False, remat=False,
     """Returns (final-normed hidden (B, T, d), aux_loss) — pair with
     chunked_cross_entropy to avoid materializing (B, T, V) logits."""
     B, T = tokens.shape
-    x = params["embed"][tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
     if extra_embeds is not None:
         x = x + extra_embeds
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))

@@ -45,7 +45,8 @@ def forward_hidden(params, cfg, tokens, patch_embeds, use_flash=False,
     from repro.models.layers import rms_norm
     B, T = tokens.shape
     extra, mask = _merge(params, cfg, tokens, patch_embeds)
-    x = params["embed"][tokens] * mask + extra
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens] * mask + extra
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     h, aux = transformer.stack_forward(params, cfg, x, positions,
                                        use_flash=use_flash, remat=remat)

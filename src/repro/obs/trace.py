@@ -10,9 +10,18 @@ execution, the same discipline the benchmarks use (PR 4).  Compile
 time is its own span: wrap the AOT ``jit().lower().compile()`` call in
 ``tracer.span(name, cat="compile")`` so steady-state spans stay clean.
 
+Profiler clock: an enabled span also opens a
+``jax.profiler.TraceAnnotation`` of its name and attributes, closed
+after the ``block_until_ready``, so under ``jax.profiler.trace`` every
+span lies on the trace's host plane on the same clock as the device's
+ops.  ``Tracer.add_hlo_ops(compiled)`` writes the compiled program's
+map from HLO instruction to ``op_name`` (the ``jax.named_scope`` path)
+into the Chrome JSON as one ``hlo_ops`` metadata event, so that a
+device op in that trace can be put down to the scope that produced it.
+
 A disabled tracer hands out a shared no-op span — zero allocations,
-no timestamps, no ``block_until_ready`` — so un-instrumented runs are
-byte-for-byte the old code path.
+no timestamps, no ``block_until_ready``, no annotation — so
+un-instrumented runs are byte-for-byte the old code path.
 
 Chrome-trace mapping: every span is one complete event (``"ph": "X"``)
 with microsecond ``ts``/``dur`` relative to tracer construction;
@@ -23,6 +32,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import threading
 import time
 from typing import Any, List, Optional
@@ -51,7 +61,7 @@ NULL_SPAN = _NullSpan()
 
 class Span:
     __slots__ = ("tracer", "name", "cat", "attrs", "t_start", "t_end",
-                 "depth", "tid", "_block")
+                 "depth", "tid", "_block", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, attrs: dict):
         self.tracer = tracer
@@ -62,6 +72,7 @@ class Span:
         self.depth = 0
         self.tid = 0
         self._block: Any = None
+        self._annotation: Any = None
 
     def block(self, x) -> None:
         """Arm the span: ``__exit__`` blocks until ``x`` (any jax
@@ -72,7 +83,10 @@ class Span:
         self.attrs.update(attrs)
 
     def __enter__(self):
+        from jax.profiler import TraceAnnotation
         self.depth, self.tid = self.tracer._push()
+        self._annotation = TraceAnnotation(self.name, **self.attrs)
+        self._annotation.__enter__()
         self.t_start = time.perf_counter()
         return self
 
@@ -82,6 +96,8 @@ class Span:
             jax.block_until_ready(self._block)
             self._block = None
         self.t_end = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        self._annotation = None
         self.tracer._pop()
         self.tracer._record(self)
         return False
@@ -106,6 +122,7 @@ class Tracer:
         self.t0 = time.perf_counter()
         self._tls = threading.local()
         self._tids: dict = {}
+        self._hlo_mapped: dict = {}     # id -> program already mapped
 
     # -- span lifecycle ------------------------------------------------
     def span(self, name: str, cat: str = "", **attrs):
@@ -140,6 +157,21 @@ class Tracer:
             "args": dict(span.attrs, depth=span.depth),
         })
 
+    def add_hlo_ops(self, program) -> None:
+        """Record one ``hlo_ops`` metadata event for a compiled program
+        (anything with ``as_text``): ``{hlo_module, ops: {instruction:
+        op_name}}``, parsed from its optimized HLO.  Once per program,
+        and only where the tracer collects events."""
+        if (not (self.enabled and self.collect)
+                or not hasattr(program, "as_text")
+                or id(program) in self._hlo_mapped):
+            return
+        self._hlo_mapped[id(program)] = program
+        module, ops = hlo_op_names(program.as_text())
+        self.events.append({"name": "hlo_ops", "ph": "M", "pid": self.pid,
+                            "tid": 0,
+                            "args": {"hlo_module": module, "ops": ops}})
+
     # -- export --------------------------------------------------------
     def to_chrome(self) -> dict:
         meta = []
@@ -155,3 +187,21 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_chrome(), f)
             f.write("\n")
+
+
+_MODULE = re.compile(r"^HloModule ([^\s,]+)")
+_INSTRUCTION = re.compile(
+    r'^\s+(?:ROOT )?%?([^\s=]+) = .*metadata=\{[^}]*?op_name="([^"]*)"')
+
+
+def hlo_op_names(text: str):
+    """(module name, {instruction: op_name}) of an optimized HLO module's
+    text.  Instruction names are unique in a module, so the map holds the
+    instructions of every computation: a device op event names one."""
+    m = _MODULE.search(text)
+    ops = {}
+    for line in text.splitlines():
+        op = _INSTRUCTION.match(line)
+        if op:
+            ops[op.group(1)] = op.group(2)
+    return (m.group(1) if m else ""), ops

@@ -95,8 +95,10 @@ class RoundRunner:
     def _save(self, state, gstep: int):
         ck = self.checkpoint
         path = f"{ck.dir}/step{gstep:06d}.npz"
-        ckpt.save(path, state, step=gstep, meta={"arch": ck.arch},
-                  algo=ck.algo, metrics=self.obs.registry.counter_stamp())
+        with self.obs.tracer.span("checkpoint", step=gstep):
+            ckpt.save(path, state, step=gstep, meta={"arch": ck.arch},
+                      algo=ck.algo,
+                      metrics=self.obs.registry.counter_stamp())
         self.obs.emit("checkpoint", step=gstep, path=path)
 
     def _ckpt_enabled(self) -> bool:
@@ -115,7 +117,9 @@ class RoundRunner:
         on the historical cadence (every ``progress_every`` steps and on
         the first step), printed, and collected into the returned
         history.  ``on_step(i, metrics, sp)`` runs inside the step span,
-        before the blocking read, for driver-specific emission."""
+        before the blocking read, for driver-specific emission.
+        Spans: ``step``, with ``stage`` (the batch) inside it, then
+        ``progress`` and ``checkpoint``."""
         obs, ns = self.obs, self.ns
         history = []
         if aot and obs.enabled:
@@ -124,11 +128,13 @@ class RoundRunner:
             step_fn = aot_with_span(obs, step_fn, "step",
                                     (state, batch_fn(start)))
             record_hlo_bytes(obs, step_fn, mesh, pcfg, scope="step", ns=ns)
+        obs.tracer.add_hlo_ops(step_fn)
         for i in range(start, start + steps):
             if pre_step is not None:
                 pre_step(i)
             with obs.tracer.span("step", cat=span_cat, step=i + 1) as sp:
-                batch = batch_fn(i)
+                with obs.tracer.span("stage", step=i + 1):
+                    batch = batch_fn(i)
                 state, metrics = step_fn(state, batch)
                 if on_step is not None:
                     on_step(i, metrics, sp)
@@ -142,8 +148,9 @@ class RoundRunner:
                     sp.dur_s * 1e3)
             if progress is not None and ((i + 1) % progress_every == 0
                                          or i == start):
-                rec = progress(i + 1, (i + 1) // L, state, metrics)
-                print(json.dumps(rec), flush=True)
+                with obs.tracer.span("progress", step=i + 1):
+                    rec = progress(i + 1, (i + 1) // L, state, metrics)
+                    print(json.dumps(rec), flush=True)
                 history.append(rec)
             if self._ckpt_enabled() and (i + 1) % self.checkpoint.every == 0:
                 self._save(state, i + 1)
@@ -164,17 +171,23 @@ class RoundRunner:
         Instrumented: the program is AOT-compiled under a ``compile``
         span, every round is a ``round`` span that ends on
         ``block_until_ready`` (staging of the next round happens INSIDE
-        the span, before the block, so double-buffering is preserved),
-        and the sync policy's ``flush_fn`` is a ``sync_flush`` span +
-        ``staleness_flush`` event.  ``post_round(state, r, gstep,
-        metrics) -> state`` runs after the round's results are on host —
-        the async policy's coordinator exchange lives there."""
+        the span, before the block, so double-buffering is preserved;
+        it is a ``stage`` span of its own), the progress callback is a
+        ``progress`` span and a save a ``checkpoint`` span, and the sync
+        policy's ``flush_fn`` is a ``sync_flush`` span +
+        ``staleness_flush`` event.  A compiled ``round_fn`` has its
+        instructions' scopes recorded (``Tracer.add_hlo_ops``).
+        ``post_round(state, r, gstep, metrics) -> state`` runs after the
+        round's results are on host — the async policy's coordinator
+        exchange lives there."""
         obs, ns = self.obs, self.ns
         history = []
-        nxt = stage_fn(start)
+        with obs.tracer.span("stage", step=start + L):
+            nxt = stage_fn(start)
         if aot and obs.enabled and rounds:
             round_fn = aot_with_span(obs, round_fn, "round", (state, nxt))
             record_hlo_bytes(obs, round_fn, mesh, pcfg, scope="round", ns=ns)
+        obs.tracer.add_hlo_ops(round_fn)
         for r in range(rounds):
             if pre_round is not None:
                 pre_round(r)
@@ -183,7 +196,8 @@ class RoundRunner:
             with obs.tracer.span("round", round=r + 1, step=gstep) as sp:
                 state, metrics = round_fn(state, cur)   # async dispatch
                 if r + 1 < rounds:
-                    nxt = stage_fn(start + (r + 1) * L)  # prefetch r+1
+                    with obs.tracer.span("stage", step=gstep + L):
+                        nxt = stage_fn(gstep)            # prefetch r+1
                 sp.block(metrics)
             obs.registry.counter(f"{ns}.steps").inc(L)
             obs.registry.counter(f"{ns}.rounds").inc()
@@ -197,8 +211,9 @@ class RoundRunner:
                 on_round(r, gstep, metrics)
             if progress is not None and ((r + 1) % progress_every == 0
                                          or r == 0):
-                rec = progress(gstep, r + 1, state, metrics)
-                print(json.dumps(rec), flush=True)
+                with obs.tracer.span("progress", step=gstep):
+                    rec = progress(gstep, r + 1, state, metrics)
+                    print(json.dumps(rec), flush=True)
                 history.append(rec)
             # a round advances L steps at once: checkpoint whenever it
             # CROSSES a checkpoint_every boundary, not only on exact
