@@ -1,10 +1,11 @@
 """Jitted public wrappers for the Pallas kernels.
 
 On a TPU backend the kernels compile for the chip; on any other backend
-they run in interpret mode.  Models call these through
-``use_flash=True`` / ``use_kernel=True`` flags; the default model path is
-the pure-XLA reference implementation, which is also the correctness
-oracle.
+they run in interpret mode.  The Mamba2 block takes the fused SSD on a
+TPU by itself (``models/mamba2.py::ssm_block_forward``), with the pure-jnp
+``ssd_chunked`` as its oracle and its path elsewhere; attention and the
+Parle updates call their kernels through ``use_flash=True`` /
+``use_kernel=True``, with the pure-XLA paths as default and oracle.
 """
 from __future__ import annotations
 
@@ -33,12 +34,10 @@ def paged_attention(q, k_pool, v_pool, table, lengths):
                                interpret=_interpret())
 
 
-def ssd_scan(x, dt, A, B_mat, C_mat, chunk: int = 128, h0=None):
-    if h0 is not None:
-        # kernel path starts from zero state; fall back to the jnp
-        # chunked implementation when resuming from a prefix state
-        from repro.models.mamba2 import ssd_chunked
-        return ssd_chunked(x, dt, A, B_mat, C_mat, chunk, h0=h0)
+def ssd_scan(x, dt, A, B_mat, C_mat, chunk: int = 128):
+    """The differentiable fused SSD from a zero state: x (B, T, nh*P),
+    dt (B, T, nh), A (nh,), B/C (B, T, N), T a multiple of ``chunk``.
+    Returns y (B, T, nh*P) and the final state (B, nh, N, P)."""
     return _ssd.ssd_scan(x, dt, A, B_mat, C_mat, chunk=chunk,
                          interpret=_interpret())
 

@@ -72,15 +72,14 @@ def _slice_layers(layers, start, size):
     return jax.tree.map(lambda a: jax.lax.slice_in_dim(a, start, start + size, axis=0), layers)
 
 
-def forward_hidden(params, cfg, tokens, remat=False, use_flash=False,
-                   use_kernel=False):
+def forward_hidden(params, cfg, tokens, remat=False, use_flash=False):
     B, T = tokens.shape
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
 
     def ssm_body(h, lp):
-        out, _ = mamba2.ssm_block_forward(lp, cfg, h, use_kernel=use_kernel)
+        out, _ = mamba2.ssm_block_forward(lp, cfg, h)
         return out, None
 
     if remat:
@@ -95,9 +94,9 @@ def forward_hidden(params, cfg, tokens, remat=False, use_flash=False,
     return rms_norm(x, params["ln_f"], cfg.norm_eps), jnp.zeros((), jnp.float32)
 
 
-def forward(params, cfg, tokens, remat=False, use_flash=False, use_kernel=False):
+def forward(params, cfg, tokens, remat=False, use_flash=False):
     h, aux = forward_hidden(params, cfg, tokens, remat=remat,
-                            use_flash=use_flash, use_kernel=use_kernel)
+                            use_flash=use_flash)
     return jnp.einsum("btd,dv->btv", h, params["head"]), aux
 
 

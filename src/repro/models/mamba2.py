@@ -7,8 +7,9 @@ The selective state space recurrence per head h (state N, head dim P):
 
 computed with the chunked SSD algorithm: quadratic attention-like math
 inside chunks of length Q = cfg.ssm_chunk, a linear recurrence across
-chunk states.  ``ssd_chunked`` here is the pure-jnp oracle that
-kernels/ssd_scan.py mirrors in Pallas.
+chunk states.  ``ssd_chunked`` here is the pure-jnp oracle, and the path
+off the TPU and from a carried state; on a TPU the block takes the fused
+Pallas forward and backward of kernels/ssd_scan.py (``_fused_ssd``).
 
 Single group (B, C shared across heads), depthwise causal conv of width
 ``ssm_conv`` over the xBC streams, gated RMSNorm before out-projection —
@@ -21,6 +22,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as kops
+from repro.kernels import ssd_scan as ssd_kernel
 from repro.models.layers import dense_init, rms_norm, silu, softplus
 from repro.utils.scan import layer_unroll
 
@@ -180,7 +183,17 @@ def _causal_conv(xBC, w, b, prefix=None):
     return silu(out + b)
 
 
-def ssm_block_forward(lp, cfg, x, h0=None, use_kernel=False):
+def _fused_ssd(cfg, T, h0) -> bool:
+    """Whether the block takes the fused Pallas SSD: on a TPU, the
+    backend it is compiled for, from a zero state, over whole chunks of
+    widths the kernels tile (``kernels/ssd_scan.py::fits``)."""
+    return (h0 is None and T % cfg.ssm_chunk == 0
+            and ssd_kernel.fits(cfg.ssm_num_heads, cfg.ssm_head_dim,
+                                cfg.ssm_chunk)
+            and jax.default_backend() == "tpu")
+
+
+def ssm_block_forward(lp, cfg, x, h0=None):
     """x: (B, T, d) -> (B, T, d), final_state."""
     Bsz, T, d = x.shape
     di, N, nh, P = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_num_heads, cfg.ssm_head_dim
@@ -190,21 +203,21 @@ def ssm_block_forward(lp, cfg, x, h0=None, use_kernel=False):
     with jax.named_scope("conv"):
         z, xBC, dt = _split_proj(cfg, proj)
         xBC = _causal_conv(xBC, lp["conv_w"], lp["conv_b"])
-        xs = xBC[..., :di].reshape(Bsz, T, nh, P)
+        xs = xBC[..., :di]
         B_mat = xBC[..., di:di + N]
         C_mat = xBC[..., di + N:]
     with jax.named_scope("ssd"):
         dt = softplus(dt + lp["dt_bias"])
         A = -jnp.exp(lp["A_log"])
-        if use_kernel:
-            from repro.kernels import ops as kops
-            y, hf = kops.ssd_scan(xs, dt, A, B_mat, C_mat, cfg.ssm_chunk,
-                                  h0=h0)
+        if _fused_ssd(cfg, T, h0):
+            # heads stay in the (B, T, nh*P) layout, D repeated per lane
+            y, hf = kops.ssd_scan(xs, dt, A, B_mat, C_mat, cfg.ssm_chunk)
+            y = y + jnp.repeat(lp["D"], P) * xs
         else:
+            xs = xs.reshape(Bsz, T, nh, P)
             y, hf = ssd_chunked(xs, dt, A, B_mat, C_mat, cfg.ssm_chunk, h0=h0)
-        y = y + lp["D"][None, None, :, None] * xs
+            y = (y + lp["D"][None, None, :, None] * xs).reshape(Bsz, T, di)
     with jax.named_scope("out_proj"):
-        y = y.reshape(Bsz, T, di)
         y = rms_norm(y * silu(z), lp["norm"], cfg.norm_eps)
         return x + jnp.einsum("bte,ed->btd", y, lp["out_proj"]), hf
 
@@ -289,12 +302,12 @@ def init_params(key, cfg, dtype=jnp.float32):
     }
 
 
-def forward_hidden(params, cfg, tokens, remat=False, use_kernel=False):
+def forward_hidden(params, cfg, tokens, remat=False):
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
 
     def body(h, lp):
-        out, _ = ssm_block_forward(lp, cfg, h, use_kernel=use_kernel)
+        out, _ = ssm_block_forward(lp, cfg, h)
         return out, jnp.zeros((), jnp.float32)
 
     if remat:
@@ -304,9 +317,8 @@ def forward_hidden(params, cfg, tokens, remat=False, use_kernel=False):
     return rms_norm(x, params["ln_f"], cfg.norm_eps), jnp.zeros((), jnp.float32)
 
 
-def forward(params, cfg, tokens, remat=False, use_kernel=False):
-    h, aux = forward_hidden(params, cfg, tokens, remat=remat,
-                            use_kernel=use_kernel)
+def forward(params, cfg, tokens, remat=False):
+    h, aux = forward_hidden(params, cfg, tokens, remat=remat)
     return jnp.einsum("btd,dv->btv", h, params["head"]), aux
 
 
@@ -322,8 +334,7 @@ def init_cache(cfg, batch, dtype=jnp.float32, num_layers=None) -> SSMCache:
     )
 
 
-def prefill(params, cfg, tokens, cache: SSMCache, use_kernel=False,
-            valid=None):
+def prefill(params, cfg, tokens, cache: SSMCache, valid=None):
     """Absorb a prompt; returns logits + populated state cache.
 
     ``valid``: optional () int32 — positions >= valid are padding (the
@@ -346,8 +357,7 @@ def prefill(params, cfg, tokens, cache: SSMCache, use_kernel=False,
     else:
         def body(h, inp):
             lp, h0 = inp
-            out, hf = ssm_block_forward(lp, cfg, h, h0=h0,
-                                        use_kernel=use_kernel)
+            out, hf = ssm_block_forward(lp, cfg, h, h0=h0)
             # conv cache = last W-1 raw xBC inputs of this layer
             u = rms_norm(h, lp["ln"], cfg.norm_eps)
             proj = jnp.einsum("btd,de->bte", u[:, -(cfg.ssm_conv - 1):],

@@ -99,19 +99,103 @@ def test_flash_attention_dtypes(dtype, key):
 ])
 @pytest.mark.parametrize("N,P", [(16, 32), (64, 64)])
 def test_ssd_scan_vs_naive(T, chunk, N, P, key):
-    B, nh = 2, 3
+    B, nh = 2, 4            # whole 128-lane groups of heads at P 32 and 64
     ks = jax.random.split(key, 5)
     x = jax.random.normal(ks[0], (B, T, nh, P)) * 0.5
     dt = jax.nn.softplus(jax.random.normal(ks[1], (B, T, nh)))
     A = -jnp.exp(jax.random.normal(ks[2], (nh,)) * 0.3)
     Bm = jax.random.normal(ks[3], (B, T, N)) * 0.5
     Cm = jax.random.normal(ks[4], (B, T, N)) * 0.5
-    yk, hk = ops.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk)
+    yk, hk = ops.ssd_scan(x.reshape(B, T, nh * P), dt, A, Bm, Cm,
+                          chunk=chunk)
     yr, hr = ref.ssd_scan(x, dt, A, Bm, Cm)
-    np.testing.assert_allclose(np.asarray(yk), np.asarray(yr),
-                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(yk).reshape(B, T, nh, P),
+                               np.asarray(yr), rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(hk), np.asarray(hr),
                                rtol=1e-4, atol=1e-4)
+
+
+# (replicas or None, B, T, chunk, nh, P, N, fast decay)
+_SSD_VJP_CASES = {
+    # 3 chunks; 2 blocks of 16 heads, each 8 lane groups of 2 heads
+    "chunks_and_head_blocks": (None, 1, 96, 32, 32, 64, 16, False),
+    "batch": (None, 3, 64, 32, 4, 32, 8, False),
+    # the Parle round's vmap over a leading replica axis
+    "replicas_vmap": (2, 1, 64, 32, 8, 64, 16, False),
+    # dt |A| = 128 a step: exp(cum_i - cum_j) overflows above the diagonal
+    # unless masked before the exp
+    "fast_decay": (None, 1, 64, 32, 2, 64, 4, True),
+}
+_SSD_OUTS = ("y", "h", "dx", "ddt", "dA", "dB", "dC")
+# max |error| / max |value| of the bfloat16-operand branch against float32
+# ssd_chunked: <= 0.0065 over four keys; with the in-chunk cumulative sums
+# rounded to bfloat16 as well it reads >= 0.035
+BF16_SSD_ERR = 0.015
+
+
+def _ssd_vjp(side, case, key):
+    """y, the final state and the full VJP (dx, ddt, dA, dB_mat, dC_mat)
+    of the fused op (``side`` "fused") or of ``ssd_chunked``."""
+    from repro.models.mamba2 import ssd_chunked
+    R, B, T, chunk, nh, P, N, fast = _SSD_VJP_CASES[case]
+    lead = (R,) if R else ()
+    ks = jax.random.split(key, 7)
+    x = jax.random.normal(ks[0], lead + (B, T, nh * P)) * 0.5
+    dt = jax.nn.softplus(jax.random.normal(ks[1], lead + (B, T, nh)))
+    A = -jnp.exp(jax.random.normal(ks[2], lead + (nh,)) * 0.3)
+    if fast:
+        dt, A = jnp.full_like(dt, 8.0), jnp.full_like(A, -16.0)
+    Bm = jax.random.normal(ks[3], lead + (B, T, N)) * 0.5
+    Cm = jax.random.normal(ks[4], lead + (B, T, N)) * 0.5
+    cts = (jax.random.normal(ks[5], lead + (B, T, nh * P)),
+           jax.random.normal(ks[6], lead + (B, nh, N, P)))
+
+    def chunked(x, dt, A, Bm, Cm):
+        y, h = ssd_chunked(x.reshape(B, T, nh, P), dt, A, Bm, Cm, chunk)
+        return y.reshape(B, T, nh * P), h
+
+    def fused(x, dt, A, Bm, Cm):
+        return ops.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk)
+
+    def vjp(f):
+        def g(x, dt, A, Bm, Cm, cts):
+            out, pull = jax.vjp(f, x, dt, A, Bm, Cm)
+            return out + pull(cts)
+        return jax.vmap(g) if R else g
+
+    f = fused if side == "fused" else chunked
+    return [np.asarray(a) for a in vjp(f)(x, dt, A, Bm, Cm, cts)]
+
+
+@pytest.mark.parametrize("case", sorted(_SSD_VJP_CASES))
+def test_ssd_scan_vjp_matches_chunked(case, key):
+    """The fused op's y, final state and full VJP (dx, ddt, dA, dB_mat,
+    dC_mat) against ``jax.vjp`` of the pure-jnp ``ssd_chunked``."""
+    got = _ssd_vjp("fused", case, key)
+    want = _ssd_vjp("chunked", case, key)
+    for name, a, b in zip(_SSD_OUTS, got, want):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.shape == b.shape and np.isfinite(a).all(), name
+        # dA sums over every position: both sides round that sum in f32
+        atol = (5e-4 if name == "dA" else 2e-5) * np.abs(b).max()
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=atol, err_msg=name)
+
+
+def test_ssd_scan_bf16_dots_match_chunked(key):
+    """The branch a TPU compiles (bfloat16 dot operands, float32
+    accumulation), interpreted under a bfloat16
+    ``default_matmul_precision``: every output stays within bfloat16
+    rounding of float32 ``ssd_chunked``, and differs from the float32
+    branch, so the bfloat16 branch is the one that ran."""
+    case = "chunks_and_head_blocks"
+    want = _ssd_vjp("chunked", case, key)
+    f32 = _ssd_vjp("fused", case, key)
+    with jax.default_matmul_precision("bfloat16"):
+        got = _ssd_vjp("fused", case, key)
+    for name, a, a32, b in zip(_SSD_OUTS, got, f32, want):
+        assert np.isfinite(a).all() and not np.array_equal(a, a32), name
+        err = np.abs(a - b).max() / np.abs(b).max()
+        assert err < BF16_SSD_ERR, (name, err)
 
 
 @pytest.mark.slow

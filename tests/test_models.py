@@ -119,3 +119,41 @@ def test_ssd_chunked_grads_finite_under_fast_decay(key):
 
     for g in jax.grad(f, argnums=(0, 1))(x, dt):
         assert np.isfinite(np.asarray(g)).all()
+
+
+def test_fused_ssd_trains_like_chunked_under_the_ssd_scope(monkeypatch, key):
+    """A small Mamba2 through the fused Pallas SSD (interpreted here; on a
+    TPU the block takes it by itself): the loss and every parameter
+    gradient match the ``ssd_chunked`` path, and the ops of both
+    directions of the fused op sit in the ``ssd`` scope that
+    ``ssd_ms.train`` reads."""
+    from bench.scopes import scope_of
+    from repro.configs.base import ModelConfig
+    from repro.models import mamba2
+    from repro.obs.trace import hlo_op_names
+    cfg = ModelConfig(name="t-ssm", family="ssm", num_layers=2, d_model=64,
+                      num_heads=0, num_kv_heads=0, d_ff=0, vocab_size=128,
+                      ssm_state=16, ssm_head_dim=32, ssm_chunk=16)
+    model = build_model(cfg)
+    params = model.init(key)
+    batch = make_batch(cfg, key, batch=2, seq=32)
+    loss = lambda p: model.loss(p, batch)[0]               # noqa: E731
+    want_l, want_g = jax.value_and_grad(loss)(params)
+    monkeypatch.setattr(mamba2, "_fused_ssd",
+                        lambda cfg, T, h0: h0 is None
+                        and T % cfg.ssm_chunk == 0)
+    got_l, got_g = jax.value_and_grad(loss)(params)
+    np.testing.assert_allclose(float(got_l), float(want_l), rtol=1e-6)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got_g)[0],
+                            jax.tree.leaves(want_g)):
+        a, b = np.asarray(a), np.asarray(b)
+        np.testing.assert_allclose(a, b, rtol=1e-4,
+                                   atol=1e-5 * np.abs(b).max(),
+                                   err_msg=jax.tree_util.keystr(path))
+    _, ops = hlo_op_names(jax.jit(jax.grad(loss)).lower(params).compile()
+                          .as_text())
+    fused = [v for v in ops.values() if "jit(ssd_scan)" in v]
+    assert any("ssd_fwd" in v and "transpose" not in v for v in fused)
+    assert any("ssd_bwd" in v and "transpose(" in v for v in fused)
+    for v in fused:
+        assert scope_of(v) == "ssd", v

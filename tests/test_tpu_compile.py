@@ -7,8 +7,13 @@ shapes the TPU's (8, 128) tiling refuses, primitives Mosaic cannot lower
 — at no chip time.  Shapes: a 2048 x 8192 leaf for the Parle kernels,
 qwen2.5-3b attention heads (16 query / 2 kv heads of 128) for flash and
 paged attention, mamba2-1.3b SSD heads (64 heads of 64, state 128) for
-the scan.
+the scan, whose forward and backward also compile as the benchmark's
+training cells run them: 2,048 tokens, vmapped over 2 Parle replicas.
+A Mamba2 block's gradient compiles on both sides of its choice of SSD:
+the fused op at zamba2-1.2b's widths (state 64), ``ssd_chunked`` at the
+smoke widths (chunks of 32, which the kernels do not tile).
 """
+import dataclasses
 import os
 
 import jax
@@ -16,10 +21,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config, smoke_variant
 from repro.kernels import flash_attention as fa
 from repro.kernels import paged_attention as pa
 from repro.kernels import parle_update as pu
 from repro.kernels import ssd_scan as ssd
+from repro.models import mamba2
 
 LEAF = (2048, 8192)
 M = LEAF[0] * LEAF[1]
@@ -51,6 +58,13 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _ssd_vjp(x, dt, A, B_mat, C_mat, dy):
+    """The fused SSD's forward and backward, as a training step takes them."""
+    f = lambda *a: ssd.ssd_scan(*a, chunk=128, interpret=False)[0]  # noqa: E731
+    y, pull = jax.vjp(f, x, dt, A, B_mat, C_mat)
+    return (y,) + pull(dy)
+
+
 def _cases():
     """name -> (kernel call, [(shape, dtype)] operands)."""
     leaf, rep = [(LEAF, F32)], [((R,) + LEAF, F32)]
@@ -58,6 +72,10 @@ def _cases():
     s4, s3 = [((4,), F32)], [((3,), F32)]
     q_heads = [((1, 2048, 16, 128), BF16)] * 3        # GQA expanded
     pool = [((256, 16, 2, 128), BF16)] * 2             # (P, ps, KV, hd)
+    # (R, B, T, nh*P), dt (R, B, T, nh), A (R, nh), B/C (R, B, T, N), dy
+    ssd_rep = [((R, 1, 2048, 4096), F32), ((R, 1, 2048, 64), F32),
+               ((R, 64), F32), ((R, 1, 2048, 128), F32),
+               ((R, 1, 2048, 128), F32), ((R, 1, 2048, 4096), F32)]
     return {
         "parle_update_leaf": (
             lambda *a: pu.parle_update_leaf(*a, interpret=False),
@@ -94,8 +112,13 @@ def _cases():
             [((8, 16, 128), BF16)] + pool + [((8, 16), I32), ((8,), I32)]),
         "ssd_scan": (
             lambda *a: ssd.ssd_scan(*a, chunk=128, interpret=False),
-            [((2, 512, 64, 64), F32), ((2, 512, 64), F32), ((64,), F32),
+            [((2, 512, 4096), F32), ((2, 512, 64), F32), ((64,), F32),
              ((2, 512, 128), F32), ((2, 512, 128), F32)]),
+        "ssd_scan_replicas_fwd": (
+            jax.vmap(lambda *a: ssd.ssd_scan(*a, chunk=128,
+                                             interpret=False)),
+            ssd_rep[:5]),
+        "ssd_scan_replicas_vjp": (jax.vmap(_ssd_vjp), ssd_rep),
     }
 
 
@@ -105,4 +128,40 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
             for s, d in operands]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if name == "ssd_scan_replicas_vjp":        # both directions are kernels
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+# config, tokens, whether the block takes the fused SSD
+_SSD_BLOCKS = {
+    "zamba2_fused": (lambda: get_config("zamba2-1.2b"), 2048, True),
+    "smoke_chunked": (lambda: smoke_variant(get_config("mamba2-1.3b")), 512,
+                      False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_SSD_BLOCKS))
+def test_ssd_block_compiles_for_v5e(name, one_chip, monkeypatch):
+    """One Mamba2 block's gradient, with the backend answering "tpu" as on
+    the chip, so ``_fused_ssd`` and the kernels' interpret switch choose
+    what they would there."""
+    make, T, fused = _SSD_BLOCKS[name]
+    cfg = dataclasses.replace(make(), num_layers=1)
+    assert mamba2._fused_ssd(cfg, T, None) is False     # CPU backend here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert mamba2._fused_ssd(cfg, T, None) is fused
+    lp = jax.eval_shape(lambda: mamba2.init_ssm_layer(jax.random.PRNGKey(0),
+                                                      cfg))
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in jax.tree.leaves(lp)]
+    x = jax.ShapeDtypeStruct((1, T, cfg.d_model), F32, sharding=one_chip)
+
+    def loss(leaves, x):
+        lp_ = jax.tree.unflatten(jax.tree.structure(lp), leaves)
+        return mamba2.ssm_block_forward(lp_, cfg, x)[0].sum()
+
+    text = jax.jit(jax.grad(loss)).lower(args, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        2 if fused else 0)
