@@ -1,15 +1,20 @@
 """Operations and bytes from shapes, and the chip's peaks.
 
-Model FLOPs count the matmuls and the state space model's contractions
-that the forward pass needs (a multiply-add is 2), over the causal half
-where a product is masked; a backward pass costs twice its forward.  The
-embedding lookup, norms, gates and recomputation do not count.
-Kernel bytes are what a call reads and writes, from its operand shapes.
+Model FLOPs come from the configuration's architecture module
+(``train_flops_per_token``), which counts the matmuls and the sequence
+mixer's contractions that the forward pass needs (a multiply-add is 2),
+over the causal half where a product is masked; a backward pass costs
+twice its forward.  The embedding lookup, norms, gates and recomputation
+do not count.  Kernel bytes are what a call reads and writes, from its
+operand shapes.
 """
 from __future__ import annotations
 
 import json
+import math
 from pathlib import Path
+
+from bench import harness
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
@@ -23,42 +28,18 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def ssm_layer_fwd(m: dict) -> float:
-    """Forward FLOPs per token of one Mamba2 layer (chunked SSD)."""
-    d, N, P, W = m["d_model"], m["ssm_state"], m["ssm_head_dim"], m["ssm_conv"]
-    di = m["ssm_expand"] * d
-    nh, Q = di // P, m["ssm_chunk"]
-    in_proj = 2 * d * (2 * di + 2 * N + nh)
-    conv = 2 * W * (di + 2 * N)
-    # within a chunk each token meets (Q + 1) / 2 earlier ones on average:
-    # C.B over N, then the weighted sum over P for every head
-    intra = 2 * (Q + 1) / 2 * (N + nh * P)
-    # the chunk's state update and its read-out: N x P per head each
-    inter = 2 * 2 * N * P * nh
-    out_proj = 2 * di * d
-    return in_proj + conv + intra + inter + out_proj
+def train_per_token(conf: dict) -> float:
+    """Forward and backward FLOPs per trained token of configuration
+    ``conf``, from its architecture module."""
+    return harness.architecture(conf).train_flops_per_token(conf["model"])
 
 
-def model_fwd(m: dict) -> float:
-    """Forward FLOPs per token of the whole model: its Mamba2 layers and
-    the head."""
-    return (m["num_layers"] * ssm_layer_fwd(m)
-            + 2 * m["d_model"] * m["vocab_size"])
-
-
-def train_per_token(m: dict) -> float:
-    """Forward and backward FLOPs per trained token."""
-    return 3 * model_fwd(m)
-
-
-def param_sizes(m: dict) -> list[int]:
-    """Element count of every parameter leaf."""
-    import math
-
+def param_sizes(conf: dict) -> list[int]:
+    """Element count of every parameter leaf of configuration ``conf``,
+    from its architecture module's ``init``."""
     import jax
-
-    from bench.reference import models
-    shapes = jax.eval_shape(lambda k: models.init(k, m),
+    arch = harness.architecture(conf)
+    shapes = jax.eval_shape(lambda k: arch.init(k, conf["model"]),
                             jax.ShapeDtypeStruct((2,), "uint32"))
     return [math.prod(s.shape) for s in jax.tree.leaves(shapes)]
 

@@ -16,6 +16,8 @@ from pathlib import Path
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 RESULTS = ROOT / "results" / "bench"
+# where a configuration's ``reference`` names its architecture module
+ARCHITECTURES = BENCH / "reference"
 
 
 def process_age_s() -> float:
@@ -138,14 +140,32 @@ def device_info(devices, peak_bytes=None) -> dict:
             "count": len(devices), "memory_peak_bytes": int(peak_bytes)}
 
 
-def load_metric_reader(name: str):
-    """``bench/metrics/<name>.py``'s ``read(art) -> float | None``."""
-    path = BENCH / "metrics" / f"{name}.py"
+def _load(prefix: str, name: str, path: Path):
+    if not path.is_file():
+        raise FileNotFoundError(f"no {path} for {name!r}")
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
+        prefix + name.replace(".", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_metric_reader(name: str):
+    """``bench/metrics/<name>.py``'s ``read(art) -> float | None``."""
+    return _load("bench_metric_", name,
+                 BENCH / "metrics" / f"{name}.py").read
+
+
+def architecture(conf: dict):
+    """The architecture module that configuration ``conf`` names under
+    ``reference``: ``<ARCHITECTURES>/<reference>.py``, with ``init(key, m)``
+    and ``loss(p, m, tokens, labels)`` (the plain float32 reference),
+    ``train_flops_per_token(m)``, ``SCOPES`` (the program's named scopes
+    inside ``model``) and ``CPU_SIZE`` (the model keys a CPU test shrinks,
+    with their values), where ``m`` is the configuration's ``model``.
+    ``m["vocab_size"]`` bounds the token rows (bench/traffic/tokens.py)."""
+    name = conf["reference"]
+    return _load("bench_arch_", name, ARCHITECTURES / f"{name}.py")
 
 
 def read_per_layer(entries, art) -> dict:

@@ -7,10 +7,14 @@ state made on the device in one jitted call from the seed, and the round
 compiled ahead of time.  It then drives that one round program through
 ``RoundRunner.run_rounds`` for the cell's first rounds: they are the
 warm-up and what the reference is compared with.  The window is one more
-``run_rounds`` call of as many whole rounds as fill ``--seconds``.
+``run_rounds`` call of as many whole rounds as fill ``--seconds``, whose
+losses are read ``AHEAD_S`` seconds of rounds late (``_LateReader``), so
+that the device has that much work queued while the host waits and a
+host stall shorter than it costs no device time.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import gc
 import math
@@ -76,6 +80,35 @@ def norm_gap(prog: dict, ref: list) -> tuple[float, str, list]:
     return worst, where, skipped
 
 
+AHEAD_S = 6.0    # seconds of rounds queued ahead of the one read
+
+
+class _LateReader:
+    """``run_rounds``' ``on_round`` hook for the window: keeps each round's
+    losses on the device and reads them ``lag`` rounds late, so that the
+    host never waits on the round it has just dispatched."""
+
+    def __init__(self, lag: int):
+        self.lag = lag
+        self.pending = collections.deque()
+        self.losses = []         # each round's step losses, in order
+        self.read_at = []        # harness.age() when each was read
+
+    def __call__(self, r, gstep, metrics):
+        self.pending.append(metrics["losses"])
+        if len(self.pending) > self.lag:
+            self._read()
+
+    def _read(self):
+        import numpy as np
+        self.losses.append(np.asarray(self.pending.popleft()).tolist())
+        self.read_at.append(harness.age())
+
+    def drain(self):
+        while self.pending:
+            self._read()
+
+
 def run(ctx) -> dict:
     import jax
 
@@ -135,11 +168,12 @@ def run(ctx) -> dict:
     def progress(step, rnd, st, metrics):
         return emit_progress(obs, algo, st, metrics, step, rnd, t_wall0)
 
-    def rounds(state, start, count):
+    def rounds(state, start, count, progress=progress, on_round=None):
         return runner.run_rounds(
             state, compiled, stage, start=start, rounds=count, L=L,
             tokens_per_round=tokens_per_round, pcfg=pcfg,
             progress_every=max(1, args.log_every // L), progress=progress,
+            on_round=on_round,
             flush_fn=policy.make_flush_fn(algo, pcfg), aot=False)
 
     # -- the first rounds: warm-up, and what the reference follows --------
@@ -158,10 +192,22 @@ def run(ctx) -> dict:
     t["warmup"] = harness.age()
 
     # -- the window --------------------------------------------------------
+    # The window's work is fixed by the steadiest warm-up round (the first
+    # may carry a first execution's cost, any one a host stall).
     # --seconds 0: the readings alone, no window (bench/tests/readings.py)
-    R = max(1, round(ctx.seconds / round_s[-1])) if ctx.seconds else 0
+    round_est = min(round_s[1:] or round_s)
+    R = max(1, round(ctx.seconds / round_est)) if ctx.seconds else 0
     if ctx.trace:
         R = min(R, job["trace_rounds"])
+    late = _LateReader(max(1, round(AHEAD_S / round_est)))
+
+    def window(state):
+        """R rounds, read late; the clock stops once all of them are done."""
+        state, _ = rounds(state, check * L, R, progress=None, on_round=late)
+        late.drain()
+        jax.block_until_ready(state)
+        return state
+
     c2 = ctx.counter.snapshot()
     setup_s = harness.age()
     t_w0 = harness.age()
@@ -169,24 +215,23 @@ def run(ctx) -> dict:
         with jax.profiler.trace(str(ctx.out / "xprof")), \
                 jax.profiler.TraceAnnotation(bench_trace.WINDOW):
             t_w0 = harness.age()
-            state, hist = rounds(state, check * L, R)
+            state = window(state)
             t_w1 = harness.age()
     else:
-        state, hist = rounds(state, check * L, R)
+        state = window(state)
         t_w1 = harness.age()
     c3 = ctx.counter.snapshot()
     window_s = t_w1 - t_w0
-    failed = sum(1 for h in hist
-                 if not all(map(math.isfinite, h["step_losses"])))
+    failed = sum(1 for l in late.losses if not all(map(math.isfinite, l)))
     peak = harness.device_info(ctx.devices)["memory_peak_bytes"]
     if ctx.trace:
         obs.finalize()
-    del state, compiled, runner, obs, hist
+    del state, compiled, runner, obs
     gc.collect()
 
     # -- the reference, once the program's state is freed -----------------
     t_ref = harness.age()
-    ref = ref_parle.run(spec["config"]["model"], job, seed, check)
+    ref = ref_parle.run(spec["config"], job, seed, check)
     ref_s = harness.age() - t_ref
     loss_gap = max(abs(p - q) / abs(q) for p, q in zip(losses, ref["losses"]))
     grad_gap, grad_at, grad_skipped = norm_gap(grad, ref["grad"])
@@ -204,6 +249,9 @@ def run(ctx) -> dict:
                   "setup_s": setup_s},
         "compiles_in_window": c3["compiles"] - c2["compiles"],
         "rounds": R, "round_s_warmup": round_s, "window_s": window_s,
+        "rounds_read_late": late.lag,
+        "window_read_s": [b - a for a, b in zip([t_w0] + late.read_at,
+                                                late.read_at)],
         "reference_s": ref_s, "worst_grad_leaf": grad_at,
         "worst_change_leaf": change_at,
         "leaves_left_out": {"grad": grad_skipped, "change": change_skipped},
@@ -225,5 +273,5 @@ def run(ctx) -> dict:
                 "tokens_per_round": tokens_per_round, "chips": chips,
                 "window_s": window_s, "spans": spans,
                 "xprof": ctx.out / "xprof", "devices": ctx.devices,
-                "model": spec["config"]["model"], "job": job},
+                "config": spec["config"], "job": job},
     }

@@ -16,7 +16,7 @@ def read(art):
     if t <= 0:
         return None
     n, L = art["args"].replicas, art["args"].L
-    sizes = flops.param_sizes(art["model"])
+    sizes = flops.param_sizes(art["config"])
     per_round = sum(L * flops.parle_inner_bytes(s, n)
                     + flops.parle_sync_bytes(s, n) for s in sizes)
     hbm = flops.peaks(art["devices"][0].device_kind)["hbm_bytes_per_s"]

@@ -1,17 +1,20 @@
-"""Plain float32 reference of the benchmark's model family, written from
-the paper's equations in straightforward ``jax.numpy``: no kernels, no
-chunked scan, no cache, no batching tricks.  Run it under
-``jax.default_matmul_precision("highest")``: on a TPU a float32 matmul
-otherwise runs in bfloat16 passes.
+"""The benchmark's Mamba2 architecture module: a configuration whose
+``reference`` is ``mamba2`` finds here its plain reference, its FLOP
+count, the program's scopes inside ``model`` and its size for a CPU test.
 
-``ssm``, Mamba2 (arXiv:2405.21060, Sec. 6-7): pre-norm block, one
-in-projection to [z, xBC, dt], a depthwise causal conv of width W with
-SiLU over xBC, the selective state space model with a single group (B
-and C shared by all heads), a D skip, a gated RMSNorm and an
-out-projection.  The SSM is evaluated in its quadratic (dual) form over
-the whole sequence: y_t = sum_{s<=t} (C_t . B_s) exp(sum_{r=s+1..t} dt_r
-A) dt_s x_s, which is the recurrence h_t = exp(dt_t A) h_{t-1} + dt_t B_t
-x_t^T, y_t = C_t . h_t unrolled, with no chunking.
+The reference is float32, written from the paper's equations in
+straightforward ``jax.numpy``: no kernels, no chunked scan, no cache, no
+batching tricks.  Run it under ``jax.default_matmul_precision("highest")``:
+on a TPU a float32 matmul otherwise runs in bfloat16 passes.
+
+Mamba2 (arXiv:2405.21060, Sec. 6-7): pre-norm block, one in-projection
+to [z, xBC, dt], a depthwise causal conv of width W with SiLU over xBC,
+the selective state space model with a single group (B and C shared by
+all heads), a D skip, a gated RMSNorm and an out-projection.  The SSM is
+evaluated in its quadratic (dual) form over the whole sequence: y_t =
+sum_{s<=t} (C_t . B_s) exp(sum_{r=s+1..t} dt_r A) dt_s x_s, which is the
+recurrence h_t = exp(dt_t A) h_{t-1} + dt_t B_t x_t^T, y_t = C_t . h_t
+unrolled, with no chunking.
 
 ``init`` draws the seeded weights the benchmark defines for a
 configuration, with the same random streams as the system under test
@@ -25,6 +28,46 @@ import jax
 import jax.numpy as jnp
 
 HEAD_BLOCK = 8          # SSM heads per block of the quadratic form
+
+# the program's named scopes inside ``model`` (models/model.py,
+# models/mamba2.py), innermost wins
+SCOPES = ("embed", "in_proj", "conv", "ssd", "out_proj", "head_loss")
+
+# the model keys a CPU test shrinks, and their values there
+CPU_SIZE = {"num_layers": 2, "d_model": 64, "vocab_size": 128,
+            "ssm_state": 16, "ssm_head_dim": 16, "ssm_chunk": 16}
+
+
+# ------------------------------------------------------------------
+# FLOPs (the convention of bench/flops.py)
+# ------------------------------------------------------------------
+
+def layer_fwd_flops(m) -> float:
+    """Forward FLOPs per token of one Mamba2 layer (chunked SSD)."""
+    d, N, P, W = m["d_model"], m["ssm_state"], m["ssm_head_dim"], m["ssm_conv"]
+    di = m["ssm_expand"] * d
+    nh, Q = di // P, m["ssm_chunk"]
+    in_proj = 2 * d * (2 * di + 2 * N + nh)
+    conv = 2 * W * (di + 2 * N)
+    # within a chunk each token meets (Q + 1) / 2 earlier ones on average:
+    # C.B over N, then the weighted sum over P for every head
+    intra = 2 * (Q + 1) / 2 * (N + nh * P)
+    # the chunk's state update and its read-out: N x P per head each
+    inter = 2 * 2 * N * P * nh
+    out_proj = 2 * di * d
+    return in_proj + conv + intra + inter + out_proj
+
+
+def fwd_flops_per_token(m) -> float:
+    """Forward FLOPs per token of the whole model: its layers and the
+    head."""
+    return m["num_layers"] * layer_fwd_flops(m) \
+        + 2 * m["d_model"] * m["vocab_size"]
+
+
+def train_flops_per_token(m) -> float:
+    """Forward and backward FLOPs per trained token."""
+    return 3 * fwd_flops_per_token(m)
 
 
 # ------------------------------------------------------------------

@@ -11,6 +11,8 @@ After L inner steps, the sync:
     v_x  = mu v_x + g_x ;  x <- x - lr (g_x + mu v_x)
     y, z <- x ;  v <- 0 ;  gamma, rho <- max(f * ., floor), f = 1 - 1/(2B)
 
+The model is the configuration's own architecture module (its
+``reference``, bench/harness.py::architecture), its ``init`` and ``loss``.
 Returns what the benchmark compares: the replica-mean loss of every inner
 step, the norm of each replica's leaf of v_x after the first sync (the
 gradient the outer update gets), and the norm of each replica's leaf of
@@ -24,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.reference import models
+from bench import harness
 from bench.traffic import tokens as token_rows
 
 
@@ -35,18 +37,21 @@ def leaf_norms(tree) -> dict:
             for p, l in flat}
 
 
-def run(m: dict, job: dict, seed: int, rounds: int) -> dict:
+def run(conf: dict, job: dict, seed: int, rounds: int) -> dict:
+    """Parle's first ``rounds`` rounds of configuration ``conf`` (a
+    configuration file) under the training traffic ``job``."""
+    arch, m = harness.architecture(conf), conf["model"]
     h = job["parle"]
     n, L, B, T = job["replicas"], job["L"], job["batch"], job["seq"]
     lr, mu, alpha = h["lr"], h["momentum"], h["alpha"]
     f = 1.0 - 1.0 / (2.0 * h["batches_per_epoch"])
     V = m["vocab_size"]
     with jax.default_matmul_precision("highest"):
-        x0 = jax.jit(lambda k: models.init(k, m))(jax.random.PRNGKey(seed))
+        x0 = jax.jit(lambda k: arch.init(k, m))(jax.random.PRNGKey(seed))
 
         @partial(jax.jit, donate_argnums=(0, 1, 2))
         def inner(y, z, v, x, toks, labs, inv_gamma):
-            loss, g = jax.value_and_grad(models.loss)(y, m, toks, labs)
+            loss, g = jax.value_and_grad(arch.loss)(y, m, toks, labs)
             tm = jax.tree.map
             gy = tm(lambda g, y, x: g + inv_gamma * (y - x), g, y, x)
             v = tm(lambda v, gy: mu * v + gy, v, gy)

@@ -5,10 +5,11 @@
         --trace <0|1>
 
 The cell is a ``workloads`` entry of ``BENCHMARK.json``; its
-configuration, traffic mix, limits and per-layer metrics are files under
-``bench/`` found by name.  ``--trace 0`` reports the cell's end-to-end
-metrics, ``--trace 1`` records a profiler trace of the window and reports
-its per-layer metrics.  Needs a TPU with as many chips as the cell asks
+configuration (with the architecture module it names), traffic mix,
+limits and per-layer metrics are files under ``bench/`` found by name.
+``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1``
+records a profiler trace of the window and reports its per-layer
+metrics.  Needs a TPU with as many chips as the cell asks
 for; on anything else it exits non-zero without a result.  Longer output
 (set-up split, readings, trace) goes to ``results/bench/``.
 """

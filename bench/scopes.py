@@ -6,7 +6,11 @@ The program names its layers with ``jax.named_scope`` (``core/parle.py``,
 ``op_name`` metadata of the instructions it lowers to, under the
 transformations that produced them (``vmap(transpose(jvp(model)))``), so
 a layer's forward and backward carry the same scope.  An op's scope is
-the innermost of ``SCOPES`` on its path.
+the innermost known scope on its path: the trainer's and Parle's
+(``SCOPES``) and those the configuration's architecture module lists
+inside ``model`` (its ``SCOPES``, bench/harness.py::architecture); where
+no configuration is given, those of every configuration in
+``BENCHMARK.json``.
 
 Where the scope comes from: on a TPU v5e an ``XLA Ops`` event carries no
 framework op name, only its instruction's text (``%fusion.7 = ...``) and
@@ -36,30 +40,50 @@ import json
 import re
 from collections import defaultdict
 
+from bench import harness
 from bench import trace as bench_trace
 
-# the program's named scopes (innermost wins), and which lie in ``model``
-SCOPES = ("model", "embed", "in_proj", "conv", "ssd", "out_proj",
-          "head_loss", "parle_inner", "parle_sync")
-MODEL = ("model", "embed", "in_proj", "conv", "ssd", "out_proj",
-         "head_loss")
+# the trainer's and Parle's named scopes (core/parle.py, models/model.py)
+SCOPES = ("model", "parle_inner", "parle_sync")
 UNSCOPED = ""
 
 _WRAPPED = re.compile(r"^[\w\-.]+\((.*)\)$")
 
 
-def scope_of(op_name: str) -> str:
-    """The innermost of ``SCOPES`` on an ``op_name`` path, each component
-    unwrapped from its transformations; ``UNSCOPED`` where none is.  XLA
-    joins the names of merged instructions with ``;``: the first is the
-    whole path."""
+def in_model(conf: dict) -> tuple:
+    """The scopes of the model's loss for configuration ``conf``: ``model``
+    and its architecture module's scopes inside it."""
+    return ("model",) + tuple(harness.architecture(conf).SCOPES)
+
+
+def of_config(conf: dict) -> tuple:
+    """Every scope a run of configuration ``conf`` names."""
+    return SCOPES + in_model(conf)[1:]
+
+
+def of_benchmark() -> tuple:
+    """Every scope a run of any configuration in ``BENCHMARK.json`` names."""
+    spec = harness.load_json(harness.ROOT / "BENCHMARK.json")
+    known = list(SCOPES)
+    for c in spec["configs"]:
+        conf = harness.load_json(harness.ROOT / c["file"])
+        known += [s for s in in_model(conf) if s not in known]
+    return tuple(known)
+
+
+def scope_of(op_name: str, known=None) -> str:
+    """The innermost of ``known`` (default ``of_benchmark()``) on an
+    ``op_name`` path, each component unwrapped from its transformations;
+    ``UNSCOPED`` where none is.  XLA joins the names of merged
+    instructions with ``;``: the first is the whole path."""
+    known = known or of_benchmark()
     found = UNSCOPED
     for part in op_name.split(";", 1)[0].split("/"):
         m = _WRAPPED.match(part)
         while m:
             part = m.group(1)
             m = _WRAPPED.match(part)
-        if part in SCOPES:
+        if part in known:
             found = part
     return found
 
@@ -98,14 +122,14 @@ _LEADING = re.compile(r"^%?([^\s=(]+)")
 _MODULE_ID = re.compile(r"\(\d+\)$")
 
 
-def _op_scope(name, module, ops_by_module) -> str:
+def _op_scope(name, module, ops_by_module, known=None) -> str:
     """An op event's scope, from the program's map of its module where
     there is one, else its module's name."""
     ops = ops_by_module.get(module)
     if ops is None:
         return module or UNSCOPED
     op = _LEADING.match(name)
-    return scope_of(ops.get(op.group(1), "") if op else "")
+    return scope_of(ops.get(op.group(1), "") if op else "", known)
 
 
 def _modules(plane):
@@ -126,12 +150,15 @@ def _module_of(start, modules) -> str:
 
 
 @functools.lru_cache(maxsize=8)
-def reduce(xplane_path: str, spans_path: str, chips: int) -> dict:
-    """Seconds of device self time per scope over the window, averaged
-    over ``chips``: ``{"scope_s": {scope: s}, "busy_s": s, "window_s": s,
-    "unscoped_ops": [[op, s], ...]}`` (the longest ten ops in no scope).
-    Cached per path: every reader of one run parses the trace once."""
+def reduce(xplane_path: str, spans_path: str, chips: int,
+           known: tuple | None = None) -> dict:
+    """Seconds of device self time per scope of ``known`` (default
+    ``of_benchmark()``) over the window, averaged over ``chips``:
+    ``{"scope_s": {scope: s}, "busy_s": s, "window_s": s, "unscoped_ops":
+    [[op, s], ...]}`` (the longest ten ops in no scope).  Cached per path
+    and scopes: every reader of one run parses the trace once."""
     from jax.profiler import ProfileData
+    known = known or of_benchmark()
     pd = ProfileData.from_file(xplane_path)
     lo, hi = bench_trace.window_of(pd)
     ops_by_module = hlo_op_map(spans_path)
@@ -149,7 +176,8 @@ def reduce(xplane_path: str, spans_path: str, chips: int) -> dict:
         iv = bench_trace._union([(s, t) for _, s, t in events])
         busy += sum(t - s for s, t in iv) / 1e9 / chips
         for (name, start, _), self_ns in zip(events, self_times(events)):
-            sc = _op_scope(name, _module_of(start, modules), ops_by_module)
+            sc = _op_scope(name, _module_of(start, modules), ops_by_module,
+                           known)
             scope_s[sc] += self_ns / 1e9 / chips
             if sc == UNSCOPED:
                 unscoped[name[:120]] += self_ns / 1e9 / chips
@@ -165,7 +193,8 @@ def of_run(art) -> dict | None:
     if art.get("kind") != "train":
         return None
     path = bench_trace.find_xplane(art["xprof"])
-    return reduce(path, str(art["spans"]), art["chips"])
+    return reduce(path, str(art["spans"]), art["chips"],
+                  of_config(art["config"]))
 
 
 def per_step_ms(art, scopes) -> float | None:

@@ -23,12 +23,13 @@ TRAIN_LIMITS = {"loss_gap": 1e-4, "grad_gap": 1e-3, "change_gap": 1e-3}
 
 
 def tiny_train(cell):
+    """The cell's spec, its model shrunk to its architecture module's
+    ``CPU_SIZE`` and its traffic to a few short rows."""
     spec = copy.deepcopy(harness.cell_spec(cell))
-    m = spec["config"]["model"]
-    m.update(num_layers=2, d_model=64, vocab_size=128, ssm_state=16,
-             ssm_head_dim=16, ssm_chunk=16)
-    spec["config"]["reduced"] = ["num_layers", "d_model", "vocab_size",
-                                 "ssm_state", "ssm_head_dim", "ssm_chunk"]
+    conf = spec["config"]
+    small = harness.architecture(conf).CPU_SIZE
+    conf["model"].update(small)
+    conf["reduced"] = sorted(set(conf["reduced"]) | set(small))
     t = spec["traffic"]
     t.update(L=3, batch=2, seq=32)
     fl = t["flags"]
@@ -67,3 +68,26 @@ def test_fault_is_not_correct(train_spec, fault):
     with faults.planted(fault):
         ok, readings = run(train_spec)
     assert not ok, readings
+
+
+def test_late_reader_reads_each_round_lag_rounds_late():
+    import jax.numpy as jnp
+    from bench.kinds.train import _LateReader
+    late = _LateReader(lag=2)
+    for r in range(5):
+        late(r, 3 * (r + 1), {"losses": jnp.full((3,), float(r))})
+        assert len(late.losses) == max(0, r + 1 - 2)
+    late.drain()
+    assert late.losses == [[float(r)] * 3 for r in range(5)]
+    assert len(late.read_at) == 5
+
+
+def test_window_counts_every_round_it_ran(train_spec):
+    chips = train_spec["workload"]["chips"]
+    out, _, info = run_cell(train_spec["workload"]["name"], 2**31 + 29, 0.5,
+                            False, devices=jax.devices()[:chips],
+                            spec=train_spec)
+    assert out["correct"] and out["failed"] == 0
+    assert out["attempted"] == info["rounds"] >= 1
+    assert len(info["window_read_s"]) == info["rounds"]
+    assert info["compiles_in_window"] == 0

@@ -1,11 +1,11 @@
-"""bench/flops.py against counts made by hand."""
+"""bench/flops.py and the Mamba2 module's counts (bench/reference/mamba2.py)
+against counts made by hand, for the ``mamba2-1.3b-s12`` configuration."""
 import pytest
 
-from bench import flops
+from bench import flops, harness
+from bench.reference import mamba2
 
-MAMBA2 = {"family": "ssm", "num_layers": 1, "d_model": 2048,
-          "vocab_size": 4190, "ssm_state": 128, "ssm_head_dim": 64,
-          "ssm_expand": 2, "ssm_conv": 4, "ssm_chunk": 128}
+CONF = harness.load_json(harness.BENCH / "configs" / "mamba2-1.3b-s12.json")
 
 
 def test_one_mamba2_layer():
@@ -14,15 +14,15 @@ def test_one_mamba2_layer():
     intra = 129 * (128 + 64 * 64)                        # 544,896
     inter = 4 * 128 * 64 * 64                            # 2,097,152
     out_proj = 2 * 4096 * 2048                           # 16,777,216
-    assert flops.ssm_layer_fwd(MAMBA2) == in_proj + conv + intra + inter \
-        + out_proj == 54_319_232
+    assert mamba2.layer_fwd_flops(CONF["model"]) == in_proj + conv + intra \
+        + inter + out_proj == 54_319_232
 
 
 def test_model_and_training_counts():
-    m = dict(MAMBA2, num_layers=4)
     fwd = 4 * 54_319_232 + 2 * 2048 * 4190
-    assert flops.model_fwd(m) == fwd
-    assert flops.train_per_token(m) == 3 * fwd
+    assert mamba2.fwd_flops_per_token(CONF["model"]) == fwd
+    assert mamba2.train_flops_per_token(CONF["model"]) == 3 * fwd
+    assert flops.train_per_token(CONF) == 3 * fwd == 703_317_504
 
 
 def test_one_parle_kernel_call():
@@ -33,11 +33,10 @@ def test_one_parle_kernel_call():
 
 
 def test_param_sizes_count_the_mamba2_share():
-    m = dict(MAMBA2, num_layers=4, norm_eps=1e-5)
     per_layer = (2048 * 8512 + 4 * 4352 + 4352 + 64 * 3 + 2048 + 4096
                  + 4096 * 2048)
-    assert sum(flops.param_sizes(m)) == 4 * per_layer + 2 * 4190 * 2048 \
-        + 2048
+    assert sum(flops.param_sizes(CONF)) == 4 * per_layer + 2 * 4190 * 2048 \
+        + 2048 == 120_561_408
 
 
 def test_unknown_device_kind_is_an_error():
