@@ -11,6 +11,15 @@ warm-up and what the reference is compared with.  The window is one more
 losses are read ``AHEAD_S`` seconds of rounds late (``_LateReader``), so
 that the device has that much work queued while the host waits and a
 host stall shorter than it costs no device time.
+
+A traffic with ``--mesh`` runs over the trainer's mesh as
+``launch/train.py`` does: the mesh from the flag, the state made on its
+planner shardings (``algo.state_pspecs``), the round built for the mesh
+(``make_round_fn(..., mesh=, replica_axis=)``).  The mesh covers the
+cell's chips exactly.  A replica-only mesh gives one round program,
+compiled ahead of time as on one chip; a mesh with axes inside a replica
+gives a round of two programs (``core/parle.py::make_sharded_round_fn``),
+which the first warm-up round compiles.
 """
 from __future__ import annotations
 
@@ -40,6 +49,43 @@ def program_config(conf: dict):
                              f"listed under reduced")
     return dataclasses.replace(base, name=conf["name"],
                                **{k: model[k] for k in conf["reduced"]})
+
+
+def _mesh(args, pcfg, chips):
+    """(mesh, replica axis) of the traffic's ``--mesh``, checked as
+    ``launch/train.py`` checks them, over exactly the cell's chips;
+    (None, None) without one, on one chip."""
+    if not args.mesh:
+        if chips != 1:
+            raise SystemExit(f"traffic: {chips} chips need a --mesh")
+        return None, None
+    from repro.launch import train as train_lib
+    from repro.launch.mesh import make_mesh_from_spec, replica_axis_of
+    mesh = make_mesh_from_spec(args.mesh)
+    raxis = replica_axis_of(mesh)
+    if raxis is None:
+        raise SystemExit(f"traffic: --mesh {args.mesh!r} has no replica axis")
+    if mesh.size != chips:
+        raise SystemExit(f"traffic: --mesh {args.mesh!r} spans {mesh.size} "
+                         f"devices, the cell {chips} chips")
+    train_lib._validate_replicas(args, pcfg, mesh, raxis)
+    return mesh, raxis
+
+
+def _round_programs(round_fn, state, batches):
+    """The two compiled programs of a round over a mesh with axes inside a
+    replica (``core/parle.py::make_sharded_round_fn``: its ``inner_jit``
+    and ``sync_jit``), lowered as the round calls them, so that the tracer
+    maps their instructions to scopes as it does a one-program round's."""
+    import jax
+    jits = dict(zip(round_fn.__code__.co_freevars,
+                    (c.cell_contents for c in round_fn.__closure__)))
+    inner = jits["inner_jit"].lower(state, batches).compile()
+    mid = jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh,
+                                           weak_type=a.weak_type),
+        state, inner.output_shardings[0])
+    return inner, jits["sync_jit"].lower(mid).compile()
 
 
 def _norms(tree, minus=None):
@@ -135,29 +181,42 @@ def run(ctx) -> dict:
     policy = resolve_train_policy(args)
     model = build_model(cfg)
     algo = registry.get(args.algo)
-    if args.mesh:
-        raise SystemExit("traffic: a cell over a --mesh needs the state's "
-                         "shardings, which this training kind does not build")
     pcfg = algo.canonicalize_cfg(ParleConfig(
         n_replicas=n, L=L, lr=args.lr, lr_inner=args.lr,
         batches_per_epoch=max(args.steps // 4, 1),
         lr_drop_factor=args.lr_drop_factor, precision=args.precision,
         sync_compress=args.sync_compress, sync_overlap=args.sync_overlap))
+    mesh, raxis = _mesh(args, pcfg, chips)
     stream = TokenStream(vocab_size=cfg.vocab_size, seq_len=args.seq,
                          batch_size=args.batch, seed=args.seed)
     key = jax.random.PRNGKey(args.seed)
-    state = jax.jit(lambda k: algo.init(model.init(k), pcfg))(key)
+    # on a mesh, each leaf made where its planner sharding puts it
+    state_sh, x0_sh = {}, {}
+    if mesh is not None:
+        from repro.sharding import partition, planner
+        shapes = jax.eval_shape(model.init, key)
+        state_sh["out_shardings"] = partition.shardings(
+            mesh, algo.state_pspecs(raxis, params=shapes, mesh=mesh,
+                                    cfg=pcfg))
+        x0_sh["out_shardings"] = planner.plan_tree(
+            shapes, mesh=mesh).shardings(mesh)
+    state = jax.jit(lambda k: algo.init(model.init(k), pcfg),
+                    **state_sh)(key)
     jax.block_until_ready(state)
     t["state"] = harness.age()
 
-    round_fn = policy.make_round_fn(algo, model.loss, pcfg,
+    round_fn = policy.make_round_fn(algo, model.loss, pcfg, mesh=mesh,
+                                    replica_axis=raxis or "replica",
                                     use_kernel=args.use_kernel)
     stage = make_round_batch_fn(stream, L, args.batch, n)
     c0 = ctx.counter.snapshot()
-    compiled = round_fn.lower(state, stage(0)).compile()
+    if hasattr(round_fn, "lower"):
+        compiled = round_fn.lower(state, stage(0)).compile()
+        memory = compiled.memory_analysis()
+    else:                  # two programs: the first warm-up round compiles
+        compiled, memory = round_fn, None
     c1 = ctx.counter.snapshot()
     t["compile"] = harness.age()
-    memory = compiled.memory_analysis()
 
     spans = ctx.out / "spans.json"
     obs = Obs(trace_out=str(spans) if ctx.trace else "", process_name="bench")
@@ -171,7 +230,7 @@ def run(ctx) -> dict:
     def rounds(state, start, count, progress=progress, on_round=None):
         return runner.run_rounds(
             state, compiled, stage, start=start, rounds=count, L=L,
-            tokens_per_round=tokens_per_round, pcfg=pcfg,
+            tokens_per_round=tokens_per_round, mesh=mesh, pcfg=pcfg,
             progress_every=max(1, args.log_every // L), progress=progress,
             on_round=on_round,
             flush_fn=policy.make_flush_fn(algo, pcfg), aot=False)
@@ -186,9 +245,12 @@ def run(ctx) -> dict:
         losses += hist[0]["step_losses"]
         if r == 0:
             grad = _norms(state.v_x)
-    x0 = jax.jit(model.init)(key)
+    x0 = jax.jit(model.init, **x0_sh)(key)
     change = _norms(state.x, x0)
     del x0
+    if ctx.trace and memory is None:
+        for program in _round_programs(round_fn, state, stage(check * L)):
+            obs.tracer.add_hlo_ops(program)
     t["warmup"] = harness.age()
 
     # -- the window --------------------------------------------------------
@@ -231,7 +293,8 @@ def run(ctx) -> dict:
 
     # -- the reference, once the program's state is freed -----------------
     t_ref = harness.age()
-    ref = ref_parle.run(spec["config"], job, seed, check)
+    ref = ref_parle.run(spec["config"], job, seed, check,
+                        devices=ctx.devices)
     ref_s = harness.age() - t_ref
     loss_gap = max(abs(p - q) / abs(q) for p, q in zip(losses, ref["losses"]))
     grad_gap, grad_at, grad_skipped = norm_gap(grad, ref["grad"])

@@ -17,6 +17,11 @@ Returns what the benchmark compares: the replica-mean loss of every inner
 step, the norm of each replica's leaf of v_x after the first sync (the
 gradient the outer update gets), and the norm of each replica's leaf of
 the change of x over all the rounds followed.
+
+Given several devices, replica a lives on device a mod their number, so
+that the replicas' inner steps run side by side; the mean of Eq. 8d is
+summed on the first device in replica order.  The arithmetic is the same
+on one device or many.
 """
 from __future__ import annotations
 
@@ -37,15 +42,19 @@ def leaf_norms(tree) -> dict:
             for p, l in flat}
 
 
-def run(conf: dict, job: dict, seed: int, rounds: int) -> dict:
+def run(conf: dict, job: dict, seed: int, rounds: int,
+        devices=None) -> dict:
     """Parle's first ``rounds`` rounds of configuration ``conf`` (a
-    configuration file) under the training traffic ``job``."""
+    configuration file) under the training traffic ``job``, on
+    ``devices`` (default: the first device)."""
     arch, m = harness.architecture(conf), conf["model"]
     h = job["parle"]
     n, L, B, T = job["replicas"], job["L"], job["batch"], job["seq"]
     lr, mu, alpha = h["lr"], h["momentum"], h["alpha"]
     f = 1.0 - 1.0 / (2.0 * h["batches_per_epoch"])
     V = m["vocab_size"]
+    devices = devices or jax.devices()[:1]
+    home = [devices[a % len(devices)] for a in range(n)]
     with jax.default_matmul_precision("highest"):
         x0 = jax.jit(lambda k: arch.init(k, m))(jax.random.PRNGKey(seed))
 
@@ -67,33 +76,38 @@ def run(conf: dict, job: dict, seed: int, rounds: int) -> dict:
             x = jax.tree.map(lambda x, g, v: x - lr * (g + mu * v), x, g, vx)
             return x, vx
 
-        xs = [x0] * n
-        vxs = [jax.tree.map(jnp.zeros_like, x0)] * n
+        xs = [jax.device_put(x0, d) for d in home]
+        vxs = [jax.tree.map(jnp.zeros_like, x) for x in xs]
         gamma, rho = h["gamma0"], h["rho0"]
         losses = np.zeros((rounds * L, n))
         grad = None
         for r in range(rounds):
-            zs = []
-            for a in range(n):
-                y, z = (jax.tree.map(jnp.copy, xs[a]) for _ in range(2))
-                v = jax.tree.map(jnp.zeros_like, x0)
-                for k in range(L):
-                    step = r * L + k
+            ys, zs = ([jax.tree.map(jnp.copy, x) for x in xs]
+                      for _ in range(2))
+            vs = [jax.tree.map(jnp.zeros_like, x) for x in xs]
+            for k in range(L):
+                step = r * L + k
+                step_loss = []
+                for a in range(n):
                     toks, labs = token_rows.rows(seed, step, a, n, B, T, V)
-                    loss, y, z, v = inner(y, z, v, xs[a], toks, labs,
-                                          1.0 / gamma)
-                    losses[step, a] = float(loss)
-                zs.append(z)
-                del y, v
-            xbar = jax.tree.map(lambda *t: sum(t) / n, *xs)
-            out = [sync(xs[a], zs[a], vxs[a], xbar, 1.0 / rho)
-                   for a in range(n)]
+                    loss, ys[a], zs[a], vs[a] = inner(
+                        ys[a], zs[a], vs[a], xs[a], toks, labs, 1.0 / gamma)
+                    step_loss.append(loss)
+                losses[step] = [float(x) for x in step_loss]
+            del ys, vs
+            xbar = jax.tree.map(
+                lambda *t: sum(jax.device_put(v, devices[0]) for v in t) / n,
+                *xs)
+            out = [sync(xs[a], zs[a], vxs[a], jax.device_put(xbar, home[a]),
+                        1.0 / rho) for a in range(n)]
             xs, vxs = [o[0] for o in out], [o[1] for o in out]
             del zs, xbar, out
             if r == 0:
                 grad = [leaf_norms(v) for v in vxs]
             gamma = max(gamma * f, h["gamma_min"])
             rho = max(rho * f, h["rho_min"])
-        change = [leaf_norms(jax.tree.map(jnp.subtract, x, x0)) for x in xs]
+        change = [leaf_norms(jax.tree.map(jnp.subtract, x,
+                                          jax.device_put(x0, x_dev)))
+                  for x, x_dev in zip(xs, home)]
     return {"losses": losses.mean(axis=1).tolist(), "grad": grad,
             "change": change}

@@ -10,6 +10,10 @@ The control is the program's own ``--precision bf16`` path (the inner
 iterate, activations and gradients in bfloat16), run on the control
 seeds.  The faults are planted in this process only (see ``faults.py``).
 No measured window is run: the readings are of the cell's first rounds.
+
+``--layout replicas:N,mesh:SPEC`` reads the cell's traffic with
+``--replicas N --mesh SPEC`` in its place, a layout that is no cell of
+its own (on a four-chip cell: ``replicas:2,mesh:replica:2,data:2``).
 """
 from __future__ import annotations
 
@@ -28,6 +32,16 @@ def seeds(s):
     return [int(x) for x in s.split(",") if x]
 
 
+def relayout(spec, layout: str):
+    """The cell's traffic with ``--replicas`` and ``--mesh`` from
+    ``replicas:N,mesh:SPEC``."""
+    n, _, mesh = layout.removeprefix("replicas:").partition(",mesh:")
+    flags = spec["traffic"]["flags"]
+    for k, v in (("--replicas", n), ("--mesh", mesh)):
+        flags[flags.index(k) + 1] = v
+    spec["traffic"]["replicas"] = int(n)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -35,9 +49,12 @@ def main(argv=None):
     ap.add_argument("--control-seeds", default="")
     ap.add_argument("--fault-seeds", default="")
     ap.add_argument("--faults", default="")
+    ap.add_argument("--layout", default="")
     a = ap.parse_args(argv)
     from bench.run import run_cell
     spec = harness.cell_spec(a.workload)
+    if a.layout:
+        relayout(spec, a.layout)
     harness.setup_jax()
     devices = harness.require_chips(spec["workload"]["chips"])
 

@@ -18,13 +18,27 @@ import pytest
 from bench import harness
 from bench.run import run_cell
 from bench.tests import faults
+from bench.tests.readings import relayout
 
 TRAIN_LIMITS = {"loss_gap": 1e-4, "grad_gap": 1e-3, "change_gap": 1e-3}
+CELLS = {w["name"]: w for w in harness.load_json(
+    harness.ROOT / "BENCHMARK.json")["workloads"]}
+# layouts that are no cell of their own: a cell's traffic with other flags.
+# replica:2,data:2 is the in-replica (FSDP) mesh that a configuration too
+# large for one chip's replicas takes
+LAYOUTS = {"train-mamba2-s12-replica4.r2d2": (
+    "train-mamba2-s12-replica4", "replicas:2,mesh:replica:2,data:2")}
 
 
 def tiny_train(cell):
-    """The cell's spec, its model shrunk to its architecture module's
-    ``CPU_SIZE`` and its traffic to a few short rows."""
+    """The cell's (or layout's) spec, its model shrunk to its architecture
+    module's ``CPU_SIZE`` and its traffic to a few short rows."""
+    if cell in LAYOUTS:
+        base, layout = LAYOUTS[cell]
+        spec = tiny_train(base)
+        spec["workload"] = dict(spec["workload"], name=cell)
+        relayout(spec, layout)
+        return spec
     spec = copy.deepcopy(harness.cell_spec(cell))
     conf = spec["config"]
     small = harness.architecture(conf).CPU_SIZE
@@ -47,10 +61,13 @@ def run(spec, seed=2**31 + 17, **kw):
     return out["correct"], info["readings"]
 
 
-@pytest.fixture(scope="module", params=[w["name"] for w in harness.load_json(
-    harness.ROOT / "BENCHMARK.json")["workloads"]])
+@pytest.fixture(scope="module", params=[*CELLS, *LAYOUTS])
 def train_spec(request):
     return tiny_train(request.param)
+
+
+def chips_of(cell):
+    return CELLS[LAYOUTS.get(cell, (cell,))[0]]["chips"]
 
 
 def test_sound_run_is_correct(train_spec):
@@ -63,10 +80,13 @@ def test_control_bf16_is_not_correct(train_spec):
     assert not ok, readings
 
 
-@pytest.mark.parametrize("fault", ["unchanged", "half_batch"])
-def test_fault_is_not_correct(train_spec, fault):
+@pytest.mark.parametrize("cell,fault", [
+    (c, f) for c in [*CELLS, *LAYOUTS]
+    for f in faults.for_chips(chips_of(c))],
+    ids=lambda v: v)
+def test_fault_is_not_correct(cell, fault):
     with faults.planted(fault):
-        ok, readings = run(train_spec)
+        ok, readings = run(tiny_train(cell))
     assert not ok, readings
 
 

@@ -1,21 +1,88 @@
 """Reduce a profiler trace (``.xplane.pb``) to what the metrics read: the
 window, the device's busy and idle time, per-op and per-program device
-time, collective time and the longest idle gaps with what the host was doing in each.
+time, collective time and the part of it exposed, and the longest idle
+gaps with what the host was doing in each.
 
 The harness opens a host annotation named ``WINDOW`` around the traced
 window, so the window and the device's ops are read on one clock.
 Busy time is the union of the intervals of the ops on a chip's
 ``XLA Ops`` line, clipped to the window, averaged over the chips used.
+
+A collective is an op whose opcode is one of ``COLLECTIVES`` (on a TPU
+an ``XLA Ops`` event is named by its instruction's text, ``%psum.109 =
+f32[...] all-reduce(...)``, whose name need not say it); an
+asynchronous one (``<collective>-start`` ... ``<collective>-done``) is in
+flight from its start op's beginning to its done op's end.  Collective
+time is the union of the collectives' intervals; the exposed part is
+what of it no other op covers on the same chip, where the other ops are
+the line's innermost ops (a ``while`` or ``call`` that holds others is
+not itself work).  Both are averaged over the chips used.
 """
 from __future__ import annotations
 
 import glob
 import os
+import re
 from collections import defaultdict
 
 WINDOW = "bench_window"
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
                "collective-permute", "all-to-all")
+
+
+_OPCODE = re.compile(r"\s(%s)(?:-(start|done))?\(" % "|".join(COLLECTIVES))
+_INSTRUCTION = re.compile(r"^%%?(%s)(?:-(start|done))?(?:\.\d+)?$"
+                          % "|".join(COLLECTIVES))
+
+
+def collective_of(name: str):
+    """(collective, phase) of an op event's name, phase ``start``,
+    ``done`` or ``""`` (one synchronous op); None for any other op.  The
+    name is the instruction's text (``%psum.109 = f32[8] all-reduce(...)``)
+    or its bare name (``all-reduce-done.3``).  In the text the opcode is
+    the word before the first ``(`` of a collective's name; an operand
+    that names one (``%all-reduce-done.3``) is no call."""
+    m = _OPCODE.search(name) or _INSTRUCTION.match(name)
+    return (m.group(1), m.group(2) or "") if m else None
+
+
+def collective_split(events):
+    """(collective seconds, exposed seconds, collectives) of one chip's
+    ``XLA Ops`` events [(name, start_ns, end_ns)], clipped to the window;
+    see the module's docstring."""
+    coll, other, open_starts = [], [], defaultdict(list)
+    order = sorted(events, key=lambda ev: (ev[1], -ev[2]))
+    for i, (name, s, e) in enumerate(order):
+        kind = collective_of(name)
+        if kind is None:
+            holds = i + 1 < len(order) and order[i + 1][1] < e
+            if not holds:
+                other.append((s, e))
+        elif kind[1] == "start":
+            open_starts[kind[0]].append(s)
+        elif kind[1] == "done" and open_starts[kind[0]]:
+            coll.append((open_starts[kind[0]].pop(0), e))
+        else:                  # synchronous, or a done whose start was
+            coll.append((s, e))    # before the window
+    for c, starts in open_starts.items():   # still in flight at its end
+        coll += [(s, max(e for _, _, e in events)) for s in starts]
+    coll_u = _union(coll)
+    total = sum(e - s for s, e in coll_u)
+    covered = _overlap(coll_u, _union(other))
+    return total / 1e9, (total - covered) / 1e9, len(coll)
+
+
+def _overlap(a, b):
+    """Nanoseconds in both of two sorted, merged interval lists."""
+    i = j = out = 0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        out += max(0, hi - lo)
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return out
 
 
 def find_xplane(root) -> str:
@@ -96,16 +163,16 @@ def reduce(path, chips: int, top: int = 10) -> dict:
                 if e > s:
                     module_time[name] += (e - s) / 1e9 / chips
         ops = _line(plane, "XLA Ops")
-        iv = []
+        iv, events = [], []
         if ops is not None:
             for name, s, e in _events(ops):
                 s, e = max(s, lo), min(e, hi)
                 if e <= s:
                     continue
                 iv.append((s, e))
+                events.append((name, s, e))
                 op_time[name] += (e - s) / 1e9 / chips
-                if i == 0 and any(c in name for c in COLLECTIVES):
-                    coll.append((name, (e - s) / 1e9))
+        coll.append(collective_split(events))
         merged = _union(iv)
         busy.append(sum(e - s for s, e in merged) / 1e9)
         if i == 0:
@@ -134,8 +201,10 @@ def reduce(path, chips: int, top: int = 10) -> dict:
         "module_time": dict(module_time),
         "device_ops": [[k, v] for k, v in ops_sorted[:top]],
         "idle_gaps": gaps,
-        "collective_s": sum(d for _, d in coll),
-        "collectives": len(coll),
+        "collective_s": sum(c[0] for c in coll) / len(coll),
+        "collective_exposed_s": sum(c[1] for c in coll) / len(coll),
+        "collectives": sum(c[2] for c in coll),
+        "collective_s_by_chip": [[c[0], c[1]] for c in coll],
     }
 
 
