@@ -21,6 +21,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.kernels import ops as kops
 from repro.kernels import ssd_scan as ssd_kernel
@@ -199,7 +200,8 @@ def ssm_block_forward(lp, cfg, x, h0=None):
     di, N, nh, P = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_num_heads, cfg.ssm_head_dim
     with jax.named_scope("in_proj"):
         u = rms_norm(x, lp["ln"], cfg.norm_eps)
-        proj = jnp.einsum("btd,de->bte", u, lp["in_proj"])
+        proj = checkpoint_name(
+            jnp.einsum("btd,de->bte", u, lp["in_proj"]), "proj")
     with jax.named_scope("conv"):
         z, xBC, dt = _split_proj(cfg, proj)
         xBC = _causal_conv(xBC, lp["conv_w"], lp["conv_b"])
@@ -302,7 +304,18 @@ def init_params(key, cfg, dtype=jnp.float32):
     }
 
 
+# What the layer scan keeps for the backward besides each layer's input:
+# the in_proj output.  The backward recomputes the rest from it: the norms,
+# the split, the conv, the SiLUs, softplus(dt), the gate, and the SSD's
+# forward (on a TPU its fused kernel runs again, which costs less than
+# writing and reading back its output and chunk states).  Outside a
+# gradient the name does nothing.
+SAVED = ("proj",)
+
+
 def forward_hidden(params, cfg, tokens, remat=False):
+    """``remat`` False saves by ``SAVED``; True recomputes the whole layer,
+    "dots" keeps matmul outputs (``transformer._remat``)."""
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
 
@@ -313,6 +326,9 @@ def forward_hidden(params, cfg, tokens, remat=False):
     if remat:
         from repro.models.transformer import _remat
         body = _remat(body, remat)
+    else:
+        body = jax.checkpoint(
+            body, policy=jax.checkpoint_policies.save_only_these_names(*SAVED))
     x, _ = jax.lax.scan(body, x, params["layers"], unroll=layer_unroll())
     return rms_norm(x, params["ln_f"], cfg.norm_eps), jnp.zeros((), jnp.float32)
 

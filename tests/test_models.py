@@ -121,6 +121,22 @@ def test_ssd_chunked_grads_finite_under_fast_decay(key):
         assert np.isfinite(np.asarray(g)).all()
 
 
+def _t_ssm():
+    from repro.configs.base import ModelConfig
+    return ModelConfig(name="t-ssm", family="ssm", num_layers=2, d_model=64,
+                       num_heads=0, num_kv_heads=0, d_ff=0, vocab_size=128,
+                       ssm_state=16, ssm_head_dim=32, ssm_chunk=16)
+
+
+def _take_ssd(monkeypatch, fused):
+    """Steer the Mamba2 block to the fused Pallas SSD (interpreted here; on
+    a TPU the block takes it by itself) or to ``ssd_chunked``."""
+    from repro.models import mamba2
+    monkeypatch.setattr(mamba2, "_fused_ssd",
+                        lambda cfg, T, h0: fused and h0 is None
+                        and T % cfg.ssm_chunk == 0)
+
+
 def test_fused_ssd_trains_like_chunked_under_the_ssd_scope(monkeypatch, key):
     """A small Mamba2 through the fused Pallas SSD (interpreted here; on a
     TPU the block takes it by itself): the loss and every parameter
@@ -128,20 +144,14 @@ def test_fused_ssd_trains_like_chunked_under_the_ssd_scope(monkeypatch, key):
     directions of the fused op sit in the ``ssd`` scope that
     ``ssd_ms.train`` reads."""
     from bench.scopes import scope_of
-    from repro.configs.base import ModelConfig
-    from repro.models import mamba2
     from repro.obs.trace import hlo_op_names
-    cfg = ModelConfig(name="t-ssm", family="ssm", num_layers=2, d_model=64,
-                      num_heads=0, num_kv_heads=0, d_ff=0, vocab_size=128,
-                      ssm_state=16, ssm_head_dim=32, ssm_chunk=16)
+    cfg = _t_ssm()
     model = build_model(cfg)
     params = model.init(key)
     batch = make_batch(cfg, key, batch=2, seq=32)
     loss = lambda p: model.loss(p, batch)[0]               # noqa: E731
     want_l, want_g = jax.value_and_grad(loss)(params)
-    monkeypatch.setattr(mamba2, "_fused_ssd",
-                        lambda cfg, T, h0: h0 is None
-                        and T % cfg.ssm_chunk == 0)
+    _take_ssd(monkeypatch, fused=True)
     got_l, got_g = jax.value_and_grad(loss)(params)
     np.testing.assert_allclose(float(got_l), float(want_l), rtol=1e-6)
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got_g)[0],
@@ -157,3 +167,82 @@ def test_fused_ssd_trains_like_chunked_under_the_ssd_scope(monkeypatch, key):
     assert any("ssd_bwd" in v and "transpose(" in v for v in fused)
     for v in fused:
         assert scope_of(v) == "ssd", v
+
+
+def _plain_hidden(params, cfg, tokens, remat=False):
+    """``mamba2.forward_hidden`` with the layer scan's body as it is, with
+    no checkpoint: autodiff keeps every intermediate it wants."""
+    from repro.models import mamba2
+    from repro.models.layers import rms_norm
+    del remat
+    x = params["embed"][tokens]
+    x, _ = jax.lax.scan(
+        lambda h, lp: (mamba2.ssm_block_forward(lp, cfg, h)[0], None),
+        x, params["layers"])
+    return (rms_norm(x, params["ln_f"], cfg.norm_eps),
+            jnp.zeros((), jnp.float32))
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["chunked", "fused"])
+def test_mamba2_save_policy_keeps_the_gradient(fused, monkeypatch, key):
+    """The layer scan saves by ``mamba2.SAVED`` and recomputes the rest in
+    the backward: the same ops on the same inputs, so the loss and every
+    parameter gradient equal those of the body with no checkpoint, on both
+    SSD paths."""
+    from repro.models import mamba2
+    _take_ssd(monkeypatch, fused)
+    cfg = _t_ssm()
+    model = build_model(cfg)
+    params = model.init(key)
+    batch = make_batch(cfg, key, batch=2, seq=32)
+    loss = lambda p: model.loss(p, batch)[0]               # noqa: E731
+    got_l, got_g = jax.jit(jax.value_and_grad(loss))(params)
+    monkeypatch.setattr(mamba2, "forward_hidden", _plain_hidden)
+    want_l, want_g = jax.jit(jax.value_and_grad(loss))(params)
+    np.testing.assert_allclose(float(got_l), float(want_l), rtol=1e-6)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got_g)[0],
+                            jax.tree.leaves(want_g)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.abs(a - b).max() <= 1e-5 * np.abs(b).max(), (
+            jax.tree_util.keystr(path), np.abs(a - b).max(), np.abs(b).max())
+
+
+def _residual_shapes(f, *args):
+    """Shapes of what the backward of ``f`` keeps, as
+    ``jax.ad_checkpoint.print_saved_residuals`` lists them, with where each
+    comes from."""
+    import contextlib
+    import io
+    import re
+    from jax.ad_checkpoint import print_saved_residuals
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        print_saved_residuals(f, *args)
+    found = []
+    for line in out.getvalue().splitlines():
+        m = re.match(r"\w+\[([\d,]*)\] (.*)", line)
+        found.append((tuple(int(d) for d in m.group(1).split(",") if d),
+                      m.group(2)))
+    return found
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["chunked", "fused"])
+def test_mamba2_layer_scan_saves_only_the_named_values(fused, monkeypatch,
+                                                       key):
+    """What the layer scan stacks for the backward, per layer: its input and
+    the in_proj output ``proj`` (``mamba2.SAVED``), and nothing else (not
+    the conv's shifted slices, the gate or the norms' values; not the SSD's
+    output or the fused op's chunk states, which the backward recomputes),
+    so a later edit of the block that adds a save shows here."""
+    _take_ssd(monkeypatch, fused)
+    cfg = _t_ssm()
+    L, B, T = cfg.num_layers, 1, 32
+    proj = 2 * cfg.ssm_inner + 2 * cfg.ssm_state + cfg.ssm_num_heads
+    model = build_model(cfg)
+    params = model.init(key)
+    batch = make_batch(cfg, key, batch=B, seq=T)
+    res = _residual_shapes(lambda p: model.loss(p, batch)[0], params)
+    stacked = sorted(s for s, src in res
+                     if "output of scan" in src and "forward_hidden" in src
+                     and s[:2] == (L, B))
+    assert stacked == [(L, B, T, cfg.d_model), (L, B, T, proj)], res

@@ -11,7 +11,9 @@ the scan, whose forward and backward also compile as the benchmark's
 training cells run them: 2,048 tokens, vmapped over 2 Parle replicas.
 A Mamba2 block's gradient compiles on both sides of its choice of SSD:
 the fused op at zamba2-1.2b's widths (state 64), ``ssd_chunked`` at the
-smoke widths (chunks of 32, which the kernels do not tile).
+smoke widths (chunks of 32, which the kernels do not tile).  The training
+cells' whole gradient (2 replicas, 4 layers at mamba2-1.3b widths, 2,048
+tokens) fits its temporaries under the layer scan's save policy.
 """
 import dataclasses
 import os
@@ -27,6 +29,7 @@ from repro.kernels import paged_attention as pa
 from repro.kernels import parle_update as pu
 from repro.kernels import ssd_scan as ssd
 from repro.models import mamba2
+from repro.models.model import build_model
 
 LEAF = (2048, 8192)
 M = LEAF[0] * LEAF[1]
@@ -165,3 +168,33 @@ def test_ssd_block_compiles_for_v5e(name, one_chip, monkeypatch):
     text = jax.jit(jax.grad(loss)).lower(args, x).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == (
         2 if fused else 0)
+
+
+def test_mamba2_training_gradient_fits_for_v5e(one_chip, monkeypatch):
+    """The gradient the training cells take, vmapped over 2 Parle replicas:
+    mamba2-1.3b cut to 4 layers and 4,190 vocabulary rows, 1 x 2,048
+    tokens, the fused SSD on as on the chip.  The layer scan keeps only
+    each layer's input and ``proj`` (``mamba2.SAVED``), so the
+    temporaries stay under 4 GB (6.65 GB when it kept every intermediate,
+    2.54 GB with the policy).  The backward runs the SSD's forward kernel
+    again to rebuild its output and states: three kernels, ``ssd_fwd``
+    twice and ``ssd_bwd`` once."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get_config("mamba2-1.3b"), num_layers=4,
+                              vocab_size=4190)
+    model = build_model(cfg)
+    shapes = jax.eval_shape(lambda: jax.vmap(model.init)(
+        jax.random.split(jax.random.PRNGKey(0), R)))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shapes)
+    tokens = jax.ShapeDtypeStruct((R, 1, 2048), I32, sharding=one_chip)
+
+    def loss(p, t):
+        return model.loss(p, {"tokens": t, "labels": t})[0]
+
+    compiled = jax.jit(jax.vmap(jax.value_and_grad(loss))).lower(
+        params, tokens).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
