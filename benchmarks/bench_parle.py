@@ -124,7 +124,7 @@ def measure_steps() -> dict:
     L, n = PIN["L"], PIN["n_replicas"]
 
     compile_s = {}
-    inner, sync, fused = steps_lib.make_parle_steps(mcfg, pcfg)
+    inner, sync = steps_lib.make_parle_steps(mcfg, pcfg)
     inner_c, compile_s["inner"] = _aot(jax.jit(inner), state, batch)
     sync_c, compile_s["sync"] = _aot(jax.jit(sync), state)
     step_c, compile_s["fused"] = _aot(
@@ -352,9 +352,9 @@ for method in ("none", "int8"):
     reps = {"w": jnp.ones((8, size), jnp.float32)}
     st_b = parle.dealias_state(parle.init_from_replicas(reps, cfg))
     st_o = parle.dealias_state(parle.init_from_replicas(reps, ocfg))
-    cb = parle.make_sharded_round_fn(loss, cfg, mesh) \
+    cb = parle.make_round_fn(loss, cfg, mesh=mesh) \
         .lower(st_b, batch).compile()
-    co = parle.make_sharded_overlap_round_fn(loss, ocfg, mesh) \
+    co = parle.make_round_fn(loss, ocfg, mesh=mesh) \
         .lower(st_o, batch).compile()
     hb = hlo_stats.overlap_structure(cb.as_text())
     ho = hlo_stats.overlap_structure(co.as_text())

@@ -69,8 +69,8 @@ def main():
                f"hbm_streams=5r3w;fused_bytes={n*4*8/1e6:.0f}MB;"
                f"compile_ms={cms:.0f}")
 
-    # int8 sync-compression codec oracle (quantize+EF; the payload side
-    # of the fused quantize / dequantize+update kernel pair)
+    # int8 sync-compression codec (quantize + error feedback: the
+    # payload side of the compressed sync)
     from repro.core import compress
     c = jax.random.normal(key, (2, n // 2)).reshape(2, -1)
     c = compress.pad_to_chunk(c)

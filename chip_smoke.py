@@ -14,7 +14,8 @@ model (``algo.deployable(state)``) with its dense cache, and its greedy
 tokens are held to the naive loop's.
 
 Four chips (``--four-chip``): the same model with n=4 on a replica:4
-mesh, under the barrier sync and under the int8 kernel sync; then the
+mesh, under the barrier sync and under the int8 sync with the Pallas
+sync update (``--use-kernel --sync-compress int8``); then the
 smoke config with n=4 on the mesh against the same four replicas held
 on one device.
 

@@ -30,8 +30,8 @@ registered object (see :mod:`repro.core.registry`) exposing
                                 the round-mean "loss" + per-step
                                 "losses" (L,).  With a mesh, replica-
                                 sharded like make_sharded_step (see the
-                                per-module docstrings for the jax
-                                0.4.37 composed-mesh scan workaround).
+                                per-module docstrings for the two-
+                                program round on composed meshes).
                                 With cfg.sync_overlap (parle/
                                 entropy_sgd) the returned round is the
                                 staleness-1 overlapped variant: the
@@ -162,24 +162,10 @@ class ParleAlgorithm:
     def make_round_fn(self, loss_fn, cfg, *, mesh=None,
                       replica_axis="replica", weight_decay=0.0,
                       use_kernel=False, lr_schedule=None):
-        sched = resolve_lr_schedule(cfg, lr_schedule)
-        if getattr(cfg, "sync_overlap", False):
-            if mesh is None:
-                return parle.make_overlap_round_fn(
-                    loss_fn, cfg, weight_decay=weight_decay,
-                    use_kernel=use_kernel, lr_schedule=sched)
-            return parle.make_sharded_overlap_round_fn(
-                loss_fn, cfg, mesh, replica_axis=replica_axis,
-                weight_decay=weight_decay, use_kernel=use_kernel,
-                lr_schedule=sched)
-        if mesh is None:
-            return parle.make_round_fn(
-                loss_fn, cfg, weight_decay=weight_decay,
-                use_kernel=use_kernel, lr_schedule=sched)
-        return parle.make_sharded_round_fn(
-            loss_fn, cfg, mesh, replica_axis=replica_axis,
+        return parle.make_round_fn(
+            loss_fn, cfg, mesh=mesh, replica_axis=replica_axis,
             weight_decay=weight_decay, use_kernel=use_kernel,
-            lr_schedule=sched)
+            lr_schedule=resolve_lr_schedule(cfg, lr_schedule))
 
     def make_round_flush_fn(self, cfg, *, lr_schedule=None):
         if not getattr(cfg, "sync_overlap", False):

@@ -22,10 +22,7 @@ payloads converges to the true mean at O(1/K) over K syncs
 (tests/test_sync_compress.py).
 
 All functions operate on FLAT (R, M) streams; the tree-level drivers
-live with their consumers (core/parle.py pads/flattens per leaf exactly
-like the Pallas drivers in kernels/parle_update.py, whose fused
-quantize / dequantize+update kernels these functions are the oracle
-for).
+live with their consumers (core/parle.py pads and flattens per leaf).
 """
 from __future__ import annotations
 
@@ -33,9 +30,8 @@ import jax.numpy as jnp
 
 METHODS = ("none", "bf16", "int8")
 CHUNK = 1024            # elements per int8 scale (= the kernel lane dim)
-# streams are padded to the Pallas block size (8 x 1024, see
-# kernels/parle_update.BLOCK) so the jnp reference and the fused kernels
-# chunk identically and produce bit-identical payloads
+# streams are padded to whole (8, 1024) tiles: the payload's layout on
+# the wire and in the async coordinator's flat vectors
 PAD_MULTIPLE = 8 * CHUNK
 
 
@@ -67,8 +63,7 @@ def quantize(c, method: str):
         chunked = c.reshape(*c.shape[:-1], c.shape[-1] // CHUNK, CHUNK)
         amax = jnp.max(jnp.abs(chunked), axis=-1)
         # multiply by the reciprocal explicitly: XLA strength-reduces
-        # x/127 to x*(1/127) under jit, and the Pallas kernel must
-        # produce bit-identical scales
+        # x/127 to x*(1/127) under jit, so jitted and eager scales agree
         scales = jnp.where(amax == 0, 1.0, amax * (1.0 / 127.0))
         q = jnp.clip(jnp.round(chunked / scales[..., None]), -127, 127)
         return q.astype(jnp.int8).reshape(c.shape), scales
@@ -89,6 +84,6 @@ def dequantize(q, scales, method: str):
 def quantize_ef(c, method: str):
     """Quantize with error feedback: returns (q, scales, residual) where
     residual = c - dequantize(q) is what the caller carries to the next
-    sync.  This is the oracle of the fused Pallas quantize kernel."""
+    sync."""
     q, scales = quantize(c, method)
     return q, scales, c - dequantize(q, scales, method)

@@ -30,8 +30,8 @@ core/elastic_sgd.py (per-step coupling, Eq. 7).
 Profiles: ``inner_step`` runs under ``jax.named_scope("parle_inner")``
 and the sync (``sync_step``, ``consensus_step``, ``overlap_head``, the
 flush and the async apply) under ``"parle_sync"``; XLA keeps the scope
-in the metadata of every op they lower to, so every round body below
-inherits the split without naming it.
+in the metadata of every op they lower to, so every step and round
+program built below inherits the split without naming it.
 """
 from __future__ import annotations
 
@@ -160,18 +160,14 @@ def inner_step(state: ParleState, grads, cfg, use_kernel: bool = False,
 # Sync step (8c)-(8d): the one cross-replica collective
 # ------------------------------------------------------------------
 
-def _quantized_leaf_stats(xl, el, method, axis_name, use_kernel):
+def _quantized_leaf_stats(xl, el, method, axis_name):
     """One leaf's compressed-sync statistics: quantize each replica's
     contribution with error feedback, gather the payload across the
     replica axis, dequantize, mean.  Shapes: xl/el (r, ...); returns
     (xbar (...), e_new (r, ...))."""
     r, shape, m = xl.shape[0], xl.shape, xl[0].size
     c = compress.pad_to_chunk((xl.astype(jnp.float32) + el).reshape(r, -1))
-    if use_kernel and method == "int8":
-        from repro.kernels import ops as kops
-        q, s, res = kops.quantize_ef(c)
-    else:
-        q, s, res = compress.quantize_ef(c, method)
+    q, s, res = compress.quantize_ef(c, method)
     e_new = res[:, :m].reshape(shape)
     if axis_name is not None:
         # pin the QUANTIZED width on the wire.  A bf16 all-gather gets
@@ -192,8 +188,7 @@ def _quantized_leaf_stats(xl, el, method, axis_name, use_kernel):
     return xbar, e_new
 
 
-def _quantized_sync_stats(x, e, method: str, axis_name, use_kernel: bool,
-                          return_payload: bool = False, shard_ctx=None):
+def _quantized_sync_stats(x, e, method: str, axis_name, shard_ctx=None):
     """Compress each replica's sync contribution and produce the Eq. (8d)
     replica mean from the compressed payloads.
 
@@ -217,29 +212,12 @@ def _quantized_sync_stats(x, e, method: str, axis_name, use_kernel: bool,
     tolerance, not bit-for-bit — like the rest of the composed path).
 
     Returns (xbar_tree, e_new_tree); xbar leaves are un-broadcast (...).
-    With ``return_payload`` the first element is instead the gathered
-    ((q_tree, scales_tree)) of flat (n, Mpad) payload leaves, for the
-    fused dequantize+update kernel (int8, unsharded leaves only).
     """
     flat, treedef = jax.tree_util.tree_flatten_with_path(x)
     flat_e = treedef.flatten_up_to(e)
-    xbars, qs, ss, e_news = [], [], [], []
+    xbars, e_news = [], []
     for (path, xl), el in zip(flat, flat_e):
-        if return_payload:
-            r, shape, m = xl.shape[0], xl.shape, xl[0].size
-            c = compress.pad_to_chunk(
-                (xl.astype(jnp.float32) + el).reshape(r, -1))
-            from repro.kernels import ops as kops
-            q, s, res = kops.quantize_ef(c)
-            e_news.append(res[:, :m].reshape(shape))
-            if axis_name is not None:
-                q = jax.lax.all_gather(q, axis_name, axis=0, tiled=True)
-                s = jax.lax.all_gather(s, axis_name, axis=0, tiled=True)
-            qs.append(q)
-            ss.append(s)
-            continue
-        call = lambda a, b: _quantized_leaf_stats(a, b, method, axis_name,
-                                                  use_kernel)
+        call = lambda a, b: _quantized_leaf_stats(a, b, method, axis_name)
         if shard_ctx is not None:
             from jax.sharding import PartitionSpec as P
 
@@ -254,24 +232,20 @@ def _quantized_sync_stats(x, e, method: str, axis_name, use_kernel: bool,
         xbars.append(xbar)
         e_news.append(e_new)
     un = jax.tree_util.tree_unflatten
-    if return_payload:
-        return (un(treedef, qs), un(treedef, ss)), un(treedef, e_news)
     return un(treedef, xbars), un(treedef, e_news)
 
 
 @jax.named_scope("parle_sync")
 def consensus_step(state: ParleState, xbar, cfg, *,
                    use_kernel: bool = False, lr_scale=1.0,
-                   shard_ctx=None, payload=None) -> ParleState:
+                   shard_ctx=None) -> ParleState:
     """The Eq. (8c)-(8d) consensus update given an ALREADY-reduced
     ``xbar`` (un-broadcast model-shaped leaves — the replica mean the
     collective produced), plus the inner-loop reset and the Eq. (9)
-    scope decay.  ``payload``: alternative (q_tree, s_tree) gathered
-    int8 payloads for the fused dequantize+mean+update kernel (the
-    barrier kernel_compress path).  ``e``, ``c`` and ``step`` pass
-    through untouched — the caller owns them (the barrier sync updates
-    ``e`` from its stats; the overlapped head updates both ``e`` and
-    ``c`` from the NEXT payload)."""
+    scope decay.  ``e``, ``c`` and ``step`` pass through untouched —
+    the caller owns them (the barrier sync updates ``e`` from its
+    stats; the overlapped head updates both ``e`` and ``c`` from the
+    NEXT payload)."""
     mu, lr = cfg.momentum, cfg.lr * lr_scale
     inv_rho = 1.0 / state.scopes.rho
     cdtype = _compute_dtype(cfg)
@@ -283,16 +257,10 @@ def consensus_step(state: ParleState, xbar, cfg, *,
         # Under bf16 the compute-copy cast y' = cast(x') is fused into
         # the kernel (third output) — no separate cast pass.
         from repro.kernels import ops as kops
-        if payload is not None:
-            x, v_x, y = kops.parle_sync_dequant_update(
-                state.x, state.z, state.v_x, *payload,
-                gamma_scale=gamma_scale, inv_rho=inv_rho, lr=lr, mu=mu,
-                y_dtype=cdtype)
-        else:
-            x, v_x, y = kops.parle_sync_update(
-                state.x, state.z, state.v_x, xbar,
-                gamma_scale=gamma_scale, inv_rho=inv_rho, lr=lr, mu=mu,
-                shard_ctx=shard_ctx, y_dtype=cdtype)
+        x, v_x, y = kops.parle_sync_update(
+            state.x, state.z, state.v_x, xbar,
+            gamma_scale=gamma_scale, inv_rho=inv_rho, lr=lr, mu=mu,
+            shard_ctx=shard_ctx, y_dtype=cdtype)
     else:
         xbar = jax.tree.map(lambda m, x: jnp.broadcast_to(m[None], x.shape),
                             xbar, state.x)
@@ -315,34 +283,19 @@ def consensus_step(state: ParleState, xbar, cfg, *,
     )
 
 
-def _sync_stats(state: ParleState, cfg, axis_name, use_kernel, shard_ctx):
+def _sync_stats(state: ParleState, cfg, axis_name, shard_ctx):
     """The Eq. (8d) replica mean of the (optionally compressed) ``x+e``
     payload — the collective half of the sync, shared by the barrier
-    sync and the overlapped head.  Returns (xbar, payload, e_new):
-    exactly one of xbar (reduced model-shaped leaves) / payload
-    (gathered (q, s) int8 trees for the fused kernel) is non-None."""
+    sync and the overlapped head.  Returns (xbar, e_new): xbar holds
+    reduced model-shaped leaves."""
     method = _sync_compress(cfg)
-    e_new, xbar, payload = state.e, None, None
-    # the fused dequantize+mean+update kernel consumes the raw int8
-    # payloads; the planner-sharded path (shard_ctx) sticks to the jnp
-    # compression + per-shard update kernels
-    kernel_compress = (use_kernel and shard_ctx is None
-                       and method == "int8")
     if method != "none":
-        stats, e_new = _quantized_sync_stats(
-            state.x, state.e, method, axis_name,
-            use_kernel and shard_ctx is None,
-            return_payload=kernel_compress, shard_ctx=shard_ctx)
-        if kernel_compress:
-            payload = stats
-        else:
-            xbar = stats
-    elif axis_name is None:
-        xbar = tree_mean_axis0(state.x)
-    else:
-        xbar = jax.tree.map(lambda v: jax.lax.pmean(jnp.mean(v, axis=0),
-                                                    axis_name), state.x)
-    return xbar, payload, e_new
+        return _quantized_sync_stats(state.x, state.e, method, axis_name,
+                                     shard_ctx=shard_ctx)
+    if axis_name is None:
+        return tree_mean_axis0(state.x), state.e
+    return jax.tree.map(lambda v: jax.lax.pmean(jnp.mean(v, axis=0),
+                                                axis_name), state.x), state.e
 
 
 @jax.named_scope("parle_sync")
@@ -357,11 +310,10 @@ def sync_step(state: ParleState, cfg, axis_name: str | None = None,
     # regardless of how many replicas ride each device.  With
     # cfg.sync_compress the payload is quantized per replica and the
     # collective becomes an all_gather of the compressed bytes.
-    xbar, payload, e_new = _sync_stats(state, cfg, axis_name, use_kernel,
-                                       shard_ctx)
+    xbar, e_new = _sync_stats(state, cfg, axis_name, shard_ctx)
     return consensus_step(state._replace(e=e_new), xbar, cfg,
                           use_kernel=use_kernel, lr_scale=lr_scale,
-                          shard_ctx=shard_ctx, payload=payload)
+                          shard_ctx=shard_ctx)
 
 
 def fused_step(state: ParleState, grads, cfg, use_kernel: bool = False,
@@ -403,69 +355,21 @@ def overlap_head(state: ParleState, cfg, axis_name: str | None = None,
     residual, and carry the reduced mean in ``c``.  ``lr_scale`` is the
     apply's outer-lr multiplier — schedule(step - 1), the same value
     the barrier sync it replays would have used."""
-    method = _sync_compress(cfg)
-    if use_kernel and shard_ctx is None and method == "int8":
-        return _overlap_head_fused(state, cfg, axis_name, lr_scale)
     applied = jax.lax.cond(
         state.step > 0,
         lambda s: consensus_step(s, s.c, cfg, use_kernel=use_kernel,
                                  lr_scale=lr_scale, shard_ctx=shard_ctx),
         lambda s: s, state)
-    xbar, payload, e_new = _sync_stats(applied, cfg, axis_name, use_kernel,
-                                       shard_ctx)
-    assert payload is None        # the fused int8 path returned above
+    xbar, e_new = _sync_stats(applied, cfg, axis_name, shard_ctx)
     return applied._replace(e=e_new, c=xbar)
 
 
-def _overlap_head_fused(state: ParleState, cfg, axis_name,
-                        lr_scale) -> ParleState:
-    """The use_kernel int8 head: consensus apply + next-payload int8
-    quantize+EF fused into ONE memory pass (kernels/parle_update.py::
-    parle_apply_quantize_flat — the overlap counterpart of the barrier's
-    fused dequantize+mean+update kernel).  The first round (nothing in
-    flight) quantizes the initial x without applying."""
-    from repro.kernels import ops as kops
-    mu, lr = cfg.momentum, cfg.lr * lr_scale
-    inv_rho = 1.0 / state.scopes.rho
-    cdtype = _compute_dtype(cfg)
-    gamma_scale = 1.0 if cfg.scale_lr_by_gamma else 1.0 / state.scopes.gamma
-
-    def apply_quant(s):
-        x, v_x, y, q, sc, e = kops.parle_apply_consensus_quantize(
-            s.x, s.z, s.v_x, s.c, s.e, gamma_scale=gamma_scale,
-            inv_rho=inv_rho, lr=lr, mu=mu, y_dtype=cdtype)
-        s = s._replace(x=x, y=y, z=x, v_y=tree_zeros_like(x), v_x=v_x,
-                       scopes=update_scopes(s.scopes, cfg), e=e)
-        return s, (q, sc)
-
-    def quant_only(s):
-        flat, treedef = jax.tree_util.tree_flatten(s.x)
-        flat_e = treedef.flatten_up_to(s.e)
-        qs, ss, es = [], [], []
-        for xl, el in zip(flat, flat_e):
-            r, shape, m = xl.shape[0], xl.shape, xl[0].size
-            cpad = compress.pad_to_chunk(
-                (xl.astype(jnp.float32) + el).reshape(r, -1))
-            q, sc, res = kops.quantize_ef(cpad)
-            qs.append(q)
-            ss.append(sc)
-            es.append(res[:, :m].reshape(shape))
-        un = jax.tree_util.tree_unflatten
-        return (s._replace(e=un(treedef, es)),
-                (un(treedef, qs), un(treedef, ss)))
-
-    state, (q, sc) = jax.lax.cond(state.step > 0, apply_quant, quant_only,
-                                  state)
-
-    def reduce_leaf(xl, ql, sl):
-        if axis_name is not None:
-            ql = jax.lax.all_gather(ql, axis_name, axis=0, tiled=True)
-            sl = jax.lax.all_gather(sl, axis_name, axis=0, tiled=True)
-        deq = compress.dequantize(ql, sl, "int8")
-        return jnp.mean(deq, axis=0)[:xl[0].size].reshape(xl.shape[1:])
-
-    c_new = jax.tree.map(reduce_leaf, state.x, q, sc)
-    return state._replace(c=c_new)
+def _sync_scale(lr_schedule, state: ParleState):
+    """The outer-lr multiplier of a consensus update that closes the
+    round ending at ``state.step``: schedule(step - 1), the value of the
+    round's last inner step (the cond'd sync of the step loop fires
+    there)."""
+    return lr_schedule(state.step - 1) if lr_schedule is not None else 1.0
 
 
 def make_flush_fn(cfg, lr_schedule=None):
@@ -483,8 +387,7 @@ def make_flush_fn(cfg, lr_schedule=None):
 
     @jax.named_scope("parle_sync")
     def flush(state):
-        lr_scale = (lr_schedule(state.step - 1) if lr_schedule is not None
-                    else 1.0)
+        lr_scale = _sync_scale(lr_schedule, state)
         return jax.lax.cond(
             state.step > 0,
             lambda s: consensus_step(s, s.c, cfg, lr_scale=lr_scale),
@@ -494,16 +397,57 @@ def make_flush_fn(cfg, lr_schedule=None):
 
 
 # ------------------------------------------------------------------
-# Train-step factory
+# The per-replica inner step, and the step and round programs built
+# from it
 # ------------------------------------------------------------------
+
+def make_replica_step(loss_fn: Callable, cfg, *, weight_decay: float = 0.0,
+                      use_kernel: bool = False, axis_name: str | None = None,
+                      lr_schedule=None, shard_ctx=None, fused: bool = False):
+    """The one per-replica inner step every Parle program runs:
+    step(state, batch) -> (state, loss, losses).
+
+    Per-replica grads of ``loss_fn(params, batch) -> (scalar, aux)`` at
+    y^a (vmap over the leading replica axis), plus weight decay, then
+    :func:`inner_step` at this step's multiplier — ``lr_schedule``:
+    step -> multiplier on BOTH cfg.lr and cfg.lr_inner (the paper fixes
+    eta' to the initial eta, so they decay together).  With ``fused`` it
+    is :func:`fused_step` instead: the inner update plus the cond'd sync
+    every L steps, the body of the step loop.  ``losses`` is the
+    per-replica loss vector, ``loss`` its mean, pmean'd to its global
+    value over ``axis_name`` when that is set.  ``inner_step`` and
+    ``sync_step`` are looked up when the step is traced."""
+
+    def replica_grad(params, batch):
+        (loss, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
+        return loss, g
+
+    def step(s: ParleState, batch):
+        losses, grads = jax.vmap(replica_grad)(s.y, batch)
+        if weight_decay:
+            grads = jax.tree.map(lambda g, p: g + weight_decay * p,
+                                 grads, s.y)
+        lr_scale = lr_schedule(s.step) if lr_schedule is not None else 1.0
+        if fused:
+            s = fused_step(s, grads, cfg, use_kernel=use_kernel,
+                           axis_name=axis_name, lr_scale=lr_scale,
+                           shard_ctx=shard_ctx)
+        else:
+            s = inner_step(s, grads, cfg, use_kernel=use_kernel,
+                           lr_scale=lr_scale, shard_ctx=shard_ctx)
+        loss = jnp.mean(losses)
+        if axis_name is not None:
+            loss = jax.lax.pmean(loss, axis_name)
+        return s, loss, losses
+
+    return step
+
 
 def _make_step_body(loss_fn: Callable, cfg, weight_decay: float,
                     use_kernel: bool, axis_name: str | None,
                     lr_schedule=None, shard_ctx=None):
-    """Shared step body of the local and sharded train steps: per-replica
-    grads (vmap over the leading axis) -> fused_step -> metrics.
-    ``lr_schedule``: step -> multiplier on BOTH cfg.lr and cfg.lr_inner
-    (the paper fixes eta' to the initial eta, so they decay together).
+    """Shared step body of the local and sharded train steps: the fused
+    replica step and its metrics.
 
     Per-replica-loss metric contract: with ``axis_name`` set the leading
     axis inside this body holds only the LOCAL replicas, so the vector
@@ -512,34 +456,19 @@ def _make_step_body(loss_fn: Callable, cfg, weight_decay: float,
     (n,) vector from its P(replica) out-spec and republishes it as
     ``loss_per_replica`` (see partition.make_sharded_step_fn), so the
     public metric always covers every replica.  The scalar ``loss`` is
-    pmean'd to its global value right here."""
-
-    def replica_grad(params, batch):
-        (loss, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
-        return loss, g
+    pmean'd to its global value by the replica step."""
+    replica_step = make_replica_step(
+        loss_fn, cfg, weight_decay=weight_decay, use_kernel=use_kernel,
+        axis_name=axis_name, lr_schedule=lr_schedule, shard_ctx=shard_ctx,
+        fused=True)
+    loss_key = ("loss_per_replica" if axis_name is None
+                else "local_loss_per_replica")
 
     def step(state: ParleState, batch):
-        losses, grads = jax.vmap(replica_grad)(state.y, batch)
-        if weight_decay:
-            grads = jax.tree.map(lambda g, p: g + weight_decay * p,
-                                 grads, state.y)
-        lr_scale = lr_schedule(state.step) if lr_schedule is not None else 1.0
-        new_state = fused_step(state, grads, cfg, use_kernel=use_kernel,
-                               axis_name=axis_name, lr_scale=lr_scale,
-                               shard_ctx=shard_ctx)
-        loss = jnp.mean(losses)
-        loss_key = "loss_per_replica"
-        if axis_name is not None:
-            loss = jax.lax.pmean(loss, axis_name)
-            loss_key = "local_loss_per_replica"
-        metrics = {
-            "loss": loss,
-            loss_key: losses,
-            "gamma": new_state.scopes.gamma,
-            "rho": new_state.scopes.rho,
-            "step": new_state.step,
-        }
-        return new_state, metrics
+        state, loss, losses = replica_step(state, batch)
+        return state, {"loss": loss, loss_key: losses,
+                       "gamma": state.scopes.gamma, "rho": state.scopes.rho,
+                       "step": state.step}
 
     return step
 
@@ -598,11 +527,7 @@ def make_sharded_train_step(loss_fn: Callable, cfg, mesh,
                                   e=c(state.e) if state.e is not None
                                   else None)
 
-    # per-device shard: n_local = n / n_dev replicas on the leading axis.
-    # A size-1 replica axis (entropy_sgd under FSDP x TP) carries ALL
-    # replicas locally: the leading-axis mean already is the global mean,
-    # and XLA rejects a cross-partition pmean over a trivial manual axis.
-    axis_name = replica_axis if mesh.shape[replica_axis] > 1 else None
+    axis_name = _replica_axis_name(mesh, replica_axis)
     local_step = _make_step_body(loss_fn, cfg, weight_decay, use_kernel,
                                  axis_name=axis_name,
                                  lr_schedule=lr_schedule,
@@ -617,325 +542,176 @@ def make_sharded_train_step(loss_fn: Callable, cfg, mesh,
                                 constrain=constrain)
 
 
-# ------------------------------------------------------------------
-# Fused L-step rounds: one compiled program per Eq. (8) round
-# ------------------------------------------------------------------
-
-def _make_round_body(loss_fn: Callable, cfg, weight_decay: float,
-                     use_kernel: bool, axis_name: str | None,
-                     lr_schedule=None, shard_ctx=None):
-    """One whole Parle round as a single traced program: ``lax.scan``
-    over the L = cfg.L inner steps (8a-8b; zero cross-replica traffic)
-    followed by the sync update (8c-8d) — Python re-enters once per
-    round instead of once per step, and no per-step ``k % L`` cond sits
-    in the hot loop.
-
-    Contract: ``batches`` leaves carry a leading round axis of length
-    cfg.L (then the replica axis); the state's step counter must be a
-    multiple of L on entry (rounds tile the trajectory).  Under those
-    invariants the result is BIT-identical to L calls of the fused
-    step: the per-step lr_scale is evaluated at the same counters, and
-    the sync fires with the lr_scale of the round's last inner step
-    (schedule(step - 1)), exactly as the cond'd path does."""
-
-    def replica_grad(params, batch):
-        (loss, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
-        return loss, g
-
-    def round_fn(state: ParleState, batches):
-        def body(s, b):
-            losses, grads = jax.vmap(replica_grad)(s.y, b)
-            if weight_decay:
-                grads = jax.tree.map(lambda g, p: g + weight_decay * p,
-                                     grads, s.y)
-            lr_scale = (lr_schedule(s.step) if lr_schedule is not None
-                        else 1.0)
-            s = inner_step(s, grads, cfg, use_kernel=use_kernel,
-                           lr_scale=lr_scale, shard_ctx=shard_ctx)
-            loss = jnp.mean(losses)
-            if axis_name is not None:
-                loss = jax.lax.pmean(loss, axis_name)
-            return s, loss
-
-        state, losses = jax.lax.scan(body, state, batches)
-        sync_scale = (lr_schedule(state.step - 1) if lr_schedule is not None
-                      else 1.0)
-        state = sync_step(state, cfg, axis_name=axis_name,
-                          use_kernel=use_kernel, lr_scale=sync_scale,
-                          shard_ctx=shard_ctx)
-        metrics = {"loss": jnp.mean(losses), "losses": losses,
-                   "gamma": state.scopes.gamma, "rho": state.scopes.rho,
-                   "step": state.step}
-        return state, metrics
-
-    return round_fn
+def _replica_axis_name(mesh, replica_axis: str):
+    """The collective axis of a replica-sharded body.  Each device holds
+    n / |replica_axis| replicas on the leading axis.  A size-1 replica
+    axis (entropy_sgd under FSDP x TP) carries ALL replicas locally: the
+    leading-axis mean already is the global mean, and XLA rejects a
+    cross-partition pmean over a trivial manual axis."""
+    return replica_axis if mesh.shape[replica_axis] > 1 else None
 
 
-def make_round_fn(loss_fn: Callable, cfg, weight_decay: float = 0.0,
+def _round_metrics(state: ParleState, losses):
+    return {"loss": jnp.mean(losses), "losses": losses,
+            "gamma": state.scopes.gamma, "rho": state.scopes.rho,
+            "step": state.step}
+
+
+def _scan_steps(step, state: ParleState, batches):
+    """The round's L inner steps as one ``lax.scan`` -> (state, losses
+    (L,))."""
+    def body(s, b):
+        s, loss, _ = step(s, b)
+        return s, loss
+
+    return jax.lax.scan(body, state, batches)
+
+
+def make_round_fn(loss_fn: Callable, cfg, *, mesh=None,
+                  replica_axis: str = "replica", weight_decay: float = 0.0,
                   use_kernel: bool = False, lr_schedule=None):
-    """Local (vmap-replica) fused round, compiled with DONATED state
-    buffers: round(state, batches) -> (state, metrics); ``batches``
-    leaves are (L, n, B, ...).  Metrics: scalar round-mean ``loss`` plus
-    the per-step ``losses`` (L,).
+    """One whole Parle round as compiled, state-DONATING code:
+    round(state, batches) -> (state, metrics).  ``batches`` leaves are
+    (L, n, B, ...); metrics are the scalar round-mean ``loss``, the
+    per-step ``losses`` (L,), ``gamma``, ``rho`` and ``step``.  Python
+    re-enters once per round instead of once per step, and no per-step
+    ``k % L`` cond sits in the hot loop.
+
+    The round scans the L = cfg.L inner steps (8a-8b; zero
+    cross-replica traffic) and places the sync (8c-8d) by
+    ``cfg.sync_overlap``: after the scan (barrier), or as
+    :func:`overlap_head` before it (staleness-1; pair with
+    :func:`make_flush_fn`).  The overlapped scan carry EXCLUDES ``c``
+    and ``e``: the inner steps never read them, and keeping the
+    collective's result out of the while loop's operands frees the
+    latency-hiding scheduler to run the collective concurrently with
+    the scan.
+
+    Contract: the state's step counter is a multiple of L on entry
+    (rounds tile the trajectory).  Then the barrier round is
+    BIT-identical to L calls of the fused step (the per-step lr_scale is
+    evaluated at the same counters and the sync fires at
+    schedule(step - 1), as the cond'd path does), and R overlap rounds
+    plus one flush are bit-identical to R barrier rounds.
+
+    With a ``mesh`` the replica axis of state and batches is sharded
+    over ``replica_axis``:
+
+    * replica-only mesh: the round runs under a fully-manual shard_map,
+      the sync pmean / compressed all_gather firing once per round; the
+      result is bit-identical to the sharded step loop on the same mesh
+      (local vs sharded differ by the all-reduce's summation order).
+    * composed mesh (in-replica "data"/"model" axes): two programs, see
+      :func:`_round_program`.
 
     Donation note: the input state's buffers are consumed.  A state
     fresh out of :func:`init` aliases x = y = z (one buffer); de-alias
     it once with :func:`dealias_state` before the first call.
     """
-    body = _make_round_body(loss_fn, cfg, weight_decay, use_kernel,
-                            axis_name=None, lr_schedule=lr_schedule)
-    return jax.jit(body, donate_argnums=(0,))
+    return _round_program(loss_fn, cfg,
+                          "overlap" if _sync_overlap(cfg) else "barrier",
+                          mesh, replica_axis, weight_decay, use_kernel,
+                          lr_schedule)
 
 
-def make_sharded_round_fn(loss_fn: Callable, cfg, mesh,
-                          replica_axis: str = "replica",
-                          weight_decay: float = 0.0,
-                          use_kernel: bool = False, lr_schedule=None):
-    """Distributed fused round.
+def _round_program(loss_fn: Callable, cfg, sync: str | None, mesh,
+                   replica_axis: str, weight_decay: float, use_kernel: bool,
+                   lr_schedule):
+    """Builds every Parle round program, for :func:`make_round_fn` and
+    :func:`make_inner_round_fn`.  ``sync`` places the sync: "barrier"
+    (:func:`sync_step` after the scan), "overlap" (:func:`overlap_head`
+    before it) or None (the scan alone; the async policy applies the
+    consensus outside the program).
 
-    Replica-only meshes run the round body under the PR-1 fully-manual
-    shard_map — the scan carries replica-sharded state, the sync pmean /
-    compressed all_gather fires once after it, and the result is
-    bit-identical to the sharded per-step loop on the same mesh (local
-    vs sharded differ by the all-reduce's summation order, ulps).
-
-    Composed meshes (in-replica "data"/"model" axes) cannot scan inside
-    a partial-manual shard_map body on the pinned jax 0.4.37 (XLA's
-    manual-subgroup propagation check trips — the ROADMAP limit), so the
-    round splits: the L inner steps run as pure-GSPMD jit over globally
-    sharded state (they carry no cross-replica collective to lower
-    manually), and the sync runs under the same partial-manual shard_map
-    as the per-step path — keeping the explicit pmean / compressed
-    gather on the wire.  GSPMD partitions the matmul reductions of the
-    inner steps slightly differently than the manual path, so composed-
-    mesh rounds match the step loop to float tolerance, not bit-for-bit
-    (same contract as PR 3's composed-mesh step).
-    """
+    A composed mesh splits the round into two compiled programs, called
+    in the round's order: ``inner_jit``, the L inner steps as a
+    pure-GSPMD scan over globally sharded state (they carry no
+    cross-replica collective to lower manually), and ``sync_jit``, the
+    sync or head under the partial-manual shard_map of the step loop,
+    which keeps the explicit pmean / compressed gather on the wire.  The
+    form dates from jax 0.4.37, where XLA's manual-subgroup check
+    rejected a scan inside a partial-manual shard_map, and a scan and a
+    partial-manual region in one module.  On jax 0.9.0 both forms
+    compile and run on 4 CPU devices (replica:2,data:2 and
+    replica:2,model:2, a two-layer Mamba2 and a toy model, with and
+    without int8 compression), their x within 1.2e-7 of this form's after
+    two rounds; the split stays because the benchmark lowers the two
+    programs by name.  The GSPMD scan takes the XLA update and no
+    ``shard_ctx`` whatever ``use_kernel`` says: the Pallas kernels run
+    each leaf under a nested shard_map, a manual region that would put
+    back into the scan what the split takes out.  GSPMD partitions the inner steps'
+    reductions differently from the manual path, so composed rounds
+    match the step loop to float tolerance, not bit-for-bit."""
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding import planner
     from repro.sharding.partition import parle_state_pspecs
     from repro.utils.compat import shard_map
 
-    axis_name = replica_axis if mesh.shape[replica_axis] > 1 else None
-    specs = parle_state_pspecs(replica_axis, cfg=cfg)
-    metric_specs = {"loss": P(), "losses": P(), "gamma": P(), "rho": P(),
-                    "step": P()}
-    n_dev = mesh.shape[replica_axis]
-    if cfg.n_replicas % n_dev != 0:
-        raise ValueError(
-            f"n_replicas={cfg.n_replicas} not divisible by "
-            f"mesh axis {replica_axis!r} of size {n_dev}")
-
-    if not planner.in_replica_axes(mesh, replica_axis):
-        body = _make_round_body(loss_fn, cfg, weight_decay, use_kernel,
-                                axis_name=axis_name,
-                                lr_schedule=lr_schedule)
-        return jax.jit(shard_map(body, mesh,
-                                 in_specs=(specs, P(None, replica_axis)),
-                                 out_specs=(specs, metric_specs)),
-                       donate_argnums=(0,))
-
-    # composed mesh: GSPMD inner scan + partial-manual shard_map sync.
-    # The two live in SEPARATE compiled programs: a jit module holding
-    # both a while-loop (the scan) and manual-subgroup regions (the
-    # shard_map sync) trips the same XLA propagation check as the
-    # scan-inside-shard_map form, so the round dispatches two programs
-    # instead of one — still O(1) Python re-entries per L steps, and
-    # the sync keeps its explicit (optionally compressed) collective.
-    shard_ctx = planner.make_shard_context(mesh, replica_axis)
-    auto = frozenset(planner.in_replica_axes(mesh, replica_axis))
-
-    def replica_grad(params, batch):
-        (loss, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
-        return loss, g
-
-    def inner_scan(state, batches):
-        def scan_body(s, b):      # inner steps: no cross-replica comms,
-            losses, grads = jax.vmap(replica_grad)(s.y, b)   # GSPMD-global
-            if weight_decay:
-                grads = jax.tree.map(lambda g, p: g + weight_decay * p,
-                                     grads, s.y)
-            lr_scale = (lr_schedule(s.step) if lr_schedule is not None
-                        else 1.0)
-            s = inner_step(s, grads, cfg, use_kernel=False,
-                           lr_scale=lr_scale)
-            return s, jnp.mean(losses)
-
-        return jax.lax.scan(scan_body, state, batches)
+    axis_name = shard_ctx = None
+    if mesh is not None:
+        n_dev = mesh.shape[replica_axis]
+        if cfg.n_replicas % n_dev != 0:
+            raise ValueError(
+                f"n_replicas={cfg.n_replicas} not divisible by "
+                f"mesh axis {replica_axis!r} of size {n_dev}")
+        axis_name = _replica_axis_name(mesh, replica_axis)
+        shard_ctx = planner.make_shard_context(mesh, replica_axis)
+        specs = parle_state_pspecs(replica_axis, cfg=cfg)
 
     def sync_body(state):
-        lr_scale = (lr_schedule(state.step - 1) if lr_schedule is not None
-                    else 1.0)
-        return sync_step(state, cfg, axis_name=axis_name,
-                         use_kernel=use_kernel, lr_scale=lr_scale,
-                         shard_ctx=shard_ctx)
+        place = overlap_head if sync == "overlap" else sync_step
+        return place(state, cfg, axis_name=axis_name, use_kernel=use_kernel,
+                     lr_scale=_sync_scale(lr_schedule, state),
+                     shard_ctx=shard_ctx)
 
-    inner_jit = jax.jit(inner_scan, donate_argnums=(0,))
-    sync_jit = jax.jit(shard_map(sync_body, mesh, in_specs=(specs,),
-                                 out_specs=specs, auto=auto),
-                       donate_argnums=(0,))
+    if shard_ctx is not None:
+        gspmd_step = make_replica_step(loss_fn, cfg,
+                                       weight_decay=weight_decay,
+                                       lr_schedule=lr_schedule)
 
-    def round_fn(state, batches):
-        state, losses = inner_jit(state, batches)
-        state = sync_jit(state)
-        return state, {"loss": jnp.mean(losses), "losses": losses,
-                       "gamma": state.scopes.gamma,
-                       "rho": state.scopes.rho, "step": state.step}
+        def inner_scan(state, batches):
+            return _scan_steps(gspmd_step, state, batches)
 
-    return round_fn
+        inner_jit = jax.jit(inner_scan, donate_argnums=(0,))
+        auto = frozenset(planner.in_replica_axes(mesh, replica_axis))
+        sync_jit = jax.jit(shard_map(sync_body, mesh, in_specs=(specs,),
+                                     out_specs=specs, auto=auto),
+                           donate_argnums=(0,))
 
+        def round_fn(state, batches):
+            if sync == "overlap":
+                state = sync_jit(state)
+            state, losses = inner_jit(state, batches)
+            if sync == "barrier":
+                state = sync_jit(state)
+            return state, _round_metrics(state, losses)
 
-# ------------------------------------------------------------------
-# Overlapped rounds (cfg.sync_overlap): head-first program rotation
-# ------------------------------------------------------------------
+        return round_fn
 
-def _make_overlap_round_body(loss_fn: Callable, cfg, weight_decay: float,
-                             use_kernel: bool, axis_name: str | None,
-                             lr_schedule=None, shard_ctx=None):
-    """One staleness-1 overlapped round: :func:`overlap_head` (apply the
-    carried consensus, issue this round's collective) then the L inner
-    steps.  The scan carry deliberately EXCLUDES ``c`` and ``e``: the
-    inner steps never read them, and keeping the collective's result out
-    of the while loop's operands is what frees the latency-hiding
-    scheduler to run the collective concurrently with the scan — a
-    carried ``c`` would make the loop's input depend on it, a barrier in
-    dataflow.  Same entry invariants and metric contract as
-    :func:`_make_round_body`; per-round losses are bit-identical to the
-    barrier round's (the scan starts from the same post-consensus
-    state), and the output state trails it by exactly the in-flight
-    ``c`` (see :func:`make_flush_fn`)."""
-
-    def replica_grad(params, batch):
-        (loss, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
-        return loss, g
+    step = make_replica_step(loss_fn, cfg, weight_decay=weight_decay,
+                             use_kernel=use_kernel, axis_name=axis_name,
+                             lr_schedule=lr_schedule)
 
     def round_fn(state: ParleState, batches):
-        apply_scale = (lr_schedule(state.step - 1)
-                       if lr_schedule is not None else 1.0)
-        head = overlap_head(state, cfg, axis_name=axis_name,
-                            use_kernel=use_kernel, lr_scale=apply_scale,
-                            shard_ctx=shard_ctx)
+        if sync == "overlap":
+            head = sync_body(state)     # c and e stay out of the carry
+            state, losses = _scan_steps(
+                step, head._replace(c=None, e=None), batches)
+            state = state._replace(c=head.c, e=head.e)
+        else:
+            state, losses = _scan_steps(step, state, batches)
+            if sync == "barrier":
+                state = sync_body(state)
+        return state, _round_metrics(state, losses)
 
-        def body(s, b):
-            losses, grads = jax.vmap(replica_grad)(s.y, b)
-            if weight_decay:
-                grads = jax.tree.map(lambda g, p: g + weight_decay * p,
-                                     grads, s.y)
-            lr_scale = (lr_schedule(s.step) if lr_schedule is not None
-                        else 1.0)
-            s = inner_step(s, grads, cfg, use_kernel=use_kernel,
-                           lr_scale=lr_scale, shard_ctx=shard_ctx)
-            loss = jnp.mean(losses)
-            if axis_name is not None:
-                loss = jax.lax.pmean(loss, axis_name)
-            return s, loss
-
-        inner, losses = jax.lax.scan(body, head._replace(c=None, e=None),
-                                     batches)
-        state = inner._replace(c=head.c, e=head.e)
-        metrics = {"loss": jnp.mean(losses), "losses": losses,
-                   "gamma": state.scopes.gamma, "rho": state.scopes.rho,
-                   "step": state.step}
-        return state, metrics
-
-    return round_fn
-
-
-def make_overlap_round_fn(loss_fn: Callable, cfg, weight_decay: float = 0.0,
-                          use_kernel: bool = False, lr_schedule=None):
-    """Local (vmap-replica) overlapped round; same donation contract as
-    :func:`make_round_fn`.  Pair with :func:`make_flush_fn` to
-    materialize the final consensus after the last round."""
-    body = _make_overlap_round_body(loss_fn, cfg, weight_decay, use_kernel,
-                                    axis_name=None, lr_schedule=lr_schedule)
-    return jax.jit(body, donate_argnums=(0,))
-
-
-def make_sharded_overlap_round_fn(loss_fn: Callable, cfg, mesh,
-                                  replica_axis: str = "replica",
-                                  weight_decay: float = 0.0,
-                                  use_kernel: bool = False,
-                                  lr_schedule=None):
-    """Distributed overlapped round.
-
-    Replica-only meshes: one fully-manual shard_map program, like the
-    barrier round — but with the collective FIRST and the scan after it,
-    so the all-gather / all-reduce sits before the while loop in the
-    schedule instead of on the critical path behind it.
-
-    Composed meshes split head and scan into separate programs (the
-    rotated form of the barrier path's jax 0.4.37 workaround — see
-    :func:`make_sharded_round_fn`): the head runs under the partial-
-    manual shard_map (cond'd apply + explicit collective, no scan), the
-    L inner steps as pure-GSPMD jit.  Same float-tolerance contract as
-    the composed barrier round."""
-    from jax.sharding import PartitionSpec as P
-
-    from repro.sharding import planner
-    from repro.sharding.partition import parle_state_pspecs
-    from repro.utils.compat import shard_map
-
-    axis_name = replica_axis if mesh.shape[replica_axis] > 1 else None
-    specs = parle_state_pspecs(replica_axis, cfg=cfg)
-    metric_specs = {"loss": P(), "losses": P(), "gamma": P(), "rho": P(),
-                    "step": P()}
-    n_dev = mesh.shape[replica_axis]
-    if cfg.n_replicas % n_dev != 0:
-        raise ValueError(
-            f"n_replicas={cfg.n_replicas} not divisible by "
-            f"mesh axis {replica_axis!r} of size {n_dev}")
-
-    if not planner.in_replica_axes(mesh, replica_axis):
-        body = _make_overlap_round_body(loss_fn, cfg, weight_decay,
-                                        use_kernel, axis_name=axis_name,
-                                        lr_schedule=lr_schedule)
-        return jax.jit(shard_map(body, mesh,
-                                 in_specs=(specs, P(None, replica_axis)),
-                                 out_specs=(specs, metric_specs)),
-                       donate_argnums=(0,))
-
-    shard_ctx = planner.make_shard_context(mesh, replica_axis)
-    auto = frozenset(planner.in_replica_axes(mesh, replica_axis))
-
-    def replica_grad(params, batch):
-        (loss, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
-        return loss, g
-
-    def head_body(state):
-        apply_scale = (lr_schedule(state.step - 1)
-                       if lr_schedule is not None else 1.0)
-        return overlap_head(state, cfg, axis_name=axis_name,
-                            use_kernel=use_kernel, lr_scale=apply_scale,
-                            shard_ctx=shard_ctx)
-
-    def inner_scan(state, batches):
-        def scan_body(s, b):
-            losses, grads = jax.vmap(replica_grad)(s.y, b)
-            if weight_decay:
-                grads = jax.tree.map(lambda g, p: g + weight_decay * p,
-                                     grads, s.y)
-            lr_scale = (lr_schedule(s.step) if lr_schedule is not None
-                        else 1.0)
-            s = inner_step(s, grads, cfg, use_kernel=False,
-                           lr_scale=lr_scale)
-            return s, jnp.mean(losses)
-
-        return jax.lax.scan(scan_body, state, batches)
-
-    head_jit = jax.jit(shard_map(head_body, mesh, in_specs=(specs,),
-                                 out_specs=specs, auto=auto),
-                       donate_argnums=(0,))
-    inner_jit = jax.jit(inner_scan, donate_argnums=(0,))
-
-    def round_fn(state, batches):
-        state = head_jit(state)
-        state, losses = inner_jit(state, batches)
-        return state, {"loss": jnp.mean(losses), "losses": losses,
-                       "gamma": state.scopes.gamma,
-                       "rho": state.scopes.rho, "step": state.step}
-
-    return round_fn
+    if mesh is not None:
+        metric_specs = {"loss": P(), "losses": P(), "gamma": P(),
+                        "rho": P(), "step": P()}
+        round_fn = shard_map(round_fn, mesh,
+                             in_specs=(specs, P(None, replica_axis)),
+                             out_specs=(specs, metric_specs))
+    return jax.jit(round_fn, donate_argnums=(0,))
 
 
 # ------------------------------------------------------------------
@@ -1050,30 +826,8 @@ def make_inner_round_fn(loss_fn: Callable, cfg, weight_decay: float = 0.0,
     entry invariants and metric contract as :func:`make_round_fn`;
     because ``x`` only changes at the consensus apply, the pushed payload
     is identical whether it is snapshotted before or after the scan."""
-
-    def replica_grad(params, batch):
-        (loss, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
-        return loss, g
-
-    def round_fn(state: ParleState, batches):
-        def body(s, b):
-            losses, grads = jax.vmap(replica_grad)(s.y, b)
-            if weight_decay:
-                grads = jax.tree.map(lambda g, p: g + weight_decay * p,
-                                     grads, s.y)
-            lr_scale = (lr_schedule(s.step) if lr_schedule is not None
-                        else 1.0)
-            s = inner_step(s, grads, cfg, use_kernel=use_kernel,
-                           lr_scale=lr_scale)
-            return s, jnp.mean(losses)
-
-        state, losses = jax.lax.scan(body, state, batches)
-        metrics = {"loss": jnp.mean(losses), "losses": losses,
-                   "gamma": state.scopes.gamma, "rho": state.scopes.rho,
-                   "step": state.step}
-        return state, metrics
-
-    return jax.jit(round_fn, donate_argnums=(0,))
+    return _round_program(loss_fn, cfg, None, None, "replica", weight_decay,
+                          use_kernel, lr_schedule)
 
 
 def async_contribution(state: ParleState, cfg):
@@ -1136,9 +890,8 @@ def make_async_apply_fn(cfg, lr_schedule=None):
 
     @jax.named_scope("parle_sync")
     def apply(state, xbar):
-        lr_scale = (lr_schedule(state.step - 1) if lr_schedule is not None
-                    else 1.0)
-        return consensus_step(state, xbar, cfg, lr_scale=lr_scale)
+        return consensus_step(state, xbar, cfg,
+                              lr_scale=_sync_scale(lr_schedule, state))
 
     return jax.jit(apply, donate_argnums=(0,))
 

@@ -72,50 +72,6 @@ def parle_sync_update(x, z, v, xbar, *, gamma_scale, inv_rho, lr, mu,
     return x2, v2, x2
 
 
-def parle_sync_dequant_update(x, z, v, q_tree, s_tree, *, gamma_scale,
-                              inv_rho, lr, mu, y_dtype=None):
-    """Fused dequantize + replica-mean + sync update (int8 compressed
-    sync).  Returns (x', v', y') like :func:`parle_sync_update`."""
-    import jax.numpy as jnp
-    emit_y = y_dtype is not None and jnp.dtype(y_dtype) != jnp.float32
-    out = _pu.parle_sync_dequant_tree(
-        x, z, v, q_tree, s_tree, gamma_scale=gamma_scale, inv_rho=inv_rho,
-        lr=lr, mu=mu, interpret=_interpret(),
-        y_dtype=y_dtype if emit_y else None)
-    if emit_y:
-        return out
-    x2, v2 = out
-    return x2, v2, x2
-
-
-def quantize_ef(c):
-    """Fused per-chunk int8 quantize + error-feedback residual on a flat
-    (R, M) stream (M % 8192 == 0).  Returns (q, scales, residual)."""
-    return _pu.quantize_ef_flat(c, interpret=_interpret())
-
-
-def parle_apply_consensus_quantize(x, z, v, c, e, *, gamma_scale, inv_rho,
-                                   lr, mu, y_dtype=None):
-    """Fused staleness-1 overlap head (int8 compressed sync): apply the
-    CARRIED consensus ``c`` (Eq. 8c-8d with the stale mean) and quantize
-    the new x + e as the next sync's payload, one memory pass.  Returns
-    (x', v', y', q_tree, s_tree, e') — y' is x' on f32, the fused cast
-    on bf16, like :func:`parle_sync_update`; q/s leaves are the FLAT
-    padded wire payloads (see parle_update.parle_apply_quantize_tree)."""
-    import jax.numpy as jnp
-    emit_y = y_dtype is not None and jnp.dtype(y_dtype) != jnp.float32
-    out = _pu.parle_apply_quantize_tree(
-        x, z, v, c, e, gamma_scale=gamma_scale, inv_rho=inv_rho, lr=lr,
-        mu=mu, interpret=_interpret(),
-        y_dtype=y_dtype if emit_y else None)
-    if emit_y:
-        x2, v2, q, s, e2, y2 = out
-    else:
-        x2, v2, q, s, e2 = out
-        y2 = x2
-    return x2, v2, y2, q, s, e2
-
-
 def elastic_worker_update(x, v, g, ref, *, inv_rho, lr, mu,
                           shard_ctx=None):
     return _pu.elastic_update_tree(x, v, g, ref, inv_rho=inv_rho,

@@ -311,261 +311,3 @@ def elastic_update_tree(x, v, g, ref, *, inv_rho, lr, mu,
     scalars = _pack_scalars(inv_rho, lr, mu)
     return _replicated_shared_tree(elastic_update_leaf, (x, v, g), ref,
                                    scalars, interpret, shard_ctx=shard_ctx)
-
-
-# ------------------------------------------------------------------
-# Compressed sync (Eq. 8d payload): fused quantize+error-feedback and
-# dequantize+mean+update kernels.  Chunk layout matches
-# core/compress.py exactly (CHUNK = the 1024 lane dim, streams padded
-# to BLOCK_ELEMS), so kernel and jnp reference produce bit-identical
-# payloads; oracles in kernels/ref.py.
-# ------------------------------------------------------------------
-
-def _quant_ef_kernel(c_ref, q_out, s_out, e_out):
-    """Per block (1, 8, 1024): one int8 payload row + one f32 scale per
-    1024-chunk + the error-feedback residual, in a single pass (1 read,
-    ~1.25 writes of the stream)."""
-    c = c_ref[0]                             # (8, 1024) f32
-    amax = jnp.max(jnp.abs(c), axis=-1, keepdims=True)      # (8, 1)
-    scale = jnp.where(amax == 0, 1.0, amax * (1.0 / 127.0))
-    q = jnp.clip(jnp.round(c / scale), -127, 127)
-    deq = q * scale
-    q_out[0] = q.astype(jnp.int8)
-    s_out[0] = scale
-    e_out[0] = c - deq
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def quantize_ef_flat(c, interpret: bool):
-    """c: (R, M) f32 with M % BLOCK_ELEMS == 0.  Returns (q, scales, e):
-    q (R, M) int8, scales (R, M // 1024) f32, e = c - dequant(q) f32.
-
-    Inside the kernel the scales are an (R, rows, 1) column, one (8, 1)
-    block per grid step: the TPU tiles the last two block dims in
-    (8, 128) units or takes them whole."""
-    r, m = c.shape
-    rows = m // BLOCK[1]
-    grid = (r, rows // BLOCK[0])
-    spec = pl.BlockSpec((1,) + BLOCK, lambda a, i: (a, i, 0))
-    s_spec = pl.BlockSpec((1, BLOCK[0], 1), lambda a, i: (a, i, 0))
-    out_shape = [
-        jax.ShapeDtypeStruct((r, rows, BLOCK[1]), jnp.int8),
-        jax.ShapeDtypeStruct((r, rows, 1), jnp.float32),
-        jax.ShapeDtypeStruct((r, rows, BLOCK[1]), jnp.float32),
-    ]
-    q, s, e = pl.pallas_call(
-        _quant_ef_kernel,
-        grid=grid,
-        in_specs=[spec],
-        out_specs=[spec, s_spec, spec],
-        out_shape=out_shape,
-        name="quantize_ef",
-        interpret=interpret,
-    )(c.reshape(r, rows, BLOCK[1]))
-    return q.reshape(r, m), s.reshape(r, rows), e.reshape(r, m)
-
-
-def _dequant_sync_kernel(scal_ref, x_ref, z_ref, v_ref, q_ref, s_ref,
-                         x_out, v_out, *maybe_y_out):
-    """Sync update with the replica mean reconstructed INSIDE the kernel
-    from the gathered quantized payloads: dequantize (n, 8, 1024) int8
-    blocks with their per-chunk scales, mean over n, then Eq. 8c-8d —
-    xbar never round-trips HBM as f32."""
-    gamma_scale = scal_ref[0]
-    inv_rho = scal_ref[1]
-    lr = scal_ref[2]
-    mu = scal_ref[3]
-    deq = q_ref[...].astype(jnp.float32) * s_ref[...]   # (n, 8, 1) scales
-    xbar = jnp.mean(deq, axis=0)             # (8, 1024)
-    x = x_ref[0]
-    g_x = gamma_scale * (x - z_ref[0]) + inv_rho * (x - xbar)
-    v_new = mu * v_ref[0] + g_x
-    x_new = x - lr * (g_x + mu * v_new)
-    x_out[0] = x_new
-    v_out[0] = v_new
-    if maybe_y_out:                          # fused y' = cast(x')
-        maybe_y_out[0][0] = x_new.astype(maybe_y_out[0].dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "y_dtype"))
-def parle_sync_dequant_flat(x, z, v, q, s, scalars, interpret: bool,
-                            y_dtype=None):
-    """Fused dequantize + replica-mean + sync update.
-
-    x, z, v: (R, M) f32 (R = local replicas); q: (n, M) int8 — the
-    all-gathered per-replica payloads of ALL n global replicas; s:
-    (n, M // 1024) f32 per-chunk scales; scalars as parle_sync_leaf.
-    Returns (x', v') or (x', v', y') like :func:`parle_sync_leaf`.
-    """
-    r, m = x.shape
-    n = q.shape[0]
-    rows = m // BLOCK[1]
-    grid = (r, rows // BLOCK[0])
-    shaped = lambda a: a.reshape(r, rows, BLOCK[1])
-    spec = pl.BlockSpec((1,) + BLOCK, lambda a, i, _s: (a, i, 0))
-    q_spec = pl.BlockSpec((n,) + BLOCK, lambda a, i, _s: (0, i, 0))
-    s_spec = pl.BlockSpec((n, BLOCK[0], 1), lambda a, i, _s: (0, i, 0))
-    emit_y = y_dtype is not None and jnp.dtype(y_dtype) != x.dtype
-    out_dtypes = [x.dtype, v.dtype] + ([jnp.dtype(y_dtype)] if emit_y else [])
-    out_shape = [jax.ShapeDtypeStruct((r, rows, BLOCK[1]), d)
-                 for d in out_dtypes]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[spec] * 3 + [q_spec, s_spec],
-        out_specs=[spec] * len(out_shape),
-    )
-    outs = pl.pallas_call(
-        _dequant_sync_kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        name="dequant_sync",
-        interpret=interpret,
-    )(scalars, shaped(x), shaped(z), shaped(v),
-      q.reshape(n, rows, BLOCK[1]), s.reshape(n, rows, 1))
-    return tuple(o.reshape(r, m) for o in outs)
-
-
-def _apply_quant_kernel(scal_ref, x_ref, z_ref, v_ref, c_ref, e_ref,
-                        x_out, v_out, q_out, s_out, e_out, *maybe_y_out):
-    """Staleness-1 overlap head, one pass: apply the CARRIED consensus
-    (Eq. 8c-8d with the stale mean c) and immediately quantize the new
-    x + e as the NEXT sync's int8 payload with error feedback — the
-    overlap counterpart of _dequant_sync_kernel (which fuses the other
-    end of the pipe).  5 reads + ~4.25 writes of the stream instead of
-    the two separate kernels' 7 reads + ~5.25 writes."""
-    gamma_scale = scal_ref[0]
-    inv_rho = scal_ref[1]
-    lr = scal_ref[2]
-    mu = scal_ref[3]
-    x = x_ref[0]                       # (8, 1024); replica dim blocked at 1
-    g_x = gamma_scale * (x - z_ref[0]) + inv_rho * (x - c_ref[...])
-    v_new = mu * v_ref[0] + g_x
-    x_new = x - lr * (g_x + mu * v_new)
-    ctot = x_new + e_ref[0]            # next payload, error fed back
-    amax = jnp.max(jnp.abs(ctot), axis=-1, keepdims=True)   # (8, 1)
-    scale = jnp.where(amax == 0, 1.0, amax * (1.0 / 127.0))
-    q = jnp.clip(jnp.round(ctot / scale), -127, 127)
-    x_out[0] = x_new
-    v_out[0] = v_new
-    q_out[0] = q.astype(jnp.int8)
-    s_out[0] = scale
-    e_out[0] = ctot - q * scale
-    if maybe_y_out:                    # fused y' = cast(x') (bf16 path)
-        maybe_y_out[0][0] = x_new.astype(maybe_y_out[0].dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "y_dtype"))
-def parle_apply_quantize_flat(x, z, v, c, e, scalars, interpret: bool,
-                              y_dtype=None):
-    """x, z, v, e: (R, M) f32; c: (M,) f32 with M % BLOCK_ELEMS == 0 —
-    the carried staleness-1 consensus, re-read per replica grid step
-    like xbar in parle_sync_leaf; scalars: (4,) f32 =
-    [gamma_scale, inv_rho, lr, mu].
-
-    Returns (x', v', q, s, e') or (x', v', q, s, e', y'): the applied
-    iterates plus the next sync's quantized payload — q (R, M) int8,
-    s (R, M // 1024) f32 per-chunk scales, e' the error-feedback
-    residual.  Chunking matches core/compress.py exactly, so payloads
-    are bit-identical to the jnp codec's."""
-    r, m = x.shape
-    rows = m // BLOCK[1]
-    grid = (r, rows // BLOCK[0])
-    shaped = lambda a: a.reshape(r, rows, BLOCK[1])
-    spec = pl.BlockSpec((1,) + BLOCK, lambda a, i, _s: (a, i, 0))
-    bar_spec = pl.BlockSpec(BLOCK, lambda a, i, _s: (i, 0))
-    s_spec = pl.BlockSpec((1, BLOCK[0], 1), lambda a, i, _s: (a, i, 0))
-    emit_y = y_dtype is not None and jnp.dtype(y_dtype) != x.dtype
-    out_shape = [
-        jax.ShapeDtypeStruct((r, rows, BLOCK[1]), x.dtype),
-        jax.ShapeDtypeStruct((r, rows, BLOCK[1]), v.dtype),
-        jax.ShapeDtypeStruct((r, rows, BLOCK[1]), jnp.int8),
-        jax.ShapeDtypeStruct((r, rows, 1), jnp.float32),
-        jax.ShapeDtypeStruct((r, rows, BLOCK[1]), jnp.float32),
-    ] + ([jax.ShapeDtypeStruct((r, rows, BLOCK[1]), jnp.dtype(y_dtype))]
-         if emit_y else [])
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[spec] * 3 + [bar_spec, spec],
-        out_specs=[spec] * 3 + [s_spec, spec] + ([spec] if emit_y else []),
-    )
-    outs = pl.pallas_call(
-        _apply_quant_kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        name="apply_quantize",
-        interpret=interpret,
-    )(scalars, shaped(x), shaped(z), shaped(v),
-      c.reshape(rows, BLOCK[1]), shaped(e))
-    x2, v2, q, s, e2, *ys = outs
-    flat = lambda a: a.reshape(r, m)
-    res = (flat(x2), flat(v2), flat(q), s.reshape(r, rows), flat(e2))
-    return res + (flat(ys[0]),) if ys else res
-
-
-def parle_apply_quantize_tree(x, z, v, c, e, *, gamma_scale, inv_rho, lr,
-                              mu, interpret: bool, y_dtype=None):
-    """Fused overlap head leafwise over pytrees: x, z, v, e leaves carry
-    the leading replica axis (R, ...); c leaves are the UN-broadcast
-    carried consensus of shape (...).  Iterate outputs are cut back to
-    leaf shape; the payload outputs q (R, Mpad) int8 / s (R, Mpad//1024)
-    f32 stay FLAT (padded like core/compress.pad_to_chunk) — that is the
-    wire format the gather ships.  Returns (x', v', q, s, e') or
-    (x', v', q, s, e', y')."""
-    scalars = _pack_scalars(gamma_scale, inv_rho, lr, mu)
-    emit_y = y_dtype is not None and jnp.dtype(y_dtype) != jnp.float32
-    flat0, treedef = jax.tree_util.tree_flatten(x)
-    fz = treedef.flatten_up_to(z)
-    fv = treedef.flatten_up_to(v)
-    fc = treedef.flatten_up_to(c)
-    fe = treedef.flatten_up_to(e)
-    num_out = 6 if emit_y else 5
-    outs = [[] for _ in range(num_out)]
-    for xl, zl, vl, cl, el in zip(flat0, fz, fv, fc, fe):
-        r, shape, size = xl.shape[0], xl.shape, xl[0].size
-        pad = (-size) % BLOCK_ELEMS
-        fl = lambda a: jnp.pad(a.reshape(r, -1), ((0, 0), (0, pad)))
-        res = parle_apply_quantize_flat(
-            fl(xl), fl(zl), fl(vl), jnp.pad(cl.reshape(-1), (0, pad)),
-            fl(el), scalars, interpret=interpret,
-            y_dtype=y_dtype if emit_y else None)
-        x2, v2, q, s, e2, *ys = res
-        cut = lambda a: a[:, :size].reshape(shape)
-        vals = [cut(x2), cut(v2), q, s, cut(e2)] \
-            + ([cut(ys[0])] if ys else [])
-        for acc, o in zip(outs, vals):
-            acc.append(o)
-    un = jax.tree_util.tree_unflatten
-    return tuple(un(treedef, o) for o in outs)
-
-
-def parle_sync_dequant_tree(x, z, v, q_tree, s_tree, *, gamma_scale,
-                            inv_rho, lr, mu, interpret: bool,
-                            y_dtype=None):
-    """Fused dequantize+mean+sync-update leafwise over pytrees.
-
-    x, z, v leaves carry the leading (local-)replica axis (R, ...);
-    q_tree/s_tree leaves are the all-gathered FLAT payloads (n, Mpad)
-    int8 / (n, Mpad // 1024) f32 produced by the quantize side (Mpad =
-    the leaf's per-replica size padded to the block multiple)."""
-    scalars = _pack_scalars(gamma_scale, inv_rho, lr, mu)
-    emit_y = y_dtype is not None and jnp.dtype(y_dtype) != jnp.float32
-    flat0, treedef = jax.tree_util.tree_flatten(x)
-    flat_z = treedef.flatten_up_to(z)
-    flat_v = treedef.flatten_up_to(v)
-    flat_q = treedef.flatten_up_to(q_tree)
-    flat_s = treedef.flatten_up_to(s_tree)
-    num_out = 3 if emit_y else 2
-    outs = [[] for _ in range(num_out)]
-    for xl, zl, vl, ql, sl in zip(flat0, flat_z, flat_v, flat_q, flat_s):
-        r, shape, size = xl.shape[0], xl.shape, xl[0].size
-        mpad = ql.shape[1]
-        fl = lambda a: jnp.pad(a.reshape(r, -1), ((0, 0), (0, mpad - size)))
-        res = parle_sync_dequant_flat(
-            fl(xl), fl(zl), fl(vl), ql, sl, scalars, interpret=interpret,
-            y_dtype=y_dtype if emit_y else None)
-        for acc, o in zip(outs, res):
-            acc.append(o[:, :size].reshape(shape))
-    un = jax.tree_util.tree_unflatten
-    return tuple(un(treedef, o) for o in outs)

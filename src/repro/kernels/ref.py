@@ -70,41 +70,6 @@ def elastic_worker_update(x, v, g, ref, *, inv_rho, lr, mu):
 
 
 # ------------------------------------------------------------------
-# Compressed-sync kernels (quantize+EF / dequantize+mean+update)
-# ------------------------------------------------------------------
-
-def quantize_ef(c):
-    """Oracle of kernels/parle_update.quantize_ef_flat: per-1024-chunk
-    symmetric int8 quantization + error-feedback residual (the codec
-    itself lives in core/compress.py — one definition, shared)."""
-    from repro.core import compress
-    return compress.quantize_ef(c, "int8")
-
-
-def parle_sync_dequant_update(x, z, v, q, s, *, gamma_scale, inv_rho,
-                              lr, mu):
-    """Oracle of the fused dequantize+mean+sync-update kernel: the
-    composition dequantize -> replica mean -> parle_sync_update."""
-    from repro.core import compress
-    xbar = jnp.mean(compress.dequantize(q, s, "int8"), axis=0)
-    return parle_sync_update(x, z, v, xbar[None], gamma_scale=gamma_scale,
-                             inv_rho=inv_rho, lr=lr, mu=mu)
-
-
-def parle_apply_quantize(x, z, v, c, e, *, gamma_scale, inv_rho, lr, mu):
-    """Oracle of the fused apply-stale-consensus + quantize kernel
-    (staleness-1 overlap head): parle_sync_update with the CARRIED mean
-    ``c``, then int8 quantize_ef of the new payload x' + e.
-
-    x, z, v, e: (R, M); c: (M,).  Returns (x', v', q, s, e')."""
-    x_new, v_new = parle_sync_update(x, z, v, c[None],
-                                     gamma_scale=gamma_scale,
-                                     inv_rho=inv_rho, lr=lr, mu=mu)
-    q, s, e_new = quantize_ef(x_new + e)
-    return x_new, v_new, q, s, e_new
-
-
-# ------------------------------------------------------------------
 # flash_attention: causal (optionally sliding-window) MHA
 # ------------------------------------------------------------------
 

@@ -61,7 +61,7 @@ def build_train_programs(cfg, mesh, shape_info, param_dtype=jnp.bfloat16):
     per_replica = gb // n
     pcfg = ParleConfig(n_replicas=n, lr=0.1, lr_inner=0.1)
 
-    inner, sync, _ = steps_lib.make_parle_steps(
+    inner, sync = steps_lib.make_parle_steps(
         cfg, pcfg, weight_decay=5e-4, remat=OPTIONS["remat"])
 
     state_sds = specs_lib.parle_state_shapes(cfg, pcfg, param_dtype)

@@ -19,7 +19,6 @@
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from repro.core import parle as parle_mod
 from repro.core import registry
@@ -88,38 +87,20 @@ def make_parle_steps(cfg, pcfg, weight_decay: float = 0.0,
                      use_flash: bool = False, remat: bool = False,
                      use_kernel: bool = False):
     loss_fn = make_loss_fn(cfg, use_flash=use_flash, remat=remat)
-
-    def replica_grad(params, batch):
-        (loss, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
-        return loss, g
+    replica_step = parle_mod.make_replica_step(
+        loss_fn, pcfg, weight_decay=weight_decay, use_kernel=use_kernel)
 
     def inner_step(state, batch):
         """(8a)-(8b): per-replica grad + fused update. Cross-replica: NONE
         (the grad all-reduce over "data" is *intra*-replica)."""
-        losses, grads = jax.vmap(replica_grad)(state.y, batch)
-        if weight_decay:
-            grads = jax.tree.map(lambda g, p: g + weight_decay * p,
-                                 grads, state.y)
-        new_state = parle_mod.inner_step(state, grads, pcfg,
-                                         use_kernel=use_kernel)
-        return new_state, {"loss": jnp.mean(losses)}
+        state, loss, _ = replica_step(state, batch)
+        return state, {"loss": loss}
 
     def sync_step(state):
         """(8c)-(8d): the one all-reduce over the replica axis."""
         return parle_mod.sync_step(state, pcfg)
 
-    def fused_step(state, batch):
-        losses, grads = jax.vmap(replica_grad)(state.y, batch)
-        if weight_decay:
-            grads = jax.tree.map(lambda g, p: g + weight_decay * p,
-                                 grads, state.y)
-        new_state = parle_mod.fused_step(state, grads, pcfg,
-                                         use_kernel=use_kernel)
-        return new_state, {"loss": jnp.mean(losses),
-                           "gamma": new_state.scopes.gamma,
-                           "rho": new_state.scopes.rho}
-
-    return inner_step, sync_step, fused_step
+    return inner_step, sync_step
 
 
 def make_prefill_step(cfg, use_flash: bool = False):

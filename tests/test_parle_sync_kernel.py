@@ -75,8 +75,13 @@ def test_sync_kernel_pytree_leafwise():
                                rtol=1e-5, atol=1e-6)
 
 
-def test_sync_step_kernel_path_matches_jnp():
-    cfg = ParleConfig(n_replicas=3, L=2, batches_per_epoch=10)
+@pytest.mark.parametrize("sync_compress", ["none", "bf16", "int8"])
+def test_sync_step_kernel_path_matches_jnp(sync_compress):
+    """The kernel sync matches the XLA sync; with a compressed sync both
+    quantize the same payload through the jnp codec, and only the
+    update's rounding can differ."""
+    cfg = ParleConfig(n_replicas=3, L=2, batches_per_epoch=10,
+                      sync_compress=sync_compress)
     key = jax.random.PRNGKey(3)
     st = parle.init_from_replicas(
         {"w": jax.random.normal(key, (3, 7)),
@@ -86,17 +91,29 @@ def test_sync_step_kernel_path_matches_jnp():
     a = parle.sync_step(st, cfg, use_kernel=False)
     b = parle.sync_step(st, cfg, use_kernel=True)
     for field in ("x", "v_x", "y", "z"):
-        np.testing.assert_allclose(np.asarray(getattr(a, field)["w"]),
-                                   np.asarray(getattr(b, field)["w"]),
-                                   rtol=1e-5, atol=1e-6)
+        for leaf in ("w", "b"):
+            np.testing.assert_allclose(
+                np.asarray(getattr(a, field)[leaf]),
+                np.asarray(getattr(b, field)[leaf]),
+                rtol=1e-5, atol=1e-6, err_msg=f"{field}[{leaf}]")
+    if sync_compress == "none":
+        assert a.e is None and b.e is None
+    else:           # the same payload: the residual is bit-identical
+        for leaf in ("w", "b"):
+            np.testing.assert_array_equal(np.asarray(a.e[leaf]),
+                                          np.asarray(b.e[leaf]))
+            assert np.any(np.asarray(b.e[leaf]) != 0)
     # scoping decay fired identically
     assert float(a.scopes.gamma) == pytest.approx(float(b.scopes.gamma))
 
 
-def test_fused_step_kernel_path_through_sync():
+@pytest.mark.parametrize("sync_compress", ["none", "int8"])
+def test_fused_step_kernel_path_through_sync(sync_compress):
     """use_kernel=True drives BOTH fused kernels (inner + sync) through
-    a sync boundary and must match the jnp path."""
-    cfg = ParleConfig(n_replicas=2, L=2, batches_per_epoch=10)
+    a sync boundary and must match the jnp path, with the int8 sync as
+    without compression."""
+    cfg = ParleConfig(n_replicas=2, L=2, batches_per_epoch=10,
+                      sync_compress=sync_compress)
     key = jax.random.PRNGKey(4)
     st_a = st_b = parle.init(
         {"w": jax.random.normal(key, (6,))}, cfg)
@@ -106,4 +123,8 @@ def test_fused_step_kernel_path_through_sync():
         st_b = parle.fused_step(st_b, g, cfg, use_kernel=True)
     np.testing.assert_allclose(np.asarray(st_a.x["w"]),
                                np.asarray(st_b.x["w"]), rtol=1e-5, atol=1e-6)
+    if sync_compress != "none":
+        np.testing.assert_allclose(np.asarray(st_a.e["w"]),
+                                   np.asarray(st_b.e["w"]),
+                                   rtol=1e-5, atol=1e-6)
     assert int(st_b.step) == 4

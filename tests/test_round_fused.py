@@ -9,8 +9,8 @@ must reproduce the per-step dispatch loop exactly.
    is neutralized by dealias_state, and steady-state outputs re-donate.
  * 8-device shard_map rounds (subprocess, like test_distributed_sync):
    replica-only mesh bit-identical; composed FSDP x TP mesh to float
-   tolerance (the jax 0.4.37 GSPMD workaround documented in
-   parle.make_sharded_round_fn).
+   tolerance (its GSPMD inner scan, documented in
+   parle._round_program).
 """
 import os
 import subprocess
@@ -132,11 +132,11 @@ _CHILD = textwrap.dedent("""
     st_steps = parle.init_from_replicas(reps, cfg)
     step8 = parle.make_sharded_train_step(loss, cfg, mesh8)
     st8 = parle.dealias_state(parle.init_from_replicas(reps, cfg))
-    round8 = parle.make_sharded_round_fn(loss, cfg, mesh8)
+    round8 = parle.make_round_fn(loss, cfg, mesh=mesh8)
     # 2 replicas per device
     mesh4 = jax.make_mesh((4,), ("replica",))
     st4 = parle.dealias_state(parle.init_from_replicas(reps, cfg))
-    round4 = parle.make_sharded_round_fn(loss, cfg, mesh4)
+    round4 = parle.make_round_fn(loss, cfg, mesh=mesh4)
     # local reference (rtol-level cross-placement check)
     st_ref = parle.dealias_state(parle.init_from_replicas(reps, cfg))
     round_ref = parle.make_round_fn(loss, cfg)
@@ -179,7 +179,7 @@ _CHILD = textwrap.dedent("""
     st_lc = parle.dealias_state(parle.init_from_replicas(repsc, cfgc))
     round_lc = parle.make_round_fn(lossc, cfgc)
     st_c = parle.dealias_state(parle.init_from_replicas(repsc, cfgc))
-    round_c = parle.make_sharded_round_fn(lossc, cfgc, meshc)
+    round_c = parle.make_round_fn(lossc, cfgc, mesh=meshc)
     for r in range(2):
         st_lc, m_lc = round_lc(st_lc, rbc)
         st_c, m_c = round_c(st_c, rbc)

@@ -32,8 +32,7 @@ from repro.models import mamba2
 from repro.models.model import build_model
 
 LEAF = (2048, 8192)
-M = LEAF[0] * LEAF[1]
-R, N_GLOBAL = 2, 4                    # local replicas; replicas gathered
+R = 2                                 # local replicas
 F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
 
 
@@ -71,7 +70,6 @@ def _ssd_vjp(x, dt, A, B_mat, C_mat, dy):
 def _cases():
     """name -> (kernel call, [(shape, dtype)] operands)."""
     leaf, rep = [(LEAF, F32)], [((R,) + LEAF, F32)]
-    flat, rep_flat = [((M,), F32)], [((R, M), F32)]
     s4, s3 = [((4,), F32)], [((3,), F32)]
     q_heads = [((1, 2048, 16, 128), BF16)] * 3        # GQA expanded
     pool = [((256, 16, 2, 128), BF16)] * 2             # (P, ps, KV, hd)
@@ -99,15 +97,6 @@ def _cases():
         "elastic_update_leaf": (
             lambda *a: pu.elastic_update_leaf(*a, interpret=False),
             rep * 3 + leaf + s3),
-        "quantize_ef_flat": (
-            lambda c: pu.quantize_ef_flat(c, interpret=False), rep_flat),
-        "parle_sync_dequant_flat": (
-            lambda *a: pu.parle_sync_dequant_flat(*a, interpret=False),
-            rep_flat * 3 + [((N_GLOBAL, M), jnp.int8),
-                            ((N_GLOBAL, M // 1024), F32)] + s4),
-        "parle_apply_quantize_flat": (
-            lambda *a: pu.parle_apply_quantize_flat(*a, interpret=False),
-            rep_flat * 3 + flat + rep_flat + s4),
         "flash_attention": (
             lambda *a: fa.flash_attention(*a, interpret=False), q_heads),
         "paged_attention": (

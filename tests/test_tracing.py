@@ -85,6 +85,36 @@ def test_hlo_op_names_maps_every_instruction_with_metadata(compiled_round):
     assert any("parle_inner" in _scopes_in(v) for v in ops.values())
 
 
+def _quad_loss(p, b):
+    return jnp.mean((p["w"] @ p["m"] - b["t"]) ** 2), ()
+
+
+@pytest.mark.parametrize("kind", ["barrier", "overlap", "async",
+                                  "replica_mesh"])
+def test_round_keeps_the_parle_scopes(kind):
+    """Every round program keeps the scopes the benchmark splits a round
+    by: ``parle_inner`` (the inner update) in each, ``parle_sync`` in
+    each round that syncs and in no other."""
+    from repro.core import registry
+    from repro.launch.mesh import make_mesh_from_spec
+    pcfg = ParleConfig(n_replicas=N, L=L, lr=0.1, lr_inner=0.1,
+                       sync_overlap=kind == "overlap")
+    state = parle.dealias_state(parle.init(
+        {"w": jnp.ones((8, 16)) * 0.1, "m": jnp.ones((16, 4)) * 0.1}, pcfg))
+    batches = {"t": jnp.ones((L, N, 8, 4))}
+    if kind == "async":
+        fn = parle.make_inner_round_fn(_quad_loss, pcfg)
+    else:
+        mesh = make_mesh_from_spec("replica:1") \
+            if kind == "replica_mesh" else None
+        fn = registry.get("parle").make_round_fn(_quad_loss, pcfg, mesh=mesh)
+    text = fn.lower(state, batches).compile().as_text()
+    seen = {p for n in re.findall(r'op_name="([^"]*)"', text)
+            for p in _scopes_in(n)}
+    assert "parle_inner" in seen
+    assert ("parle_sync" in seen) == (kind != "async")
+
+
 def test_stager_runs_under_its_scope():
     stage = make_round_batch_fn(TokenStream(vocab_size=64, seq_len=8,
                                             batch_size=1, seed=1), 2, 1, 2)
