@@ -166,22 +166,62 @@ def _split_proj(cfg, proj):
     return z, xBC, dt
 
 
+def _taps(pad, w, T):
+    """sum_i pad[:, i:i+T, :] * w[i]: the W-tap depthwise conv of a (B,
+    T+W-1, C) sequence."""
+    out = jnp.zeros((pad.shape[0], T, pad.shape[2]), pad.dtype)
+    for i in range(w.shape[0]):
+        out = out + pad[:, i:i + T, :] * w[i]
+    return out
+
+
+@jax.custom_vjp
+def _conv_silu(xBC, w, b):
+    """silu(causal conv of xBC, zero-padded in front, + b)."""
+    W = w.shape[0]
+    return silu(_taps(jnp.pad(xBC, ((0, 0), (W - 1, 0), (0, 0))), w,
+                      xBC.shape[1]) + b)
+
+
+def _conv_silu_fwd(xBC, w, b):
+    return _conv_silu(xBC, w, b), (xBC, w, b)
+
+
+def _conv_silu_bwd(res, g):
+    """Two passes over (B, T, C).  Autodiff would transpose each tap's slice
+    into a padded cotangent of its own and sum the W of them; here one pass
+    recomputes the pre-activation and reduces dw and db, the other sums the
+    W shifts of one end-padded da into dx."""
+    xBC, w, b = res
+    W, T = w.shape[0], xBC.shape[1]
+    pad = jnp.pad(xBC, ((0, 0), (W - 1, 0), (0, 0)))
+    a = _taps(pad, w, T) + b
+    s = jax.nn.sigmoid(a)
+    da = g * s * (1 + a * (1 - s))
+    dw = jnp.stack([jnp.sum(pad[:, i:i + T, :] * da, axis=(0, 1))
+                    for i in range(W)])
+    db = jnp.sum(da, axis=(0, 1))
+    # x[t] reaches out[t + W-1-i] through tap i: the mirror of the forward,
+    # over da padded at the end, taps reversed
+    dx = _taps(jnp.pad(da, ((0, 0), (0, W - 1), (0, 0))), w[::-1], T)
+    return dx.astype(xBC.dtype), dw.astype(w.dtype), db.astype(b.dtype)
+
+
+_conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
 def _causal_conv(xBC, w, b, prefix=None):
-    """Depthwise causal conv.  xBC: (B, T, C); w: (W, C).
+    """Depthwise causal conv, then SiLU.  xBC: (B, T, C); w: (W, C).
 
     ``prefix``: optional (B, W-1, C) ring of raw xBC inputs preceding
-    this segment (chunk-resumed prefill); None pads with zeros — and a
-    zero prefix is bitwise identical to the zero padding.
+    this segment (chunk-resumed prefill, forward only); None pads with
+    zeros — and a zero prefix is bitwise identical to the zero padding.
+    Without a prefix the gradient is ``_conv_silu_bwd``'s.
     """
-    W = w.shape[0]
     if prefix is None:
-        pad = jnp.pad(xBC, ((0, 0), (W - 1, 0), (0, 0)))
-    else:
-        pad = jnp.concatenate([prefix.astype(xBC.dtype), xBC], axis=1)
-    out = jnp.zeros_like(xBC)
-    for i in range(W):
-        out = out + pad[:, i:i + xBC.shape[1], :] * w[i]
-    return silu(out + b)
+        return _conv_silu(xBC, w, b)
+    pad = jnp.concatenate([prefix.astype(xBC.dtype), xBC], axis=1)
+    return silu(_taps(pad, w, xBC.shape[1]) + b)
 
 
 def _fused_ssd(cfg, T, h0) -> bool:

@@ -13,10 +13,12 @@ A Mamba2 block's gradient compiles on both sides of its choice of SSD:
 the fused op at zamba2-1.2b's widths (state 64), ``ssd_chunked`` at the
 smoke widths (chunks of 32, which the kernels do not tile).  The training
 cells' whole gradient (2 replicas, 4 layers at mamba2-1.3b widths, 2,048
-tokens) fits its temporaries under the layer scan's save policy.
+tokens) fits its temporaries under the layer scan's save policy, and its
+causal conv's backward keeps no per-tap cotangents.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -159,16 +161,12 @@ def test_ssd_block_compiles_for_v5e(name, one_chip, monkeypatch):
         2 if fused else 0)
 
 
-def test_mamba2_training_gradient_fits_for_v5e(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def training_gradient(one_chip):
     """The gradient the training cells take, vmapped over 2 Parle replicas:
     mamba2-1.3b cut to 4 layers and 4,190 vocabulary rows, 1 x 2,048
-    tokens, the fused SSD on as on the chip.  The layer scan keeps only
-    each layer's input and ``proj`` (``mamba2.SAVED``), so the
-    temporaries stay under 4 GB (6.65 GB when it kept every intermediate,
-    2.54 GB with the policy).  The backward runs the SSD's forward kernel
-    again to rebuild its output and states: three kernels, ``ssd_fwd``
-    twice and ``ssd_bwd`` once."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tokens, the backend answering "tpu" so the fused SSD is on as on the
+    chip.  Returns (config, compiled)."""
     cfg = dataclasses.replace(get_config("mamba2-1.3b"), num_layers=4,
                               vocab_size=4190)
     model = build_model(cfg)
@@ -182,8 +180,38 @@ def test_mamba2_training_gradient_fits_for_v5e(one_chip, monkeypatch):
     def loss(p, t):
         return model.loss(p, {"tokens": t, "labels": t})[0]
 
-    compiled = jax.jit(jax.vmap(jax.value_and_grad(loss))).lower(
-        params, tokens).compile()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return cfg, jax.jit(jax.vmap(jax.value_and_grad(loss))).lower(
+            params, tokens).compile()
+
+
+def test_mamba2_training_gradient_fits_for_v5e(training_gradient):
+    """The layer scan keeps only each layer's input and ``proj``
+    (``mamba2.SAVED``), so the temporaries stay under 4 GB (6.65 GB when it
+    kept every intermediate, 2.54 GB with the policy).  The backward runs
+    the SSD's forward kernel again to rebuild its output and states: three
+    kernels, ``ssd_fwd`` twice and ``ssd_bwd`` once."""
+    _, compiled = training_gradient
     assert compiled.as_text().count(
         'custom_call_target="tpu_custom_call"') == 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
+def test_mamba2_conv_backward_writes_no_per_tap_cotangents(
+        training_gradient):
+    """The causal conv's backward (``mamba2._conv_silu_bwd``) sums the W
+    shifts of one padded cotangent into dxBC.  Autodiff of the W-tap sum
+    wrote one padded cotangent per tap, a fusion whose output was a tuple
+    of W arrays of xBC's (replicas, 1, 2,048, 4,352) shape, and read them
+    back in a second; no instruction outputs such a tuple now."""
+    cfg, compiled = training_gradient
+    shape = f"f32[{R},1,2048,{cfg.ssm_inner + 2 * cfg.ssm_state}]"
+    tuples = {}
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \((.*?)\) [\w\-]+\(", line)
+        if m:
+            tuples[m.group(1)] = m.group(2).count(shape)
+    assert tuples and max(tuples.values()) < cfg.ssm_conv, {
+        k: v for k, v in tuples.items() if v >= cfg.ssm_conv}
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
